@@ -1,7 +1,7 @@
 """Solver heartbeat: periodic liveness + resource records.
 
-A big-board solve is hours of silence between per-level records — longer
-than the environment's relay MTBF — and when it wedges the operator has
+A big-board solve is hours of silence between per-level records, and when
+it wedges the operator has
 nothing to distinguish "slow level" from "dead backend". The heartbeat
 is a daemon thread that every ``interval`` seconds emits one record with:
 

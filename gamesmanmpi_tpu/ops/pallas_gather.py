@@ -1,4 +1,10 @@
-"""Pallas monotone-window gather (round-4; first Mosaic-compiled on chip).
+"""Pallas monotone-window gather (round-4; the installed Mosaic refuses it).
+
+The TPU compiler of JAX 0.9.0 rejects this kernel ("Not implemented:
+Multiple source vregs along gather dimension" on tpu.dynamic_gather over
+vector<128x128xi32>), for int32 and int64 indices alike;
+tests/test_tpu_compile.py pins that as a strict xfail. It runs only under
+GAMESMAN_DENSE_GATHER=pallas, and in interpret mode on the CPU.
 
 The dense engine's backward step is, per move, one byte-gather with a
 globally NON-DECREASING flat index vector (solve/dense.py sorted-gather

@@ -18,27 +18,6 @@ from gamesmanmpi_tpu.utils.env import env_int, env_opt, env_str
 AXIS = "shards"
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """Version-portable shard_map.
-
-    jax >= 0.5 exposes jax.shard_map with a `check_vma` knob; on the 0.4.x
-    line (this container ships 0.4.37) the API lives at
-    jax.experimental.shard_map.shard_map and the same knob is spelled
-    `check_rep`. Every sharded kernel builder routes through here so the
-    engine runs on both — without this the whole sharded engine failed at
-    build time with AttributeError on 0.4.x.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    kwargs = {} if check_vma is None else {"check_rep": check_vma}
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
 def make_mesh(num_shards: int | None = None, devices=None) -> Mesh:
     """A 1-D mesh over `num_shards` devices (default: all available)."""
     if devices is None:

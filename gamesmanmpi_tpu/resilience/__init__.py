@@ -2,8 +2,8 @@
 
 The runs this system exists for — multi-hour retrograde sweeps over
 billions of positions (the Pentago in-core solve, the weeks-long
-computation behind "Othello is Solved") — are longer than this
-environment's relay MTBF, and the serving layer must degrade instead of
+computation behind "Othello is Solved") — outlast the time between
+host or device failures, and the serving layer must degrade instead of
 dying under reader faults. Three pieces, one subsystem:
 
 * ``faults`` — a deterministic fault-injection registry: named fault

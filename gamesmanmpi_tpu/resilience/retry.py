@@ -11,8 +11,7 @@ anything fatal untouched.
 
 What counts as transient: injected :class:`TransientFault`, and runtime
 errors whose message carries a known transient marker (the gRPC-ish
-status words a remote-relay XLA backend surfaces when the transport
-hiccups). ``RESOURCE_EXHAUSTED`` is deliberately NOT transient — an OOM
+status words an XLA runtime surfaces when its transport hiccups). ``RESOURCE_EXHAUSTED`` is deliberately NOT transient — an OOM
 at a fixed shape will OOM again; retrying it would just triple the time
 to the real failure. Extend the marker list for a specific deployment
 with ``GAMESMAN_RETRY_MARKERS`` (comma-separated substrings).
@@ -57,7 +56,7 @@ def is_transient(exc: BaseException) -> bool:
     if isinstance(exc, FatalFault):
         return False
     # jaxlib's XlaRuntimeError subclasses RuntimeError; transport-level
-    # failures can also surface as bare OSError from the relay socket.
+    # failures can also surface as bare OSError from a socket.
     if not isinstance(exc, (RuntimeError, OSError)):
         return False
     msg = str(exc).lower()

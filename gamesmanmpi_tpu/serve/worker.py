@@ -297,11 +297,11 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
     cfg = json.loads(argv[0])
-    from gamesmanmpi_tpu.utils.platform import apply_platform_env
+    from gamesmanmpi_tpu.utils.platform import pin_host_cpu
 
     # Same platform policy as `cli serve`: the query kernels are
-    # host-side by design; honor GAMESMAN_PLATFORM before backend init.
-    apply_platform_env(default_fake_devices=1)
+    # host-side by design, so a worker never touches the accelerator.
+    pin_host_cpu()
     listen_sock = socket.socket(fileno=int(cfg["listen_fd"]))
     return run_worker(cfg, listen_sock, int(cfg["pipe_fd"]))
 

@@ -123,7 +123,7 @@ def test_transient_classification():
     assert is_transient(TransientFault("x"))
     assert not is_transient(FatalFault("x"))
     assert is_transient(RuntimeError("UNAVAILABLE: socket closed"))
-    assert is_transient(RuntimeError("DEADLINE_EXCEEDED: relay stall"))
+    assert is_transient(RuntimeError("DEADLINE_EXCEEDED: transport stall"))
     assert not is_transient(RuntimeError("RESOURCE_EXHAUSTED: OOM"))
     assert not is_transient(ValueError("UNAVAILABLE"))  # not a runtime error
     assert not is_transient(KeyboardInterrupt())

@@ -138,8 +138,8 @@ def test_manifest_writes_are_atomic(tmp_path):
 def test_forward_checkpoint_resume_mid_forward(tmp_path):
     """A run killed mid-DISCOVERY resumes from the deepest saved frontier.
 
-    Forward alone is a multi-hour phase at big-board scale — longer than
-    the environment's relay MTBF — so frontiers are checkpointed per level
+    Forward alone is a multi-hour phase at big-board scale — it can
+    outlast the host it runs on — so frontiers are checkpointed per level
     as discovered, not only after the sweep completes (the r04 gap the 6x6
     feasibility analysis named). The kill lands after level 3's save; the
     resumed run must re-expand only from level 3 down and still match the
@@ -563,6 +563,59 @@ def test_force_platform_noop_and_epoch_keying(monkeypatch):
     assert _cache_key(game, "k", (1,), lowering=()) != key_old
     tables.drop_stale_device_caches()
     assert tables._dev_binom is None and not tables._dev_consts
+
+
+def test_use_compile_cache_placement(monkeypatch):
+    """One policy for every entry point: a cache directory given from
+    outside wins and nothing is set; otherwise the fixed repo path."""
+    import jax
+
+    from gamesmanmpi_tpu.utils import platform as plat
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        jax.config.update("jax_compilation_cache_dir", None)
+        plat.use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == str(
+            plat.REPO_CACHE_DIR)
+        assert plat.REPO_CACHE_DIR.name == ".jax_compile_cache"
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", None)
+        plat.use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir is None
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_cli_compile_cache_goes_where_env_says(tmp_path):
+    """A CLI solve with JAX_COMPILATION_CACHE_DIR set fills that directory
+    and writes none of its entries into the repo's default cache."""
+    import os
+    import subprocess
+    import sys
+
+    from gamesmanmpi_tpu.utils.platform import REPO_CACHE_DIR
+
+    def entries(d):
+        return set(os.listdir(d)) if os.path.isdir(d) else set()
+
+    cache = tmp_path / "cache"
+    before = entries(REPO_CACHE_DIR)
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(cache),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    proc = subprocess.run(
+        [sys.executable, "-m", "gamesmanmpi_tpu.cli", "tictactoe"],
+        env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "remoteness: 9" in proc.stdout
+    ours = entries(cache)
+    assert ours, "no compile-cache entries in JAX_COMPILATION_CACHE_DIR"
+    # Other test workers may write the repo cache meanwhile; none of them
+    # writes THIS run's entries.
+    assert not (entries(REPO_CACHE_DIR) - before) & ours
 
 
 def test_cli_query_from_dense_checkpoints_no_tables(tmp_path, capsys):
