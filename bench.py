@@ -2047,27 +2047,6 @@ def inner() -> int:
         raise SystemExit("bench: no accelerator found (set "
                          "GAMESMAN_PLATFORM=cpu for a CPU run)")
 
-    # ISSUE 15 roofline accounting: measure THIS host's per-dispatch
-    # overhead (a tiny compiled kernel round-tripped N times) and
-    # publish it as GAMESMAN_DISPATCH_COST_SECS so every solve's
-    # stats["roofline"]["dispatch_overhead_frac"] prices its dispatch
-    # count against a measured figure instead of a guess. An inherited
-    # operator value wins (a deliberate override for a known platform).
-    if not os.environ.get("GAMESMAN_DISPATCH_COST_SECS"):
-        import jax.numpy as jnp
-
-        probe = jax.jit(lambda a: a + 1)
-        arg = jnp.zeros((8,), dtype=jnp.int32)
-        probe(arg).block_until_ready()  # compile outside the timing
-        t0 = time.perf_counter()
-        reps = 64
-        for _ in range(reps):
-            probe(arg).block_until_ready()
-        cost = (time.perf_counter() - t0) / reps
-        os.environ["GAMESMAN_DISPATCH_COST_SECS"] = f"{cost:.9f}"
-        print(f"dispatch cost: {cost * 1e6:.1f} us/dispatch",
-              file=sys.stderr)
-
     # Engine selection: the dense class-partitioned engine (solve/dense.py)
     # is the fast path for non-symmetric Connect-4 boards on the
     # accelerator; on a CPU run its VPU-shaped rank loops lose to
@@ -2213,11 +2192,9 @@ def inner() -> int:
             },
             "overlap_secs": round(stats.get("overlap_secs", 0.0), 3),
             "fused": bool(stats.get("fused", False)),
-            # ISSUE 15 roofline fields: analytic HBM operand throughput,
-            # the headline per-chip rate, and the wall fraction spent on
-            # dispatch overhead (dispatch count x the calibrated
-            # per-dispatch cost measured above) — what bench_compare
-            # diffs across the committed BENCH_* trajectory.
+            # Roofline fields: analytic HBM operand throughput
+            # and the headline per-chip rate — what bench_compare diffs
+            # across the committed BENCH_* trajectory.
             "roofline": {
                 "operand_gbps": round(
                     traffic / max(stats.get("secs_total", 0.0), 1e-9)
@@ -2230,9 +2207,6 @@ def inner() -> int:
                 "pps_per_chip": round(
                     best_pps / (stats.get("shards", 1)
                                 if dev.platform != "cpu" else 1), 1),
-                "dispatch_overhead_frac": (
-                    stats.get("roofline") or {}
-                ).get("dispatch_overhead_frac", 0.0),
             },
         }
         if "shards" in stats:

@@ -309,12 +309,23 @@ def _report(result, devices: int, elapsed: float, args) -> None:
 
         save_result_npz(args.table_out, result)
         print(f"table written: {args.table_out}")
+    if args.query:
+        from gamesmanmpi_tpu.obs import trace_span
+
+        with trace_span("query", queries=len(args.query)):
+            _answer_queries(result, args)
+
+
+def _answer_queries(result, args) -> None:
+    """Print one line per ``--query`` position, from the solved table."""
+    from gamesmanmpi_tpu.core.values import value_name
+
     ckpt = None
-    if args.query and getattr(args, "checkpoint_dir", None):
+    if getattr(args, "checkpoint_dir", None):
         from gamesmanmpi_tpu.utils import LevelCheckpointer
 
         ckpt = LevelCheckpointer(args.checkpoint_dir)
-    for q in args.query or ():
+    for q in args.query:
         # The reference prints only the root; point queries answer for any
         # reachable position from the solved table (SolveResult.lookup
         # canonicalizes, so sym=1 tables answer for all class members). In
@@ -528,6 +539,7 @@ def _solve_main(args, t0: float, logger) -> int:
     import pathlib
 
     from gamesmanmpi_tpu.core.values import value_name
+    from gamesmanmpi_tpu.obs import trace_span
     from gamesmanmpi_tpu.utils.profiling import maybe_profile
 
     checkpointer = None
@@ -583,7 +595,8 @@ def _solve_main(args, t0: float, logger) -> int:
             # auto-derived with grow-and-retry (compat.solve_module_jitted).
             from gamesmanmpi_tpu.compat import solve_module_jitted
 
-            with maybe_profile(args.profile_dir):
+            with maybe_profile(args.profile_dir), \
+                    trace_span("solve", engine="compat"):
                 result = solve_module_jitted(
                     module,
                     devices=args.devices,
@@ -595,7 +608,8 @@ def _solve_main(args, t0: float, logger) -> int:
             _report(result, args.devices, time.perf_counter() - t0, args)
             return 0
         else:
-            with maybe_profile(args.profile_dir):
+            with maybe_profile(args.profile_dir), \
+                    trace_span("solve", engine="host"):
                 value, remoteness, table = solve_module(module)
             elapsed = time.perf_counter() - t0
             print(f"game: {pathlib.Path(args.game).stem} (compat module)")
@@ -703,6 +717,8 @@ def _solve_main(args, t0: float, logger) -> int:
     if args.engine == "hybrid":
         from gamesmanmpi_tpu.solve.hybrid import HybridSolver
 
+        engine = "hybrid"
+
         try:
             solver = HybridSolver(
                 game,
@@ -719,6 +735,8 @@ def _solve_main(args, t0: float, logger) -> int:
     elif args.engine != "classic" and dense_eligible:
         from gamesmanmpi_tpu.solve.dense import DenseSolver
 
+        engine = "dense"
+
         try:
             solver = DenseSolver(
                 game,
@@ -733,6 +751,8 @@ def _solve_main(args, t0: float, logger) -> int:
     elif args.devices > 1:
         from gamesmanmpi_tpu.parallel import ShardedSolver
 
+        engine = "sharded"
+
         solver = ShardedSolver(
             game,
             num_shards=args.devices,
@@ -744,6 +764,7 @@ def _solve_main(args, t0: float, logger) -> int:
     else:
         from gamesmanmpi_tpu.solve import Solver
 
+        engine = "classic"
         solver = Solver(
             game,
             paranoid=args.paranoid,
@@ -770,7 +791,8 @@ def _solve_main(args, t0: float, logger) -> int:
         else (lambda: None)
     )
     try:
-        with maybe_profile(args.profile_dir):
+        with maybe_profile(args.profile_dir), \
+                trace_span("solve", engine=engine):
             result = solver.solve()
     except PreemptionRequested as e:
         _dump_flightrec("preempted")

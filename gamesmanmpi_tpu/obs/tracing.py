@@ -1,8 +1,12 @@
 """Span / trace_span: phase-level wall-time tracing.
 
 A ``Span`` measures one named phase (a solver level's forward expand, a
-backward resolve, a checkpoint write, a serving batch). Ending a span
-fans out to up to three sinks:
+backward resolve, a checkpoint write, a serving batch). While it is open
+it is also a profiler annotation (a ``jax.profiler.TraceAnnotation``,
+carrying its integer and string fields), so a running profiler trace
+(``--profile-dir``, the benchmark's ``--trace 1``) holds every span on
+the device trace's own clock. Ending a span fans out to up to three
+sinks:
 
 * the metrics registry — ``gamesman_span_seconds{span=...}`` histogram
   plus ``gamesman_span_payload_total{span=...,key=...}`` counters for
@@ -26,6 +30,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 from typing import Optional
@@ -115,15 +120,38 @@ class TraceEventSink:
         os.replace(tmp, path)
 
 
+def _annotation_args(fields: dict) -> dict:
+    """The fields a profiler annotation carries: integers and strings."""
+    return {k: v for k, v in fields.items()
+            if isinstance(v, (int, str)) and not isinstance(v, bool)}
+
+
+def _open_annotation(name: str, fields: dict):
+    """Enter a profiler annotation for a span; None where JAX is not
+    loaded (no profiler can be running then) or the annotation fails.
+    With no profiler running it is one inactive TraceMe."""
+    if "jax" not in sys.modules:
+        return None
+    try:
+        from jax.profiler import TraceAnnotation
+
+        ann = TraceAnnotation(name, **_annotation_args(fields))
+        ann.__enter__()
+        return ann
+    except Exception:  # noqa: BLE001 - diagnostics only
+        return None
+
+
 class Span:
-    """One timed phase. Construction starts the clock; ``end()`` stops it
-    and fans out (idempotent — a with-block exit after an explicit end is
-    a no-op). ``set()`` attaches payload fields; they ride into the JSONL
-    record, the trace event's args, and (integers only) the payload
-    counters."""
+    """One timed phase. Construction starts the clock and opens the
+    span's profiler annotation; ``end()`` closes both and fans out
+    (idempotent — a with-block exit after an explicit end is a no-op).
+    Spans of one thread may end in any order. ``set()`` attaches payload
+    fields; they ride into the JSONL record, the trace event's args, the
+    profiler annotation, and (integers only) the payload counters."""
 
     __slots__ = ("name", "fields", "_clock", "_registry", "_logger",
-                 "_t0", "_secs", "_log")
+                 "_t0", "_secs", "_log", "_ann")
 
     def __init__(self, name: str, *, logger=None,
                  registry: Optional[MetricsRegistry] = None,
@@ -135,6 +163,7 @@ class Span:
         self._logger = logger
         self._log = log
         self._secs: Optional[float] = None
+        self._ann = _open_annotation(name, self.fields)
         self._t0 = self._clock()
         # Flight recorder (obs/flightrec.py): every span registers as
         # in-flight at construction so a post-mortem dump can name what
@@ -152,7 +181,15 @@ class Span:
 
     def set(self, **fields) -> "Span":
         self.fields.update(fields)
+        self._annotate(fields)
         return self
+
+    def _annotate(self, fields: dict) -> None:
+        if self._ann is not None and fields:
+            try:
+                self._ann.set_metadata(**_annotation_args(fields))
+            except Exception:  # noqa: BLE001 - diagnostics only
+                pass
 
     @property
     def secs(self) -> Optional[float]:
@@ -165,6 +202,13 @@ class Span:
         self._secs = self._clock() - self._t0
         if fields:
             self.fields.update(fields)
+            self._annotate(fields)
+        if self._ann is not None:
+            try:
+                self._ann.__exit__(None, None, None)
+            except Exception:  # noqa: BLE001 - diagnostics only
+                pass
+            self._ann = None
         if log is not None:
             self._log = log
         reg = self._registry or default_registry()

@@ -9,6 +9,7 @@ moral equivalent of the segment-reduce in BASELINE.json's north star, with the
 segmentation made trivial by alignment.
 """
 
+import jax
 import jax.numpy as jnp
 
 from gamesmanmpi_tpu.core.values import (
@@ -21,6 +22,7 @@ from gamesmanmpi_tpu.core.values import (
 )
 
 
+@jax.named_scope("combine")
 def combine_children(child_values, child_remoteness, mask):
     """Combine child results into parent (value, remoteness).
 

@@ -7,6 +7,7 @@ dedups each frontier wholesale with sort + neighbor-compare + resort, a
 static-shape O(n log n) pattern XLA maps well (SURVEY.md §7 "Dedup at scale").
 """
 
+import jax
 import jax.numpy as jnp
 
 from gamesmanmpi_tpu.core.bitops import sentinel_for
@@ -39,6 +40,7 @@ def compaction_sort_bytes(itemsize: int) -> int:
     return itemsize if compact_method() == "resort" else 0
 
 
+@jax.named_scope("dedup")
 def compact_sorted(s, keep, merge: bool | None = None,
                    compact: str | None = None):
     """Compact kept entries of a SORTED array to the front (sorted order
@@ -60,6 +62,7 @@ def compact_sorted(s, keep, merge: bool | None = None,
     return _sort(jnp.where(keep, s, sentinel), merge)
 
 
+@jax.named_scope("dedup")
 def sort_unique(states, merge: bool | None = None,
                 compact: str | None = None):
     """Sort states, drop duplicates/sentinels, compact to the front.

@@ -149,6 +149,7 @@ def _nvalid_or_full(flat, nvalid):
     return jnp.minimum(nvalid.astype(jnp.int32), jnp.int32(flat.shape[0]))
 
 
+@jax.named_scope("dedup")
 def fused_sort_unique(flat, nvalid=None, method: str | None = None,
                       merge: bool | None = None, compact: str | None = None):
     """sort_unique with the fused lowering: [N] -> (uniq [N], count).
@@ -176,6 +177,7 @@ def fused_sort_unique(flat, nvalid=None, method: str | None = None,
     return sort_unique(flat, merge, compact)
 
 
+@jax.named_scope("dedup")
 def fused_dedup_provenance(flat, nvalid=None, method: str | None = None,
                            merge: bool | None = None,
                            compact: str | None = None):

@@ -16,6 +16,7 @@ This kernel is the backward pass's dominant cost; these two choices are what
 took the r02 solve off the 8x-slower-than-CPU floor.
 """
 
+import jax
 import jax.numpy as jnp
 
 from gamesmanmpi_tpu.core.bitops import sentinel_for
@@ -38,6 +39,7 @@ def search_method() -> str:
     )
 
 
+@jax.named_scope("lookup")
 def lookup_sorted(keys, table_states, table_values, table_remoteness,
                   method: str | None = None):
     """Look keys up in one sorted solved level.
@@ -79,6 +81,7 @@ def lookup_sorted(keys, table_states, table_values, table_remoteness,
     return values, remoteness, hit
 
 
+@jax.named_scope("lookup")
 def lookup_window(keys, window, method: str | None = None):
     """Look keys up across a window of solved levels.
 

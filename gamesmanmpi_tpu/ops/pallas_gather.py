@@ -85,6 +85,7 @@ def _dyn_gather(x, idx, axis: int):
         mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
 
 
+@jax.named_scope("gather")
 def cells_table_gather(cells, idx, valid):
     """Direct-address packed-cell gather: cells[idx] where valid, else 0.
 

@@ -35,6 +35,7 @@ from gamesmanmpi_tpu.ops.dedup import compact_sorted
 from gamesmanmpi_tpu.ops.mergesort import sort_with_payload
 
 
+@jax.named_scope("dedup")
 def dedup_provenance(flat, merge: bool | None = None,
                      compact: str | None = None):
     """Sort-unique [N] states AND report where each input slot landed.
@@ -64,6 +65,7 @@ def dedup_provenance(flat, merge: bool | None = None,
     return uniq, count, uidx
 
 
+@jax.named_scope("gather")
 def gather_cells(uidx, wvals, wrem):
     """Packed (value, remoteness) cells for stored unique-indices.
 

@@ -207,6 +207,14 @@ def _fetch_global(arr) -> np.ndarray:
     return np.asarray(arr)
 
 
+def _all_to_all(x):
+    """The owner-routed exchange: a tiled all_to_all over the mesh axis,
+    under the ``route`` scope (docs/OBSERVABILITY.md "Device scopes")."""
+    with jax.named_scope("route"):
+        return jax.lax.all_to_all(x, AXIS, split_axis=0, concat_axis=0,
+                                  tiled=True)
+
+
 def _route_by_owner(flat, S: int, cap_out: int, sentinel):
     """Bucket a flat state array by owner shard for all_to_all routing.
 
@@ -267,8 +275,7 @@ def _sharded_forward_step(game: TensorGame, S: int, route_cap: int, local,
     send, counts, s_owner, pos, order = _route_by_owner(
         flat, S, route_cap, sentinel
     )
-    routed = jax.lax.all_to_all(send, AXIS, split_axis=0, concat_axis=0,
-                                tiled=True)
+    routed = _all_to_all(send)
     # fused (ISSUE 14): the dedup after the route runs through the fused
     # rank/sort+dedup stage (ops/fused) — per-shard callback on CPU,
     # single-pair-sort scatterinv on accelerators. The routed buffer has
@@ -296,10 +303,7 @@ def _sharded_forward_step(game: TensorGame, S: int, route_cap: int, local,
     # uidx is in routed layout (row i = slots received from source i), so
     # the return all_to_all lands row o of the parent's eidx with the uids
     # of the children it sent to owner o, in routing order.
-    eidx = jax.lax.all_to_all(
-        uidx.reshape(S, route_cap), AXIS, split_axis=0, concat_axis=0,
-        tiled=True,
-    )
+    eidx = _all_to_all(uidx.reshape(S, route_cap))
     # slot[j]: where child slot j's reply lives in eidx.reshape(-1). Out-of-
     # range rows (sentinel children, owner==S) and overflow (pos >=
     # route_cap — the host retries it at a larger capacity) map to -1.
@@ -328,8 +332,7 @@ def _route_core(game: TensorGame, S: int, qcap: int, local):
     send, qcounts, s_owner, pos, order = _route_by_owner(
         flat, S, qcap, sentinel
     )
-    queries = jax.lax.all_to_all(send, AXIS, split_axis=0, concat_axis=0,
-                                 tiled=True)
+    queries = _all_to_all(send)
     return queries, qcounts, s_owner, pos, order
 
 
@@ -406,8 +409,7 @@ def _sharded_backward_step(game: TensorGame, S: int, qcap: int, local,
         )
         vals, rems, _ = lookup_window(queries.reshape(-1), window, method)
         reply = pack_cells(vals, rems).reshape(S, qcap)
-        reply = jax.lax.all_to_all(reply, AXIS, split_axis=0, concat_axis=0,
-                                   tiled=True)
+        reply = _all_to_all(reply)
     values, remoteness, misses = _reply_core(
         game, S, qcap, local, reply, s_owner, pos, order
     )
@@ -468,8 +470,7 @@ def _sharded_reply_step(game: TensorGame, S: int, qcap: int, local, acc,
     [B, M] child layout, and combined.
     """
     local = local[0]
-    reply = jax.lax.all_to_all(acc[0], AXIS, split_axis=0, concat_axis=0,
-                               tiled=True)
+    reply = _all_to_all(acc[0])
     values, remoteness, misses = _reply_core(
         game, S, qcap, local, reply, s_owner[0], pos[0], order[0]
     )
@@ -487,7 +488,7 @@ def _sharded_edges_route_step(S: int, ecap: int, eidx):
     accumulator (one extra output — same rationale as _sharded_route_step).
     """
     e = eidx[0].reshape(S, ecap)
-    q = jax.lax.all_to_all(e, AXIS, split_axis=0, concat_axis=0, tiled=True)
+    q = _all_to_all(e)
     acc = jnp.zeros((S * ecap,), dtype=jnp.uint32)
     return q.reshape(-1)[None], acc[None]
 
@@ -522,10 +523,7 @@ def _sharded_edges_reply_step(game: TensorGame, S: int, ecap: int, local,
     the consistency counter tracks only zero-move non-primitive rows.
     """
     local = local[0]
-    reply = jax.lax.all_to_all(
-        acc[0].reshape(S, ecap), AXIS, split_axis=0, concat_axis=0,
-        tiled=True,
-    ).reshape(-1)
+    reply = _all_to_all(acc[0].reshape(S, ecap)).reshape(-1)
     sl = slot[0]
     got = jnp.where(
         sl >= 0, reply[jnp.clip(sl, 0, reply.shape[0] - 1)], jnp.uint32(0)
@@ -3037,7 +3035,7 @@ class ShardedSolver:
             "bytes_host": self.edges_bytes_spilled + self.ckpt_bytes_raw,
             "roofline": roofline_stats(
                 self.bytes_sorted + self.bytes_gathered,
-                num_positions, t_total, self.dispatch_total,
+                num_positions, t_total,
                 chips=(self.S if jax.devices()[0].platform != "cpu"
                        else 1),
             ),

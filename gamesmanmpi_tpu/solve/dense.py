@@ -80,6 +80,7 @@ import jax.numpy as jnp
 
 from gamesmanmpi_tpu.core.values import LOSE, TIE
 from gamesmanmpi_tpu.games.connect4 import Connect4
+from gamesmanmpi_tpu.obs import Span, trace_span
 from gamesmanmpi_tpu.ops.combine import combine_children
 from gamesmanmpi_tpu.solve.engine import get_kernel, schedule_kernel
 from gamesmanmpi_tpu.solve.precompile import sds
@@ -632,34 +633,40 @@ def build_dense_step(tables: DenseTables, level: int, cblock: int,
 
     def step(rank0, child_cells, binom, cellidx, filled, newbit,
              valid, move_row, move_fill, child_cellidx, snapk):
+        # Scopes (docs/OBSERVABILITY.md): expand = unrank + line tests,
+        # lookup = child rank, gather = the u8 child-cell fetch, combine.
         P = filled.shape[0]
-        ranks = (rank0.astype(rank_dtype)
-                 + jax.lax.iota(rank_dtype, cblock)[None, :])  # [1, cb]
+        with jax.named_scope("expand"):
+            ranks = (rank0.astype(rank_dtype)
+                     + jax.lax.iota(rank_dtype, cblock)[None, :])  # [1, cb]
 
-        p1 = _unrank_bits(ranks, n1, binom, cellidx, bitpos, dt, rank_dtype,
-                          use_onehot)
-        p2 = filled[:, None] ^ p1
-        mover = p1 if mover_is_p1 else p2
-        current = p2 if mover_is_p1 else p1
+            p1 = _unrank_bits(ranks, n1, binom, cellidx, bitpos, dt,
+                              rank_dtype, use_onehot)
+            p2 = filled[:, None] ^ p1
+            mover = p1 if mover_is_p1 else p2
+            current = p2 if mover_is_p1 else p1
 
-        mover_line = _connected_fold(mover, h, connect, dt)
-        current_line = _connected_fold(current, h, connect, dt)
+            mover_line = _connected_fold(mover, h, connect, dt)
+            current_line = _connected_fold(current, h, connect, dt)
 
         # mover_line: the player to move already lost. current_line without
         # mover_line: unreachable garbage — terminal-ize it so it never
         # fans out gathers (value is arbitrary; nothing real reads it).
         # Full board without lines: TIE.
         if is_top:
-            return jnp.where(
-                mover_line | current_line, jnp.uint8(LOSE), jnp.uint8(TIE)
-            )  # remoteness 0 everywhere at the top level
+            with jax.named_scope("combine"):
+                return jnp.where(
+                    mover_line | current_line, jnp.uint8(LOSE),
+                    jnp.uint8(TIE),
+                )  # remoteness 0 everywhere at the top level
         prim_mask = mover_line | current_line
 
         if fused_rank:
-            cranks = _rank_all_moves_fused(
-                p1, binom, cellidx, snapk, bitpos, rank_dtype, use_onehot,
-                p1_moves, w, h,
-            )
+            with jax.named_scope("lookup"):
+                cranks = _rank_all_moves_fused(
+                    p1, binom, cellidx, snapk, bitpos, rank_dtype,
+                    use_onehot, p1_moves, w, h,
+                )
         if gather_mode == "pallas":
             # Window-pad the child table ONCE per step so the kernel's
             # internal pad (a full-table copy) is a no-op for all w move
@@ -668,88 +675,92 @@ def build_dense_step(tables: DenseTables, level: int, cblock: int,
 
             m = child_cells.shape[0]
             tpad = padded_table_len(m, PALLAS_WINDOW) - m
-            child_cells_pal = (
-                jnp.concatenate(
-                    [child_cells, jnp.zeros((tpad,), child_cells.dtype)]
-                ) if tpad else child_cells
-            )
+            with jax.named_scope("gather"):
+                child_cells_pal = (
+                    jnp.concatenate(
+                        [child_cells, jnp.zeros((tpad,), child_cells.dtype)]
+                    ) if tpad else child_cells
+                )
         child_vals = []
         child_rems = []
         masks = []
         for c in range(w):
-            if fused_rank:
-                crank = cranks[c]
-            else:
-                cbits = (p1 | newbit[:, c : c + 1]) if p1_moves else p1
-                crank = _rank_bits(cbits, binom, child_cellidx[:, :, c],
-                                   bitpos, dt, rank_dtype, use_onehot)
-            flat = (move_row[:, c : c + 1].astype(flat_dtype)
-                    * flat_dtype(Cc) + crank.astype(flat_dtype))
-            ok = valid[:, c : c + 1] & jnp.ones((1, cblock), bool)
-            if gather_mode in ("sorted", "pallas"):
-                # Invalid rows and pad lanes (rank >= C in the last block,
-                # whose unranked bits are garbage) get a monotone fill —
-                # invalid rows the previous valid row's LAST slot (or 0
-                # before any valid row), pad lanes their own row's last
-                # slot — keeping the flat vector globally non-decreasing
-                # so the gather may stream instead of scattering reads.
-                in_range = ranks < rank_dtype(C)  # [1, cb]
-                fillr = jnp.where(
-                    valid[:, c : c + 1],
-                    move_row[:, c : c + 1],
-                    move_fill[:, c : c + 1],
-                ).astype(flat_dtype)
-                fill = jnp.where(
-                    fillr < 0, flat_dtype(0),
-                    fillr * flat_dtype(Cc) + flat_dtype(Cc - 1),
-                )
-                flat = jnp.where(ok & in_range, flat, fill)
-
-                def _xla_sorted(f=flat):
-                    return child_cells.at[f.reshape(-1)].get(
-                        indices_are_sorted=True, mode="clip"
-                    ).reshape(f.shape)
-
-                if gather_mode == "pallas":
-                    from gamesmanmpi_tpu.ops.pallas_gather import (
-                        monotone_window_gather,
-                    )
-
-                    # flat stays in flat_dtype (int64 for 6x6+): the
-                    # kernel wrapper derives block-local int32 offsets
-                    # outside Mosaic.
-                    out, nmiss = monotone_window_gather(
-                        child_cells_pal, flat.reshape(-1),
-                        block=PALLAS_BLOCK, window=PALLAS_WINDOW,
-                        interpret=pallas_interpret,
-                    )
-                    cell = jax.lax.cond(
-                        nmiss == jnp.int32(0),
-                        lambda: out.reshape(flat.shape),
-                        _xla_sorted,
-                    )
+            with jax.named_scope("lookup"):
+                if fused_rank:
+                    crank = cranks[c]
                 else:
-                    cell = _xla_sorted()
-            else:
-                cell = child_cells[
-                    jnp.clip(flat, 0, child_cells.shape[0] - 1)
-                ]
-            child_vals.append(cell & jnp.uint8(3))
-            child_rems.append((cell >> jnp.uint8(2)).astype(jnp.int32))
+                    cbits = (p1 | newbit[:, c : c + 1]) if p1_moves else p1
+                    crank = _rank_bits(cbits, binom, child_cellidx[:, :, c],
+                                       bitpos, dt, rank_dtype, use_onehot)
+                flat = (move_row[:, c : c + 1].astype(flat_dtype)
+                        * flat_dtype(Cc) + crank.astype(flat_dtype))
+                ok = valid[:, c : c + 1] & jnp.ones((1, cblock), bool)
+            with jax.named_scope("gather"):
+                if gather_mode in ("sorted", "pallas"):
+                    # Invalid rows and pad lanes (rank >= C in the last block,
+                    # whose unranked bits are garbage) get a monotone fill —
+                    # invalid rows the previous valid row's LAST slot (or 0
+                    # before any valid row), pad lanes their own row's last
+                    # slot — keeping the flat vector globally non-decreasing
+                    # so the gather may stream instead of scattering reads.
+                    in_range = ranks < rank_dtype(C)  # [1, cb]
+                    fillr = jnp.where(
+                        valid[:, c : c + 1],
+                        move_row[:, c : c + 1],
+                        move_fill[:, c : c + 1],
+                    ).astype(flat_dtype)
+                    fill = jnp.where(
+                        fillr < 0, flat_dtype(0),
+                        fillr * flat_dtype(Cc) + flat_dtype(Cc - 1),
+                    )
+                    flat = jnp.where(ok & in_range, flat, fill)
+
+                    def _xla_sorted(f=flat):
+                        return child_cells.at[f.reshape(-1)].get(
+                            indices_are_sorted=True, mode="clip"
+                        ).reshape(f.shape)
+
+                    if gather_mode == "pallas":
+                        from gamesmanmpi_tpu.ops.pallas_gather import (
+                            monotone_window_gather,
+                        )
+
+                        # flat stays in flat_dtype (int64 for 6x6+): the
+                        # kernel wrapper derives block-local int32 offsets
+                        # outside Mosaic.
+                        out, nmiss = monotone_window_gather(
+                            child_cells_pal, flat.reshape(-1),
+                            block=PALLAS_BLOCK, window=PALLAS_WINDOW,
+                            interpret=pallas_interpret,
+                        )
+                        cell = jax.lax.cond(
+                            nmiss == jnp.int32(0),
+                            lambda: out.reshape(flat.shape),
+                            _xla_sorted,
+                        )
+                    else:
+                        cell = _xla_sorted()
+                else:
+                    cell = child_cells[
+                        jnp.clip(flat, 0, child_cells.shape[0] - 1)
+                    ]
+                child_vals.append(cell & jnp.uint8(3))
+                child_rems.append((cell >> jnp.uint8(2)).astype(jnp.int32))
             masks.append(ok)
 
-        cv = jnp.stack(child_vals, axis=-1).reshape(P * cblock, w)
-        cr = jnp.stack(child_rems, axis=-1).reshape(P * cblock, w)
-        mk = (jnp.stack(masks, axis=-1)
-              & ~prim_mask[..., None]).reshape(P * cblock, w)
-        values, rem_out = combine_children(cv, cr, mk)
-        values = values.reshape(P, cblock)
-        rem_out = rem_out.reshape(P, cblock)
+        with jax.named_scope("combine"):
+            cv = jnp.stack(child_vals, axis=-1).reshape(P * cblock, w)
+            cr = jnp.stack(child_rems, axis=-1).reshape(P * cblock, w)
+            mk = (jnp.stack(masks, axis=-1)
+                  & ~prim_mask[..., None]).reshape(P * cblock, w)
+            values, rem_out = combine_children(cv, cr, mk)
+            values = values.reshape(P, cblock)
+            rem_out = rem_out.reshape(P, cblock)
 
-        values = jnp.where(prim_mask, jnp.uint8(LOSE), values)
-        rem_out = jnp.where(prim_mask, 0, rem_out)
-        return values | (jnp.clip(rem_out, 0, 63).astype(jnp.uint8)
-                         << jnp.uint8(2))
+            values = jnp.where(prim_mask, jnp.uint8(LOSE), values)
+            rem_out = jnp.where(prim_mask, 0, rem_out)
+            return values | (jnp.clip(rem_out, 0, 63).astype(jnp.uint8)
+                             << jnp.uint8(2))
 
     # Not jitted here: engine.get_kernel / schedule_kernel jit the builder's
     # return value themselves.
@@ -1370,15 +1381,16 @@ class DenseSolver:
 
     # -- reachability sweep -------------------------------------------------
 
-    def _maybe_drain(self, added_cells: int, ref) -> bool:
+    def _maybe_drain(self, added_cells: int, ref, level: int) -> bool:
         """Run-ahead control shared by every dense level loop (sweep,
         backward, and the hybrid's copies of both): after sync_cells cells
         of async dispatch, force a 1-byte fetch so the host cannot enqueue
         every level's buffers before any kernel retires (the round-2 OOM;
-        see __init__)."""
+        see __init__). The fetch waits out the device: a ``drain`` span."""
         self._undrained = getattr(self, "_undrained", 0) + added_cells
         if self._undrained > self.sync_cells:
-            np.asarray(ref[:1])
+            with trace_span("drain", level=level):
+                np.asarray(ref[:1])
             self._undrained = 0
             return True
         return False
@@ -1414,7 +1426,7 @@ class DenseSolver:
             if nblk * cblock != C:
                 level_reach = level_reach[:, :C]
             reach_flat = self._replicate(level_reach.reshape(-1))
-            self._maybe_drain(len(t.profiles[L]) * C, reach_flat)
+            self._maybe_drain(len(t.profiles[L]) * C, reach_flat, L)
             counts_dev[L] = cnt
         counts = {0: 1}
         counts.update({L: int(v) for L, v in counts_dev.items()})
@@ -1524,27 +1536,24 @@ class DenseSolver:
         for L in range(start_L, -1, -1):
             P = len(t.profiles[L])
             C = t.class_size[L]
+            sp = Span("dense_backward", logger=self.logger, level=L,
+                      classes=P, class_size=C)
             encodable_total += P * C
             computed_encodable += P * C
             level_cells = self._backward_level(L, child_flat)
             child_flat = self._replicate(level_cells.reshape(-1))
-            drained = self._maybe_drain(P * C, child_flat)
-            if self.logger is not None:
-                rec = {
-                    "phase": "dense_backward", "level": L, "classes": P,
-                    "class_size": C,
-                }
-                if drained:
-                    now = time.perf_counter()
-                    rec["secs_since_last_drain"] = round(now - last_drain, 4)
-                    last_drain = now
-                self.logger.log(rec)
+            if self._maybe_drain(P * C, child_flat, L):
+                now = time.perf_counter()
+                sp.set(secs_since_last_drain=round(now - last_drain, 4))
+                last_drain = now
             if saved is not None:
-                saved[L] = np.asarray(level_cells).reshape(P, C)
+                with trace_span("download", level=L, bytes=P * C):
+                    saved[L] = np.asarray(level_cells).reshape(P, C)
             if self.checkpointer is not None:
                 self.checkpointer.save_dense_level(
                     L, np.asarray(level_cells)
                 )
+            sp.end()
 
         root_cell = int(jnp.reshape(child_flat, (-1,))[0])
         value, remoteness = root_cell & 3, root_cell >> 2

@@ -59,6 +59,7 @@ there are three defenses, in order of importance:
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Dict, NamedTuple, Optional
 
@@ -211,21 +212,14 @@ def _counted(kind: str, fn):
 
 
 def roofline_stats(hbm_bytes: int, positions: int, wall_secs: float,
-                   dispatches: int, chips: int = 1) -> dict:
+                   chips: int = 1) -> dict:
     """The ISSUE 15 roofline rollup both engines put in their stats and
-    bench.py folds into the record: analytic HBM operand throughput,
-    per-chip solve rate, and the wall fraction spent on dispatch
-    overhead (dispatch count x the host-calibrated per-dispatch cost,
-    ``GAMESMAN_DISPATCH_COST_SECS`` — bench.py measures it; uncalibrated
-    processes report 0.0, never a guess)."""
+    bench.py folds into the record: analytic HBM operand throughput and
+    per-chip solve rate."""
     wall = max(float(wall_secs), 1e-9)
-    cost = env_float("GAMESMAN_DISPATCH_COST_SECS", 0.0)
     return {
         "operand_gbps": round(hbm_bytes / wall / 1e9, 3),
         "pps_per_chip": round(positions / wall / max(int(chips), 1), 1),
-        "dispatch_overhead_frac": round(
-            min(int(dispatches) * cost / wall, 1.0), 6
-        ),
     }
 
 
@@ -297,6 +291,19 @@ def _sweep_stale_kernels() -> None:
         _KERNELS_EPOCH = epoch
 
 
+def _named(fn, kind: str):
+    """A kernel's traced function, named after its kind: JAX names the
+    program ``jit_<kind>`` (``jit_dense_step``, ``jit_fwdp``), which is
+    what a profiler trace's reduction keys on."""
+
+    @functools.wraps(fn)
+    def kernel(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    kernel.__name__ = kernel.__qualname__ = kind
+    return kernel
+
+
 def get_kernel(game: TensorGame, kind: str, shape_key, builder,
                lowering=(), jit_kwargs=None):
     # Games whose identity is per-instance (TensorizedModule: host callbacks
@@ -314,11 +321,16 @@ def get_kernel(game: TensorGame, kind: str, shape_key, builder,
         # waiting out its residual beats restarting a 15 s remote compile.
         pre = global_precompiler()
         if pre.scheduled(key):
-            compiled = pre.get(key, block=True)
+            compiled = pre.get(key, block=False)
+            if compiled is None and pre.scheduled(key):
+                # Still compiling: the solve waits, and says so.
+                with trace_span("kernel_wait", kind=kind):
+                    compiled = pre.get(key, block=True)
             if compiled is not None:
                 cache[key] = compiled
                 return _counted(kind, compiled)
-        fn = cache[key] = jax.jit(builder(game), **(jit_kwargs or {}))
+        fn = cache[key] = jax.jit(_named(builder(game), kind),
+                                  **(jit_kwargs or {}))
     return _counted(kind, fn)
 
 
@@ -346,7 +358,8 @@ def schedule_kernel(game: TensorGame, kind: str, shape_key, builder, avals,
     pre = global_precompiler()
     if pre.scheduled(key):
         return
-    pre.schedule(key, jax.jit(builder(game), **(jit_kwargs or {})),
+    pre.schedule(key, jax.jit(_named(builder(game), kind),
+                              **(jit_kwargs or {})),
                  tuple(avals), heavy=heavy)
 
 
@@ -387,11 +400,18 @@ def canonical_scalar(game: TensorGame, state):
     return game.state_dtype(np.asarray(c)[0]), int(np.asarray(lvl)[0])
 
 
+def _table_bytes(table: LevelTable) -> int:
+    """Host bytes of one level's table (what a ``download`` span moved)."""
+    return int(table.states.nbytes + table.values.nbytes
+               + table.remoteness.nbytes)
+
+
 def undecided_mask(game: TensorGame, states):
     """Which lanes hold real, non-terminal positions: [B] bool."""
     return (states != game.sentinel) & (game.primitive(states) == UNDECIDED)
 
 
+@jax.named_scope("expand")
 def canonical_children(game: TensorGame, states, active):
     """expand + canonicalize + deactivate parents + sentinel-fill.
 
@@ -408,6 +428,7 @@ def canonical_children(game: TensorGame, states, active):
     return children, mask
 
 
+@jax.named_scope("expand")
 def expand_core(game: TensorGame, states, merge: bool | None = None,
                 compact: str | None = None):
     """Shared expand+mask+dedup: [B] -> (uniq [B*M] sorted, count).
@@ -419,6 +440,7 @@ def expand_core(game: TensorGame, states, merge: bool | None = None,
     return sort_unique(children.reshape(-1), merge, compact)
 
 
+@jax.named_scope("expand")
 def expand_provenance(game: TensorGame, states, merge: bool | None = None,
                       compact: str | None = None):
     """Forward expand that also keeps the dedup sort's provenance.
@@ -444,6 +466,7 @@ def expand_provenance(game: TensorGame, states, merge: bool | None = None,
     return uniq, count, uidx, prim
 
 
+@jax.named_scope("gather")
 def resolve_provenance(n, prim, uidx, wvals, wrem, max_moves: int):
     """Backward resolve from stored provenance: gathers + combine only.
 
@@ -472,6 +495,7 @@ def resolve_provenance(n, prim, uidx, wvals, wrem, max_moves: int):
     return values, remoteness, misses
 
 
+@jax.named_scope("expand")
 def expand_with_levels(game: TensorGame, states, merge: bool | None = None,
                        compact: str | None = None):
     """Generic-path forward: expand_core + each child's topological level."""
@@ -500,6 +524,7 @@ def _chain_to_cap(buf, cap: int, sentinel):
     )
 
 
+@jax.named_scope("expand")
 def fused_forward_step(game: TensorGame, states, n, keep_children: bool,
                        method: str | None, merge: bool | None,
                        compact: str | None):
@@ -525,6 +550,7 @@ def fused_forward_step(game: TensorGame, states, n, keep_children: bool,
     return states, uniq, count, prim, uidx
 
 
+@jax.named_scope("expand")
 def expand_with_levels_fused(game: TensorGame, states, n,
                              method: str | None, merge: bool | None,
                              compact: str | None):
@@ -540,6 +566,7 @@ def expand_with_levels_fused(game: TensorGame, states, n,
     return uniq, levels, count
 
 
+@jax.named_scope("gather")
 def fused_table_resolve(game: TensorGame, cells, states, prim, kids,
                         table_len: int):
     """One fused backward level against the persistent value table.
@@ -623,6 +650,7 @@ def _make_bwdt_builder(has_kids: bool, table_len: int):
     return build
 
 
+@jax.named_scope("lookup")
 def resolve_level(game: TensorGame, states, window,
                   method: str | None = None):
     """[B] states + solved deeper levels -> (values, remoteness, misses).
@@ -1764,11 +1792,13 @@ class Solver:
                     or self.level_sink is not None
                 ):
                     note_dispatch("download")
-                    tbl = LevelTable(
-                        states=rec.host_states(),
-                        values=np.asarray(values_dev[:n]),
-                        remoteness=np.asarray(rem_dev[:n]),
-                    )
+                    with trace_span("download", level=k) as dl:
+                        tbl = LevelTable(
+                            states=rec.host_states(),
+                            values=np.asarray(values_dev[:n]),
+                            remoteness=np.asarray(rem_dev[:n]),
+                        )
+                        dl.set(bytes=_table_bytes(tbl))
                 if tbl is not None and (self.store_tables
                                         or k == root_level):
                     resolved[k] = tbl
@@ -1797,7 +1827,8 @@ class Solver:
             if not from_checkpoint and cap >= (1 << 21):
                 # Same enqueue-run-ahead bound as the unfused path: one
                 # 8-byte fetch per BIG level caps liveness.
-                np.asarray(misses)
+                with trace_span("drain", level=k):
+                    np.asarray(misses)
             item = np.dtype(g.state_dtype).itemsize
             lvl_host_bytes = self._level_host_bytes(
                 k, root_level, cap, n, item, uploaded, from_checkpoint
@@ -1985,11 +2016,13 @@ class Solver:
                     or self.level_sink is not None
                 ):
                     note_dispatch("download")
-                    tbl = LevelTable(
-                        states=rec.host_states(),
-                        values=np.asarray(values_dev[:n]),
-                        remoteness=np.asarray(rem_dev[:n]),
-                    )
+                    with trace_span("download", level=k) as dl:
+                        tbl = LevelTable(
+                            states=rec.host_states(),
+                            values=np.asarray(values_dev[:n]),
+                            remoteness=np.asarray(rem_dev[:n]),
+                        )
+                        dl.set(bytes=_table_bytes(tbl))
                 if tbl is not None and (self.store_tables
                                         or k == root_level):
                     resolved[k] = tbl
@@ -2020,7 +2053,8 @@ class Solver:
                 # to OOM HBM at 5x5 scale. An 8-byte fetch (~65 ms) per BIG
                 # level caps liveness at ~one level's working set; small
                 # levels stay fully async.
-                np.asarray(misses)
+                with trace_span("drain", level=k):
+                    np.asarray(misses)
             self.bytes_sorted += lvl_sort_bytes
             self.bytes_gathered += lvl_gather_bytes
             lvl_host_bytes = self._level_host_bytes(
@@ -2220,10 +2254,11 @@ class Solver:
                         "max_level_jump inconsistent — or non-primitive "
                         "positions with zero legal moves)"
                     )
-                values = np.asarray(values_dev[:n])
-                remoteness = np.asarray(rem_dev[:n])
-                table = LevelTable(states=states, values=values,
-                                   remoteness=remoteness)
+                with trace_span("download", level=k) as dl:
+                    table = LevelTable(states=states,
+                                       values=np.asarray(values_dev[:n]),
+                                       remoteness=np.asarray(rem_dev[:n]))
+                    dl.set(bytes=_table_bytes(table))
             if self.store_tables or k == root_level:
                 resolved[k] = table
             if self.level_sink is not None:
@@ -2444,14 +2479,10 @@ class Solver:
             "pipeline": self.pipeline,
             # ISSUE 15 roofline accounting: the per-solve rollup of the
             # per-level bytes_hbm/bytes_host/dispatches/wall span fields.
-            # dispatch_overhead_frac prices the dispatch count against a
-            # measured per-dispatch cost (bench.py calibrates
-            # GAMESMAN_DISPATCH_COST_SECS on the running host; 0 = not
-            # calibrated, the fraction reads 0 rather than invented).
             "bytes_host": self.bytes_host,
             "roofline": roofline_stats(
                 self.bytes_sorted + self.bytes_gathered,
-                num_positions, t_total, self.dispatch_total, chips=1,
+                num_positions, t_total, chips=1,
             ),
         }
         self.progress = {"phase": "done"}

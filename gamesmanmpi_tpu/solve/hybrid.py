@@ -718,7 +718,7 @@ class HybridSolver:
             C = t.class_size[L]
             cells = d._backward_level(L, child_flat)
             child_flat = d._replicate(cells.reshape(-1))
-            d._maybe_drain(P * C, child_flat)
+            d._maybe_drain(P * C, child_flat, L)
             if saved is not None:
                 saved[L] = np.asarray(cells).reshape(P, C)
 

@@ -75,14 +75,10 @@ def test_bench_smoke_fused_contract():
     assert ab["fused"]["dispatches_per_level"] \
         < ab["unfused"]["dispatches_per_level"]
     assert ab["speedup"] > 0
-    # ISSUE 15 roofline fields: present, with a MEASURED (calibrated)
-    # per-dispatch cost pricing a nonzero overhead fraction.
+    # Roofline fields: operand throughput and per-chip rate.
     rf = record["roofline"]
-    assert set(rf) == {"operand_gbps", "pps_per_chip",
-                       "dispatch_overhead_frac"}
+    assert set(rf) == {"operand_gbps", "pps_per_chip"}
     assert rf["pps_per_chip"] == record["value"]
-    assert 0 < rf["dispatch_overhead_frac"] <= 1.0
-    assert "dispatch cost:" in stderr  # the calibration ran
     # The XLA host-feature-mismatch spam is filtered from the forwarded
     # stderr (it dwarfed the run lines in BENCH_r05.json's tail).
     assert "host machine features" not in stderr

@@ -16,7 +16,7 @@ headline value against the trajectory's best. The command exits
 nonzero (status 1) when ``new / best < --min-ratio`` (default 0.6, env
 ``BENCH_COMPARE_MIN_RATIO``): a regression past the threshold fails
 CI; a pass prints the ratio plus the roofline-field deltas
-(operand_gbps, dispatches/level, dispatch_overhead_frac) so a
+(operand_gbps, dispatches/level) so a
 borderline run is explainable from the output alone. Usage errors
 (unreadable/record-less input) exit with status 2; no comparable
 reference (first record on a new metric or device) passes with a note
@@ -126,11 +126,6 @@ def compare(new: dict, trajectory, min_ratio: float) -> tuple:
         "dispatches_per_level",
         (new.get("dispatches") or {}).get("per_level"),
         (best.get("dispatches") or {}).get("per_level"),
-    ))
-    lines.append(_fmt_delta(
-        "dispatch_overhead_frac",
-        nrf.get("dispatch_overhead_frac"),
-        brf.get("dispatch_overhead_frac"),
     ))
     ab_ok, ab_lines = check_trace_ab(new)
     lines.extend(ab_lines)
