@@ -1,58 +1,58 @@
-"""Pallas monotone-window gather (round-4; the installed Mosaic refuses it).
+"""Pallas monotone-window gather: the dense backward's child-cell fetch.
 
-The TPU compiler of JAX 0.9.0 rejects this kernel ("Not implemented:
-Multiple source vregs along gather dimension" on tpu.dynamic_gather over
-vector<128x128xi32>), for int32 and int64 indices alike;
-tests/test_tpu_compile.py pins that as a strict xfail. It runs only under
-GAMESMAN_DENSE_GATHER=pallas, and in interpret mode on the CPU.
+The dense engine's backward step gathers, per move, one u8 child cell
+per position through a globally NON-DECREASING flat index vector
+(solve/dense.py's monotone fill builds exactly that). XLA's TPU gather
+treats it as random access: ~8 ns per lane on a TPU v5e whatever the
+index order (7.65 s for the 941,638,830 lanes of a Connect-4 6x4 solve).
+A monotone gather can stream instead: each block of outputs reads a
+bounded stretch of the table, so this kernel DMAs two small views of the
+table into VMEM per block and selects from them there (0.19 s for the
+same 6x4 lanes on a v5e, every lane served from the views).
 
-The dense engine's backward step is, per move, one byte-gather with a
-globally NON-DECREASING flat index vector (solve/dense.py sorted-gather
-mode builds exactly that). XLA's TPU gather treats it as random access
-(~9-11 ns/element measured, microbench2 r04: 32M u32 gathers = 357 ms
-regardless of table size or the sorted-indices hint); a monotone gather
-can instead stream: each block of K indices touches a bounded window of
-the table, so the kernel keeps two window-aligned table tiles in VMEM
-and selects locally — HBM traffic becomes sequential tile reads instead
-of per-element transactions.
+Layout. The table is packed ONCE into 32-bit words, [R, 128] int32 with
+R a multiple of 8 (one (8, 128) tile): for an itemsize-s table, word
+(r, l) holds elements ``r*128*e + k*128 + l`` for k < e = 4 // s, byte
+lane k at bits 8*s*k. An element's (row, lane) is then (off // (128*e),
+off % 128), and a tile of 8 rows covers 4096 u8 elements. Views start on
+whole tiles, so every DMA is tile-aligned.
 
-Mosaic constraints that shaped this kernel (verified against the
-installed lowering, jax/_src/pallas/mosaic/lowering.py):
+Views. Each block of B outputs (B = 1024, one [8, 128] vreg) gets two
+views of ``window`` elements: view A starts at the tile holding the
+block's first index, view B ends at the end of the tile holding its last
+index. A block whose lanes each lie within ``window`` of its first or of
+its last index is served whole from VMEM, across one large jump between
+the two, or across the drop from one move's indices to the next. One grid
+step covers up to MAX_STEP_BLOCKS blocks: it starts all their DMAs, then
+waits for and selects each block in turn.
 
-* rank-1 block shapes must be whole-array or 128-multiples — the original
-  per-block (1,) miss-count output could not lower; the miss count is now
-  computed OUTSIDE the kernel (it depends only on idx and the window
-  bases, one fused elementwise XLA pass).
-* `lax.gather` lowers ONLY as 2-D `take_along_axis` with operand, indices
-  and output all the same 2-D shape (tpu.dynamic_gather along dim 0 or
-  dim 1). A rank-1 in-kernel `jnp.take` can never compile. The kernel
-  therefore views the 2-window tile as a [R, 128] matrix and decomposes
-  each offset into (row = off // 128, lane = off % 128):
+What Mosaic accepts (JAX 0.9.0, compiled for a described v5e:2x2):
+``lax.gather`` lowers only as a 2-D ``tpu.dynamic_gather`` whose operand,
+indices and output share one shape. Along the LANE axis (axis 1) it
+compiles at [8, 128], [16, 128] and [64, 128] int32; along the SUBLANE
+axis (axis 0) only at [8, 128] — at 16 or more rows Mosaic refuses with
+"Multiple source vregs along gather dimension", which is what sank the
+first version of this kernel (a sublane row-select over [128, 128]). So
+the kernel selects with lane gathers only: for each view row q it
+broadcasts that row over the block's sublanes, lane-gathers it by
+``off % 128`` and keeps it where ``off // (128*e) == q``; a shift and a
+mask then pick the element's byte out of its word. Further rules it
+keeps: no int64 value enters the kernel (the int64 -> int32 convert
+lowering recurses forever, and every Python int is int64 under x64, so
+each scalar is pinned int32), so int64 indices become int32 offsets
+into their block's two views outside it (-1 where in neither), while
+int32 indices enter as they are; an int32 scalar sum is refused under
+x64, so the miss count is summed in f32; table rows reach the kernel
+already packed 2-D (an in-kernel reshape of a packed dtype is a shape
+cast Mosaic's layout inference refuses), while the indices arrive 1-D,
+in XLA's own layout, and are reshaped to [8, 128] inside; a u8 result is
+stored four blocks at a time, in whole (32, 128) tiles.
 
-      v   = take_along_axis(tile, row*, axis=0)   # sublane row-select
-      out = take_along_axis(v,    lane*, axis=1)[:, 0]  # lane select
-
-  (row*/lane* broadcast to the [R, 128] operand shape), processing R
-  outputs per step so every gather operand/index shape matches.
-
-Contract: monotone_window_gather(table_u32, idx) == table[idx] for
-non-decreasing idx, EXCEPT for elements whose block spans more than one
-window width — those are miss-flagged (out undefined there) and counted;
-the caller sizes `window` so misses are structurally rare and falls back
-to a plain gather when nmiss > 0. The dense child gathers have expansion
-ratio C(L+1,n1')/C(L,n1) <= 2, so window = 4*block covers them with
-margin.
-
-idx may be int32 OR int64 (round 5): the kernel never sees the absolute
-indices — BLOCK-LOCAL offsets (idx - block's window-aligned base, in
-[0, 2*window)) are computed outside in one fused elementwise XLA pass
-and enter Mosaic as int32. int64 inside a Mosaic kernel is a hard
-no-go (the int64->int32 convert lowering recurses forever — r04 chip
-session), but an int64 FLAT INDEX SPACE only needs 64-bit arithmetic
-outside: the per-block window base (window units) stays under 2^31 for
-any table XLA can allocate, and offsets are bounded by 2*window. This
-is what unlocks gather_mode=pallas for int64-flat boards (6x6+), where
-the gather win matters most (solve/dense.py:~1103, VERDICT r4 #3).
+Misses. A lane outside both views gets ``table[idx]`` from a fix-up that
+re-gathers, with XLA, only the blocks that hold such lanes: their ids are
+compacted once, then one loop trip each, so its cost is in proportion to
+the missed blocks, and nothing when there are none. The kernel counts
+the missed lanes of each block; their sum is returned beside the output.
 """
 
 from __future__ import annotations
@@ -60,6 +60,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+TILE_ROWS = 8          # rows of one (8, 128) int32 tile: a view's alignment
+LANES = 128
+GATHER_BLOCK = 1024    # outputs per block: one [8, 128] int32 vreg
+GATHER_WINDOW = 8192   # elements per view: two u8 tiles
+MAX_STEP_BLOCKS = 64   # blocks per grid step (and DMAs in flight: 2x)
 
 
 def _dyn_gather(x, idx, axis: int):
@@ -95,7 +101,7 @@ def cells_table_gather(cells, idx, valid):
     hold sentinel / garbage on invalid lanes). valid: [...] bool.
 
     The gather indices are states in frontier order — NOT monotone — so
-    the monotone-window kernel above does not apply; XLA's plain gather is
+    the monotone-window kernel below does not apply; XLA's plain gather is
     the right lowering on both backends (measured 0.015 s for 4M lanes
     from a 128 MB table on this host's CPU, vs 0.148 s for the binary
     search it replaces). Cell 0 is UNDECIDED, so the same zero doubles as
@@ -111,131 +117,261 @@ def cells_table_gather(cells, idx, valid):
     return jnp.where(valid, cells[safe], jnp.uint32(0))
 
 
-def padded_table_len(m: int, window: int) -> int:
-    """Table length monotone_window_gather pads to internally: a whole
-    number of windows, at least two (so tile q+1 always exists). Callers
-    that gather repeatedly from one table (the dense backward's w
-    per-move gathers) pre-pad to this length once, making the kernel's
-    internal pad a no-op."""
-    return max(-(-m // window), 2) * window
+def _pack_words(table, window: int):
+    """A 1-D table of 1-, 2- or 4-byte elements as [R, 128] int32 words
+    (module docstring), padded to whole tiles and to at least one view."""
+    size = jnp.dtype(table.dtype).itemsize
+    if size not in (1, 2, 4):
+        raise ValueError(f"window gathers need 1/2/4-byte elements, "
+                         f"got {table.dtype}")
+    e = 4 // size
+    row_elems = LANES * e
+    if window % (TILE_ROWS * row_elems):
+        raise ValueError(f"window ({window}) must be a multiple of "
+                         f"{TILE_ROWS * row_elems} for {table.dtype}")
+    m = table.shape[0]
+    rows = max(-(-m // (TILE_ROWS * row_elems)) * TILE_ROWS,
+               window // row_elems)
+    if rows * row_elems > m:
+        table = jnp.concatenate(
+            [table, jnp.zeros((rows * row_elems - m,), table.dtype)])
+    if e == 1:
+        return lax.bitcast_convert_type(table, jnp.int32).reshape(rows, LANES)
+    # Strided row slices of a [rows*e, 128] view: XLA fuses them into one
+    # pass, where a [rows, e, 128] reshape materialises the table widened
+    # to u32 (4.5 GB for 6x5's largest child level).
+    parts = table.reshape(rows * e, LANES)
+    words = parts[0::e].astype(jnp.uint32)
+    for k in range(1, e):
+        words = words | (parts[k::e].astype(jnp.uint32)
+                         << jnp.uint32(8 * size * k))
+    return lax.bitcast_convert_type(words, jnp.int32)
 
 
-def monotone_window_gather(table, idx, block: int = 2048,
-                           window: int = 8192, interpret: bool = False):
-    """table [M], idx [N] int32/int64 non-decreasing ->
-    (out [N] table.dtype, nmiss scalar int32).
+def _view_bases(first, last, rows: int, view_rows: int, row_elems: int):
+    """Tile-aligned first rows of each block's two views (idx's dtype in,
+    int32 rows out): A at the tile of the block's first index, B ending
+    with the tile of its last, both clamped inside the table."""
+    dt = first.dtype
+    tile = dt.type(TILE_ROWS * row_elems)
+    hi = rows - view_rows
+    a = jnp.clip((first // tile) * TILE_ROWS, 0, hi)
+    b = jnp.clip((last // tile + 1) * TILE_ROWS - view_rows, 0, hi)
+    return a.astype(jnp.int32), b.astype(jnp.int32)
 
-    Misses (a block spanning past its 2-window view) leave garbage in
-    `out` at those positions and are counted (when nonzero, the count may
-    include padding replicas of a missing tail element); callers must
-    treat any nonzero nmiss as "fall back to a plain gather".
-    """
+
+def _kernel_call(bases, lidx, words, out_dtype, *, n: int, local: bool,
+                 window: int, block_rows: int, step_blocks: int, interpret):
+    """Run the window kernel.
+
+    bases [nsteps, 1, 2*step_blocks] int32: each block's view A and view
+    B first rows. lidx [nb*block] int32: the indices, or (local) their
+    offsets into the block's two views laid end to end, -1 where in
+    neither; the last grid step may run past its end. n: the real lanes.
+    -> (the selected elements [nb*block_rows, 128] out_dtype, garbage
+    where missed; the missed real lanes of each block
+    [nsteps*step_blocks] int32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if window % 128:
-        raise ValueError(f"window must be a multiple of 128, got {window}")
-    if block % 128:
-        # Mosaic rank-1 block rule (module docstring): fail loudly here,
-        # not with an opaque lowering error on chip.
-        raise ValueError(f"block must be a multiple of 128, got {block}")
-    rows = (2 * window) // 128          # [rows, 128] view of the 2-window tile
-    if block % rows:
-        raise ValueError(
-            f"block ({block}) must be a multiple of 2*window/128 ({rows})")
-    nchunk = block // rows
+    g = step_blocks
+    nsteps = bases.shape[0]
+    per_word = 4 // jnp.dtype(out_dtype).itemsize
+    row_elems = LANES * per_word
+    view_rows = window // row_elems
+    row_shift = row_elems.bit_length() - 1
+    elem_bits = 32 // per_word
+    block = block_rows * LANES
+    # Every scalar below is pinned int32 (module docstring: no int64 in
+    # Mosaic, and under x64 a bare Python int traces as int64).
+    i32 = jnp.int32
 
-    n = idx.shape[0]
-    npad = -n % block
-    if npad:
-        idx = jnp.concatenate([idx, jnp.full((npad,), idx[-1], idx.dtype)])
-    nblk = idx.shape[0] // block
-    # Window-aligned base of each block's view, clamped so tile q+1 exists.
-    m = table.shape[0]
-    padded = padded_table_len(m, window)
-    nwin = padded // window
-    tpad = padded - m
-    if tpad:
-        table = jnp.concatenate(
-            [table, jnp.zeros((tpad,), table.dtype)]
-        )
-    starts = idx[:: block]  # [nblk] first index of each block
-    # All absolute-index arithmetic happens HERE, in idx's own dtype
-    # (int64 for 6x6+ flat spaces): only window-unit bases (< 2^31 for
-    # any allocatable table) and 2*window-bounded offsets reach Mosaic.
-    base_win = jnp.clip(starts // window, 0, nwin - 2).astype(jnp.int32)
-    aligned = base_win.astype(idx.dtype) * idx.dtype.type(window)
+    def select(k, step, lane_no, base_ref, idx_ref, buf, miss_ref):
+        """Block k of this step: its [block_rows, 128] int32 elements."""
+        ja, jb = i32(2) * k, i32(2) * k + i32(1)
+        l0 = pl.multiple_of(k * i32(block), block)
+        ix = idx_ref[pl.ds(l0, block)].reshape(block_rows, LANES)
+        if local:
+            first_a, first_b = i32(0), i32(window)
+        else:
+            first_a = base_ref[0, ja] * i32(row_elems)
+            first_b = base_ref[0, jb] * i32(row_elems)
+        rel_a, rel_b = ix - first_a, ix - first_b
+        in_a = (rel_a >= 0) & (rel_a < i32(window))
+        in_b = (rel_b >= 0) & (rel_b < i32(window))
+        off = jnp.where(in_a, rel_a,
+                        jnp.where(in_b, rel_b + i32(window), i32(0)))
+        gblock = step * i32(g) + k
+        real = (gblock < i32(n // block)) | (
+            (gblock == i32(n // block)) & (lane_no < i32(n % block)))
+        # Counted in f32 (exact up to 2^24): Mosaic refuses an int32 sum
+        # to a scalar under x64.
+        miss_ref[0, k] = jnp.sum(jnp.where(
+            in_a | in_b | ~real, jnp.float32(0), jnp.float32(1))
+        ).astype(i32)
+        row = lax.shift_right_logical(off, i32(row_shift))
+        lane = off & i32(LANES - 1)
+        acc = jnp.zeros((block_rows, LANES), i32)
+        for v, j in ((0, ja), (1, jb)):
+            view = buf[j]                                 # [view_rows, 128]
+            for q in range(view_rows):
+                src = jnp.broadcast_to(view[q:q + 1, :], (block_rows, LANES))
+                got = _dyn_gather(src, lane, axis=1)
+                acc = jnp.where(row == i32(v * view_rows + q), got, acc)
+        if per_word > 1:
+            byte = lax.shift_right_logical(off, i32(7)) & i32(per_word - 1)
+            acc = lax.shift_right_logical(acc, byte * i32(elem_bits)) \
+                & i32((1 << elem_bits) - 1)
+        return acc
 
-    # The table reaches the kernel as a [padded/128, 128] matrix, reshaped
-    # ONCE outside (a free XLA relayout): an in-kernel rank-1 -> rank-2
-    # reshape is a Mosaic shape cast, and for packed dtypes (the dense
-    # engine's u8 cells) layout inference rejects it on chip —
-    # "infer-vector-layout: unsupported shape cast, vector<8192xi8> ->
-    # vector<64x128xi8>" (chip session r04). With 2-D BlockSpecs the tiles
-    # arrive already [window/128, 128] and no shape cast exists for ANY
-    # table dtype.
-    wrows = window // 128
-    table2d = table.reshape(padded // 128, 128)
+    def kernel(base_ref, idx_ref, words_hbm, out_ref, miss_ref, buf, sem,
+               tile):
+        def copy(j):
+            return pltpu.make_async_copy(
+                words_hbm.at[pl.ds(base_ref[0, j], view_rows)],
+                buf.at[j], sem.at[j])
 
-    # Block-local offsets, computed OUTSIDE the kernel (one fused
-    # elementwise XLA pass in idx's dtype) and clamped into the tile:
-    # the kernel receives only these int32 offsets, so an int64 flat
-    # index space never enters Mosaic (module docstring). The miss count
-    # shares the same pass — misses depend only on idx and the window
-    # bases (Mosaic's rank-1 output block rule keeps it out of the
-    # kernel regardless).
-    off_all = idx - jnp.repeat(aligned, block)
-    miss = jnp.sum(((off_all < 0) | (off_all >= 2 * window))
-                   .astype(jnp.int32))
-    off_i32 = jnp.clip(off_all, 0, 2 * window - 1).astype(jnp.int32)
+        @pl.loop(i32(0), i32(2 * g))
+        def _(j):
+            copy(j).start()
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # window-unit tile bases
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i, bw: (i,)),
-            pl.BlockSpec((wrows, 128), lambda i, bw: (bw[i], 0)),
-            pl.BlockSpec((wrows, 128), lambda i, bw: (bw[i] + 1, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i, bw: (i,)),
-        ],
-    )
+        step = pl.program_id(0)
+        lane_no = (lax.broadcasted_iota(i32, (block_rows, LANES), 0)
+                   * i32(LANES)
+                   + lax.broadcasted_iota(i32, (block_rows, LANES), 1))
 
-    def kernel(bw_ref, off_ref, t0_ref, t1_ref, out_ref):
-        # [rows, 128] view of the two window tiles. Sub-32-bit tables (the
-        # dense engine's u8 cells) gather as i32 — Mosaic's dynamic_gather
-        # targets 32-bit lanes; the cast back on store is exact for
-        # unsigned sub-ranges.
-        tile = jnp.concatenate([t0_ref[:], t1_ref[:]], axis=0)
-        if tile.dtype.itemsize < 4:
-            tile = tile.astype(jnp.int32)
-        # All scalars below are pinned int32: under jax_enable_x64 bare
-        # Python ints trace as weak int64 scalars, and ANY int64 in a
-        # Mosaic kernel hits the infinitely-recursing int64->int32
-        # convert lowering (see _dyn_gather's docstring). Chunks are
-        # STATIC rank-1 slices of off_ref — a [nchunk, rows] reshape
-        # would be another Mosaic shape cast (see the tile note above).
-        c128 = jnp.int32(128)
-        for k in range(nchunk):
-            off = off_ref[k * rows:(k + 1) * rows]          # [rows]
-            r = lax.div(off, c128)
-            c = lax.rem(off, c128)
-            v = _dyn_gather(
-                tile, jnp.broadcast_to(r[:, None], (rows, 128)), axis=0)
-            sel = _dyn_gather(
-                v, jnp.broadcast_to(c[:, None], (rows, 128)), axis=1)
-            out_ref[k * rows:(k + 1) * rows] = sel[:, 0].astype(out_ref.dtype)
+        # per_word blocks a trip, a loop rather than unrolled (the kernel's
+        # size sets how long every warm start spends lowering it): a
+        # narrow output is stored in whole (8 * per_word, 128) tiles.
+        @pl.loop(i32(0), i32(g // per_word))
+        def _(t):
+            @pl.loop(i32(0), i32(per_word))
+            def _(u):
+                k = t * i32(per_word) + u
+                copy(i32(2) * k).wait()
+                copy(i32(2) * k + i32(1)).wait()
+                r0 = pl.multiple_of(u * i32(block_rows), TILE_ROWS)
+                tile[pl.ds(r0, block_rows), :] = select(
+                    k, step, lane_no, base_ref, idx_ref, buf, miss_ref)
 
-    (out,) = pl.pallas_call(
+            rows = per_word * block_rows
+            r0 = pl.multiple_of(t * i32(rows), TILE_ROWS * per_word)
+            out_ref[pl.ds(r0, rows), :] = tile[...].astype(out_dtype)
+
+    rows_per_step = g * block_rows
+    step_map = lambda i: (i, i32(0), i32(0))  # noqa: E731
+    out, miss = pl.pallas_call(
         kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct((nblk * block,), table.dtype),
+        out_shape=(jax.ShapeDtypeStruct(
+            (lidx.shape[0] // LANES, LANES), out_dtype),
+                   jax.ShapeDtypeStruct((nsteps, 1, g), i32)),
+        grid=(nsteps,),
+        in_specs=[
+            pl.BlockSpec((None, 1, 2 * g), step_map,
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((g * block,), lambda i: (i,)),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        grid_spec=grid_spec,
+        out_specs=(
+            pl.BlockSpec((rows_per_step, LANES), lambda i: (i, i32(0))),
+            pl.BlockSpec((None, 1, g), step_map, memory_space=pltpu.SMEM),
+        ),
+        scratch_shapes=[
+            pltpu.VMEM((2 * g, view_rows, LANES), i32),
+            pltpu.SemaphoreType.DMA((2 * g,)),
+            pltpu.VMEM((per_word * block_rows, LANES), i32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(base_win, off_i32, table2d, table2d)
-    # Padding lanes replicate idx[-1]; they miss iff the real tail element
-    # misses, so nmiss stays 0 exactly when every real element hit (the
-    # contract callers check). When nonzero it may count tail replicas.
-    return out[:n], miss
+        name="window_gather",
+    )(bases, lidx, words)
+    return out, miss.reshape(-1)
+
+
+def _fix_misses(out, table, idx, missed, block: int):
+    """Re-gather, with XLA, each block that holds a missed lane. The
+    missed blocks' ids are compacted once, then each takes one loop trip:
+    the cost is in proportion to the missed blocks, and nothing runs when
+    none missed. out, idx: [nb*block]; missed [nb]: lanes per block."""
+    nb = missed.shape[0]
+    top = table.shape[0] - 1
+    hit = missed > 0
+    count = jnp.sum(hit, dtype=jnp.int32)
+
+    def fix(o):
+        # A stable sort, not jnp.nonzero: the TPU lowers nonzero's cumsum
+        # as a reduce-window that overflows scoped VMEM at ~1k blocks.
+        _, ids = lax.sort((jnp.where(hit, 0, 1).astype(jnp.int32),
+                           lax.iota(jnp.int32, nb)),
+                          num_keys=1, is_stable=True)
+
+        def one_block(i, o):
+            start = ids[i] * jnp.int32(block)
+            rows = jnp.clip(lax.dynamic_slice(idx, (start,), (block,)),
+                            0, top)
+            return lax.dynamic_update_slice(o, table[rows], (start,))
+
+        return lax.fori_loop(jnp.int32(0), count, one_block, o)
+
+    return lax.cond(count > 0, fix, lambda o: o, out)
+
+
+def monotone_window_gather(table, idx, block: int = GATHER_BLOCK,
+                           window: int = GATHER_WINDOW, interpret=False):
+    """table [M] (1/2/4-byte elements), idx [N] int32/int64
+    non-decreasing -> (out [N] == table[idx], missed lanes: int32 scalar).
+
+    ``block`` outputs share two views of ``window`` elements each (module
+    docstring). Out-of-range indices are clamped, as XLA's gather clamps
+    them. A decreasing idx stays exact, only slower (its lanes miss)."""
+    if block % (TILE_ROWS * LANES):
+        raise ValueError(f"block must be a multiple of "
+                         f"{TILE_ROWS * LANES}, got {block}")
+    table, idx = jnp.asarray(table), jnp.asarray(idx)
+    words = _pack_words(table, window)
+    e = 4 // jnp.dtype(table.dtype).itemsize
+    row_elems = LANES * e
+    view_rows = window // row_elems
+    n = idx.shape[0]
+    nb = -(-n // block)
+    if nb * block > n:
+        idx = jnp.concatenate(
+            [idx, jnp.full((nb * block - n,), idx[-1], idx.dtype)])
+    # Steps of g blocks, the last one possibly partial: Pallas drops its
+    # writes past the end, and the kernel counts no miss there.
+    g = min(MAX_STEP_BLOCKS, -(-nb // e) * e)
+    nsteps = -(-nb // g)
+    a, b = _view_bases(idx[::block], idx[block - 1::block],
+                       words.shape[0], view_rows, row_elems)
+    # int32 indices enter the kernel as they are, and it finds their view
+    # offsets itself: on a TPU v5e a 6x4 solve's gather took 0.242 s so,
+    # against 0.293 s with every index dtype taking the offsets pass below.
+    local = idx.dtype != jnp.int32
+    lidx = idx
+    if local:
+        # Wider indices: their offsets into the two views, found here in
+        # idx's dtype so that only int32 enters the kernel.
+        dt = idx.dtype
+
+        def rel(base):
+            return idx - jnp.repeat(base.astype(dt) * dt.type(row_elems),
+                                    block)
+
+        rel_a, rel_b = rel(a), rel(b)
+        lidx = jnp.where(
+            (rel_a >= 0) & (rel_a < window), rel_a,
+            jnp.where((rel_b >= 0) & (rel_b < window), rel_b + window, -1),
+        ).astype(jnp.int32)
+    bases = jnp.zeros((nsteps * g, 2), jnp.int32).at[:nb].set(
+        jnp.stack([a, b], axis=1))
+    out_dtype = table.dtype if e > 1 else jnp.int32
+    got, missed = _kernel_call(
+        bases.reshape(nsteps, 1, 2 * g), lidx, words, out_dtype, n=n,
+        local=local, window=window, block_rows=block // LANES,
+        step_blocks=g, interpret=interpret)
+    got = got.reshape(-1)
+    out = got if e > 1 else lax.bitcast_convert_type(got, table.dtype)
+    missed = missed[:nb]
+    out = _fix_misses(out, table, idx, missed, block)
+    return out[:n], jnp.sum(missed, dtype=jnp.int32)

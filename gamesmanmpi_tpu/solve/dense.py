@@ -82,6 +82,10 @@ from gamesmanmpi_tpu.core.values import LOSE, TIE
 from gamesmanmpi_tpu.games.connect4 import Connect4
 from gamesmanmpi_tpu.obs import Span, trace_span
 from gamesmanmpi_tpu.ops.combine import combine_children
+from gamesmanmpi_tpu.ops.pallas_gather import (
+    GATHER_BLOCK,
+    monotone_window_gather,
+)
 from gamesmanmpi_tpu.solve.engine import get_kernel, schedule_kernel
 from gamesmanmpi_tpu.solve.precompile import sds
 from gamesmanmpi_tpu.utils.env import env_int, env_opt, env_str
@@ -256,8 +260,8 @@ class DenseTables:
         # are lexicographic and adding e_c to two profiles preserves their
         # lex order. With ranks monotone per row, the flat child index
         # vector is globally non-decreasing once invalid rows are filled
-        # with the previous valid row's LAST slot — which lets the gather
-        # carry XLA's indices_are_sorted hint (GAMESMAN_DENSE_GATHER).
+        # with the previous valid row's LAST slot — which lets the Pallas
+        # window gather stream it (GAMESMAN_DENSE_GATHER=pallas).
         move_fill = np.maximum.accumulate(
             np.where(valid, move_row, -1), axis=0
         ).astype(np.int32)
@@ -565,16 +569,6 @@ def _rank_all_moves_fused(bits, binom, cellidx, snapk, bitpos, rank_dtype,
     return snaps + acc_sh[None]
 
 
-# Kernel block / window for gather_mode="pallas". block=2048 divides every
-# _cblock (which rounds to a PALLAS_BLOCK multiple), so no kernel block
-# straddles a profile row; window=4*block covers child-rank spans up to a
-# ~4x per-level expansion ratio (near-full levels, where the time is, are
-# close to 1x). Blocks that still miss (tiny early levels can expand
-# faster) fall back per-call via lax.cond.
-PALLAS_BLOCK = 2048
-PALLAS_WINDOW = 8192
-
-
 def build_dense_step(tables: DenseTables, level: int, cblock: int,
                      rank_dtype, flat_dtype, use_onehot: bool,
                      fused_rank: bool = False,
@@ -588,25 +582,20 @@ def build_dense_step(tables: DenseTables, level: int, cblock: int,
        newbit [P, w], valid [P, w] bool, move_row [P, w] i32,
        move_fill [P, w] i32, child_cellidx [ncells, P, w] i32,
        snapk [ncells, P] i32)
-      -> cells [P, cblock] u8
+      -> (cells [P, cblock] u8 ([P, C] where a pallas-mode cblock pads
+          C), missed gather lanes int32 scalar)
 
     gather_mode picks the child-cell gather lowering; results are
-    identical in all three (tests pin it):
-      "plain"  — clip + XLA gather (measured fastest XLA form on-chip);
-      "sorted" — invalid rows' flat indices get a monotone fill (see
-                 level_consts move_fill) and the gather carries
-                 indices_are_sorted=True (measured: the hint buys
-                 nothing, chip session r04);
-      "pallas" — the same monotone fill feeds the Pallas monotone-window
-                 gather (ops/pallas_gather.py), which streams window
-                 tiles through VMEM instead of issuing per-element HBM
-                 transactions. A block whose child-rank span exceeds the
-                 window misses; nmiss>0 falls back to the sorted-hint
-                 XLA gather for that call via lax.cond. Blocks never
-                 straddle profile rows (_cblock rounds to the kernel
-                 block), so spans are bounded by the per-level child
-                 expansion ratio and the big near-full levels — where
-                 the time is — run miss-free.
+    identical in both (tests pin it):
+      "plain"  — clip + XLA gather;
+      "pallas" — invalid rows' flat indices get a monotone fill (see
+                 level_consts move_fill), and the Pallas window gather
+                 (ops/pallas_gather.py) DMAs two table views per block of
+                 1024 lanes into VMEM and selects there. Lanes outside
+                 both views are re-gathered exactly by its fix-up and
+                 counted: the second output, 0 in plain mode. Blocks
+                 never straddle profile rows (_cblock rounds to the
+                 kernel block).
 
     fused_rank picks the single-walk child ranking
     (_rank_all_moves_fused) over the per-move walks; results are
@@ -658,7 +647,7 @@ def build_dense_step(tables: DenseTables, level: int, cblock: int,
                 return jnp.where(
                     mover_line | current_line, jnp.uint8(LOSE),
                     jnp.uint8(TIE),
-                )  # remoteness 0 everywhere at the top level
+                ), jnp.int32(0)  # remoteness 0 everywhere at the top level
         prim_mask = mover_line | current_line
 
         if fused_rank:
@@ -667,22 +656,8 @@ def build_dense_step(tables: DenseTables, level: int, cblock: int,
                     p1, binom, cellidx, snapk, bitpos, rank_dtype,
                     use_onehot, p1_moves, w, h,
                 )
-        if gather_mode == "pallas":
-            # Window-pad the child table ONCE per step so the kernel's
-            # internal pad (a full-table copy) is a no-op for all w move
-            # gathers. The XLA fallback keeps the unpadded table.
-            from gamesmanmpi_tpu.ops.pallas_gather import padded_table_len
-
-            m = child_cells.shape[0]
-            tpad = padded_table_len(m, PALLAS_WINDOW) - m
-            with jax.named_scope("gather"):
-                child_cells_pal = (
-                    jnp.concatenate(
-                        [child_cells, jnp.zeros((tpad,), child_cells.dtype)]
-                    ) if tpad else child_cells
-                )
-        child_vals = []
-        child_rems = []
+        cells = []
+        flats = []
         masks = []
         for c in range(w):
             with jax.named_scope("lookup"):
@@ -695,58 +670,47 @@ def build_dense_step(tables: DenseTables, level: int, cblock: int,
                 flat = (move_row[:, c : c + 1].astype(flat_dtype)
                         * flat_dtype(Cc) + crank.astype(flat_dtype))
                 ok = valid[:, c : c + 1] & jnp.ones((1, cblock), bool)
-            with jax.named_scope("gather"):
-                if gather_mode in ("sorted", "pallas"):
-                    # Invalid rows and pad lanes (rank >= C in the last block,
-                    # whose unranked bits are garbage) get a monotone fill —
-                    # invalid rows the previous valid row's LAST slot (or 0
-                    # before any valid row), pad lanes their own row's last
-                    # slot — keeping the flat vector globally non-decreasing
-                    # so the gather may stream instead of scattering reads.
-                    in_range = ranks < rank_dtype(C)  # [1, cb]
-                    fillr = jnp.where(
-                        valid[:, c : c + 1],
-                        move_row[:, c : c + 1],
-                        move_fill[:, c : c + 1],
-                    ).astype(flat_dtype)
-                    fill = jnp.where(
-                        fillr < 0, flat_dtype(0),
-                        fillr * flat_dtype(Cc) + flat_dtype(Cc - 1),
-                    )
-                    flat = jnp.where(ok & in_range, flat, fill)
-
-                    def _xla_sorted(f=flat):
-                        return child_cells.at[f.reshape(-1)].get(
-                            indices_are_sorted=True, mode="clip"
-                        ).reshape(f.shape)
-
-                    if gather_mode == "pallas":
-                        from gamesmanmpi_tpu.ops.pallas_gather import (
-                            monotone_window_gather,
-                        )
-
-                        # flat stays in flat_dtype (int64 for 6x6+): the
-                        # kernel wrapper derives block-local int32 offsets
-                        # outside Mosaic.
-                        out, nmiss = monotone_window_gather(
-                            child_cells_pal, flat.reshape(-1),
-                            block=PALLAS_BLOCK, window=PALLAS_WINDOW,
-                            interpret=pallas_interpret,
-                        )
-                        cell = jax.lax.cond(
-                            nmiss == jnp.int32(0),
-                            lambda: out.reshape(flat.shape),
-                            _xla_sorted,
-                        )
-                    else:
-                        cell = _xla_sorted()
-                else:
-                    cell = child_cells[
-                        jnp.clip(flat, 0, child_cells.shape[0] - 1)
-                    ]
-                child_vals.append(cell & jnp.uint8(3))
-                child_rems.append((cell >> jnp.uint8(2)).astype(jnp.int32))
             masks.append(ok)
+            with jax.named_scope("gather"):
+                if gather_mode == "plain":
+                    cells.append(child_cells[
+                        jnp.clip(flat, 0, child_cells.shape[0] - 1)
+                    ])
+                    continue
+                # Invalid rows and pad lanes (rank >= C in the last block,
+                # whose unranked bits are garbage) get a monotone fill —
+                # invalid rows the previous valid row's LAST slot (or 0
+                # before any valid row), pad lanes their own row's last
+                # slot — keeping the flat vector globally non-decreasing
+                # so the gather may stream instead of scattering reads.
+                in_range = ranks < rank_dtype(C)  # [1, cb]
+                fillr = jnp.where(
+                    valid[:, c : c + 1],
+                    move_row[:, c : c + 1],
+                    move_fill[:, c : c + 1],
+                ).astype(flat_dtype)
+                fill = jnp.where(
+                    fillr < 0, flat_dtype(0),
+                    fillr * flat_dtype(Cc) + flat_dtype(Cc - 1),
+                )
+                flats.append(jnp.where(ok & in_range, flat, fill)
+                             .reshape(-1))
+        missed = jnp.int32(0)
+        if gather_mode == "pallas":
+            # One kernel call for all w moves: each move's run is monotone,
+            # and a block that spans the drop from one move's last index to
+            # the next one's first is served by its two views (view A the
+            # tail, view B the head). flat stays in flat_dtype (int64 for
+            # 6x6+): the wrapper derives int32 offsets outside Mosaic.
+            with jax.named_scope("gather"):
+                got, missed = monotone_window_gather(
+                    child_cells, jnp.concatenate(flats),
+                    interpret=pallas_interpret)
+                cells = list(got.reshape(w, P, cblock))
+        with jax.named_scope("gather"):
+            child_vals = [cell & jnp.uint8(3) for cell in cells]
+            child_rems = [(cell >> jnp.uint8(2)).astype(jnp.int32)
+                          for cell in cells]
 
         with jax.named_scope("combine"):
             cv = jnp.stack(child_vals, axis=-1).reshape(P * cblock, w)
@@ -759,8 +723,13 @@ def build_dense_step(tables: DenseTables, level: int, cblock: int,
 
             values = jnp.where(prim_mask, jnp.uint8(LOSE), values)
             rem_out = jnp.where(prim_mask, 0, rem_out)
-            return values | (jnp.clip(rem_out, 0, 63).astype(jnp.uint8)
-                             << jnp.uint8(2))
+            cells = values | (jnp.clip(rem_out, 0, 63).astype(jnp.uint8)
+                              << jnp.uint8(2))
+            if gather_mode == "pallas" and cblock > C:
+                # A level that fits one block drops its pad ranks here,
+                # not in a program of its own per level.
+                cells = cells[:, :C]
+            return cells, missed
 
     # Not jitted here: engine.get_kernel / schedule_kernel jit the builder's
     # return value themselves.
@@ -1046,6 +1015,7 @@ class DenseSolver:
         #: is why it is opt-in), and a resumed solve skips the deepest
         #: CONTIGUOUS completed prefix, rechaining from its last level.
         self.checkpointer = checkpointer
+        self._gather_miss: list = []  # per-step device scalars, one solve
         self.devices = int(devices)
         if self.devices < 1:
             raise ValueError(f"devices must be >= 1, got {devices}")
@@ -1087,23 +1057,21 @@ class DenseSolver:
             "GAMESMAN_DENSE_RANK", "simple"
         ) == "fused"
         # Gather lowering (identical results in all modes, tests pin it):
-        #   "plain"  — clip + XLA gather. MEASURED on the v5e (chip
-        #              session r04): 9.04M pos/s — the fastest XLA form.
-        #   "sorted" — monotone fill + indices_are_sorted hint. MEASURED:
-        #              6.35M — the hint costs fill arithmetic and buys
-        #              nothing (microbench2: XLA's gather runs ~0.37 GB/s
-        #              with or without sorted indices).
-        #   "pallas" — monotone fill + the Pallas monotone-window gather
-        #              (ops/pallas_gather.py), streaming window tiles
-        #              through VMEM; per-call lax.cond fallback to the
-        #              sorted XLA gather when any block's span misses its
-        #              window. The dense backward is ~pure gather (8.6e8
-        #              operand bytes at 0.112 GB/s, r04), so this is the
-        #              candidate past 9M pos/s; go/no-go is
-        #              tools/pallas_chip_check.py on silicon.
+        #   "plain"  — clip + XLA gather, the CPU default. On the v5e XLA
+        #              treats it as random access: 7.649 s of an 8.56 s
+        #              6x4 solve, ~8 ns per lane (a traced warm solve).
+        #   "pallas" — monotone fill + the Pallas window gather
+        #              (ops/pallas_gather.py), the accelerator default:
+        #              per block of 1024 lanes, two table views DMA'd into
+        #              VMEM; lanes outside both are re-gathered exactly by
+        #              a fix-up and counted (stats gather_miss_lanes).
+        #              MEASURED on a TPU v5e, 6x4 solves with a warm
+        #              compile cache: gather 0.26 s of device time against
+        #              plain's 7.65 s, no lane missed; 74.8M positions/s
+        #              against 10.8M (PERF.md §6).
         self.gather_mode = platform_auto_flag(
-            "GAMESMAN_DENSE_GATHER", accel="plain", cpu="plain",
-            choices=("plain", "sorted", "pallas"),
+            "GAMESMAN_DENSE_GATHER", accel="pallas", cpu="plain",
+            choices=("plain", "pallas"),
         )
         if (self.gather_mode == "pallas" and self.devices > 1
                 and jax.default_backend() != "cpu"
@@ -1124,10 +1092,6 @@ class DenseSolver:
                 "(set GAMESMAN_DENSE_GATHER_PALLAS_MESH=1 to override)",
                 stacklevel=2,
             )
-            # "plain", not "sorted": the r04 chip A/B measured sorted at
-            # 0.70x plain (the hint buys nothing and the extra sort
-            # costs) — the safety valve must demote to the shipped
-            # optimum, not the slowest mode.
             self.gather_mode = "plain"
         nc = self.tables.ncells
         max_class = max(self.tables.class_size)
@@ -1170,8 +1134,9 @@ class DenseSolver:
         gathers from; consts are KBs); the [P, cblock] output shards over
         its RANK axis, and XLA's SPMD partitioner propagates that
         constraint back through the elementwise/fori unrank chain so each
-        device computes only its lane slice. The reach step's scalar count
-        replicates (XLA inserts the cross-shard sum).
+        device computes only its lane slice. Both kernels' scalar second
+        output (the reach count, the missed gather lanes) replicates (XLA
+        inserts the cross-shard sum).
         """
         if self._mesh is None:
             return None
@@ -1181,8 +1146,7 @@ class DenseSolver:
 
         rep = NamedSharding(self._mesh, PartitionSpec())
         cells = NamedSharding(self._mesh, PartitionSpec(None, AXIS))
-        out = (cells, rep) if kind == "dense_reach" else cells
-        return dict(in_shardings=rep, out_shardings=out)
+        return dict(in_shardings=rep, out_shardings=(cells, rep))
 
     def _kernel(self, kind: str, level: int, cblock: int, builder):
         t, rd, fd, oh, fr, gm = (self.tables, self._rank_dtype,
@@ -1205,14 +1169,17 @@ class DenseSolver:
         P = len(self.tables.profiles[level])
         C = self.tables.class_size[level]
         cblock = max(min(C, max(self.block_elems // max(P, 1), 1)), 1)
-        if self.gather_mode == "pallas" and cblock >= PALLAS_BLOCK:
-            # Round to a PALLAS_BLOCK multiple so the Pallas kernel's
-            # blocks never straddle a profile row (a straddling block's
-            # index span is ~the child class size — a guaranteed window
-            # miss). Only in pallas mode: the rounding changes cblock,
-            # which keys every kernel cache entry — the other modes would
-            # recompile their whole program set for nothing.
-            cblock -= cblock % PALLAS_BLOCK
+        if self.gather_mode == "pallas" and cblock >= GATHER_BLOCK:
+            # Round UP to a GATHER_BLOCK multiple, keeping the number of
+            # blocks, so the Pallas kernel's blocks never straddle a
+            # profile row (a straddling block's index span is ~the child
+            # class size) at the cost of < GATHER_BLOCK pad ranks a row;
+            # rounding down would add a nearly empty block to every level
+            # that fits one. Only in pallas mode: the rounding changes
+            # cblock, which keys every kernel cache entry — the other
+            # modes would recompile their whole program set for nothing.
+            per_block = -(-C // -(-C // cblock))
+            cblock = -(-per_block // GATHER_BLOCK) * GATHER_BLOCK
         if self._mesh is not None:
             # A sharded [P, cblock] output must split its rank axis evenly
             # across the mesh; round UP (pad ranks) — out-of-range lanes
@@ -1220,16 +1187,16 @@ class DenseSolver:
             # them (in_range masks / clipped gathers), and callers slice
             # back to C.
             cblock = -(-cblock // self.devices) * self.devices
-            if (self.gather_mode == "pallas" and cblock >= PALLAS_BLOCK
-                    and cblock % PALLAS_BLOCK):
+            if (self.gather_mode == "pallas" and cblock >= GATHER_BLOCK
+                    and cblock % GATHER_BLOCK):
                 # The round-up broke the pallas invariant (every cblock
-                # >= PALLAS_BLOCK is a PALLAS_BLOCK multiple, so kernel
+                # >= GATHER_BLOCK is a GATHER_BLOCK multiple, so kernel
                 # blocks never straddle profile rows — including when the
                 # round-up itself crossed the threshold); re-round to a
                 # size satisfying both.
                 import math
 
-                q = math.lcm(self.devices, PALLAS_BLOCK)
+                q = math.lcm(self.devices, GATHER_BLOCK)
                 cblock = -(-cblock // q) * q
         return cblock, -(-C // cblock)
 
@@ -1282,7 +1249,8 @@ class DenseSolver:
         )
 
     def schedule_compiles(self, reach_first: bool = False,
-                          last_level: Optional[int] = None) -> None:
+                          last_level: Optional[int] = None,
+                          reach: bool = True) -> None:
         """Queue background compiles of EVERY level's kernels.
 
         Unlike the BFS engine's speculative capacity ladder, the dense
@@ -1292,7 +1260,8 @@ class DenseSolver:
         seconds per program).
 
         last_level bounds both phases (the hybrid engine runs dense
-        kernels only up to its cutover region).
+        kernels only up to its cutover region). reach=False leaves out the
+        count sweep's kernels.
         """
         t = self.tables
         nc = t.ncells if last_level is None else min(last_level, t.ncells)
@@ -1317,6 +1286,8 @@ class DenseSolver:
             ("dense_step", range(nc, -1, -1), build_dense_step, False),
             ("dense_reach", range(1, nc + 1), build_reach_step, True),
         ]
+        if not reach:
+            phases = phases[:1]
         if reach_first:
             phases.reverse()
         for kind, levels, builder, for_reach in phases:
@@ -1432,14 +1403,20 @@ class DenseSolver:
         counts.update({L: int(v) for L, v in counts_dev.items()})
         return counts, reach_flat
 
+    def _known_counts(self) -> Optional[Dict[int, int]]:
+        """The per-level reachable counts this process or the sidecar
+        already holds (then cached per process), else None."""
+        cached = _REACH_COUNTS.get(self._board_key)
+        if cached is None:
+            cached = _load_cached_counts(self._board_key)
+            if cached is not None:
+                _REACH_COUNTS[self._board_key] = cached
+        return cached
+
     def reachable_counts(self) -> Dict[int, int]:
         """Exact per-level reachable-position counts (cached per process)."""
-        cached = _REACH_COUNTS.get(self._board_key)
+        cached = self._known_counts()
         if cached is not None:
-            return cached
-        cached = _load_cached_counts(self._board_key)
-        if cached is not None:
-            _REACH_COUNTS[self._board_key] = cached
             return cached
         self.schedule_compiles(reach_first=True)
         counts, _ = self._sweep_levels(self.tables.ncells)
@@ -1450,7 +1427,8 @@ class DenseSolver:
     def _backward_level(self, L: int, child_flat):
         """One dense backward level (blocked, no sync): the deeper level's
         flat cells -> this level's [P, C] cells. Shared by solve() and the
-        hybrid's below-cutover loop."""
+        hybrid's below-cutover loop. Appends the level's missed gather
+        lanes (device scalars) to self._gather_miss."""
         t = self.tables
         C = t.class_size[L]
         cblock, nblk = self._cblock(L)
@@ -1458,15 +1436,18 @@ class DenseSolver:
         consts = self._upload_consts(L, for_reach=False)
         blocks = []
         for b in range(nblk):
-            blocks.append(step(
+            cells_b, missed = step(
                 self._rank0(b, cblock), child_flat,
                 consts["binom"], consts["cellidx"], consts["filled"],
                 consts["newbit"], consts["valid"],
                 consts["move_row"], consts["move_fill"],
                 consts["child_cellidx"], consts["snapk"],
-            ))
+            )
+            blocks.append(cells_b)
+            # Kept on the device: solve() fetches them once, with the root.
+            self._gather_miss.append(missed)
         cells = blocks[0] if nblk == 1 else jnp.concatenate(blocks, axis=1)
-        if nblk * cblock != C:
+        if cells.shape[1] != C:
             cells = cells[:, :C]
         return cells
 
@@ -1528,8 +1509,15 @@ class DenseSolver:
         # compiles; a partial resume bounds the dense_step set to what it
         # will actually run.
         if start_L >= 0:
-            self.schedule_compiles(last_level=start_L)
+            # The count sweep's kernels only where it will run: for a board
+            # whose counts are known, each solve would trace and load them
+            # for nothing, holding the GIL against the solve's own.
+            sweep = (self.count_positions != False  # noqa: E712
+                     and self._known_counts() is None)
+            self.schedule_compiles(last_level=start_L, reach=sweep)
         computed_encodable = 0
+        gather_lanes = 0
+        self._gather_miss = []
         self._undrained = 0
         last_drain = t0  # drains are the only real sync points, so they
         # are the only honest per-segment timestamps (dispatch is async)
@@ -1540,6 +1528,8 @@ class DenseSolver:
                       classes=P, class_size=C)
             encodable_total += P * C
             computed_encodable += P * C
+            if L < nc:
+                gather_lanes += P * C * t.width
             level_cells = self._backward_level(L, child_flat)
             child_flat = self._replicate(level_cells.reshape(-1))
             if self._maybe_drain(P * C, child_flat, L):
@@ -1555,7 +1545,9 @@ class DenseSolver:
                 )
             sp.end()
 
-        root_cell = int(jnp.reshape(child_flat, (-1,))[0])
+        root_cell, *missed = jax.device_get(
+            [jnp.reshape(child_flat, (-1,))[0], *self._gather_miss])
+        root_cell, gather_miss = int(root_cell), sum(int(m) for m in missed)
         value, remoteness = root_cell & 3, root_cell >> 2
         solve_secs = time.perf_counter() - t0
 
@@ -1597,6 +1589,11 @@ class DenseSolver:
             "bytes_sorted": 0,
             # Operand bytes of the gathers this RUN issued (u8 cells).
             "bytes_gathered": computed_encodable * g.max_moves,
+            # Child-cell gather lanes this run issued (one per position
+            # and move, levels below the top) and those the pallas
+            # gather's views missed, which its fix-up served.
+            "gather_lanes": gather_lanes,
+            "gather_miss_lanes": gather_miss,
         }
         if counted:
             stats["reachable_per_level"] = counted

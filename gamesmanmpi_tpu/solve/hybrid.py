@@ -713,16 +713,23 @@ class HybridSolver:
         # DenseSolver.solve; no-op at devices=1).
         child_flat = d._replicate(boundary_cells.reshape(-1))
         d._undrained = 0
+        d._gather_miss = []
+        gather_lanes = 0
         for L in range(K - 1, -1, -1):
             P = len(t.profiles[L])
             C = t.class_size[L]
+            gather_lanes += P * C * t.width
             cells = d._backward_level(L, child_flat)
             child_flat = d._replicate(cells.reshape(-1))
             d._maybe_drain(P * C, child_flat, L)
             if saved is not None:
                 saved[L] = np.asarray(cells).reshape(P, C)
 
-        root_cell = int(jnp.reshape(child_flat, (-1,))[0])
+        # The dense levels' missed gather lanes come with the root: no
+        # per-level sync.
+        root_cell, *missed = jax.device_get(
+            [jnp.reshape(child_flat, (-1,))[0], *d._gather_miss])
+        root_cell, gather_miss = int(root_cell), sum(int(m) for m in missed)
         value, remoteness = root_cell & 3, root_cell >> 2
         t_total = time.perf_counter() - t0
 
@@ -752,6 +759,10 @@ class HybridSolver:
             # full-space sweep count sits alongside (equal when sym=0).
             "frontier_at_boundary": int(frontier.shape[0]),
             "reachable_at_boundary": counts[B],
+            # The dense levels below the cutover: child-cell gather lanes
+            # and those the pallas gather's views missed (DenseSolver's).
+            "gather_lanes": gather_lanes,
+            "gather_miss_lanes": gather_miss,
         }
         self._log(phase="done", **{k: v for k, v in stats.items()
                                    if k != "game"})

@@ -287,35 +287,95 @@ def test_dense_fused_rank_matches_simple(monkeypatch):
         np.testing.assert_array_equal(f2.cells[L], cells)
 
 
-@pytest.mark.slow  # ~42 s CPU: full-solve A/B of the sorted-gather lowering
 def test_dense_sorted_gather_matches_plain(monkeypatch):
-    # GAMESMAN_DENSE_GATHER=sorted is a lowering hint (monotone fill for
-    # invalid rows + pad lanes, indices_are_sorted gather): every cell of
-    # every level table must match the plain gather, including with
-    # blocking forced (pad lanes in every tail block).
+    """The monotone (sorted) fill feeding the Pallas window gather
+    (interpret mode here) on 5x2: at default blocking, with tiny blocks
+    (block_elems=64: kernel blocks span several profile rows and the tail
+    block's pad lanes), and fed by the fused rank. Every level table
+    matches the plain gather; the default blocking misses no lane."""
     g = get_game("connect4:w=5,h=2")
     plain = DenseSolver(g).solve()
-    monkeypatch.setenv("GAMESMAN_DENSE_GATHER", "sorted")
-    srt = DenseSolver(g).solve()
+    monkeypatch.setenv("GAMESMAN_DENSE_GATHER", "pallas")
+    pal = DenseSolver(g).solve()
     blocked = DenseSolver(g, block_elems=64).solve()
-    # Both lowering flags together compose into a distinct program — the
-    # combination a chip measurement run would plausibly enable.
     monkeypatch.setenv("GAMESMAN_DENSE_RANK", "fused")
     both = DenseSolver(g).solve()
-    for L, cells in plain.cells.items():
-        np.testing.assert_array_equal(srt.cells[L], cells)
-        np.testing.assert_array_equal(blocked.cells[L], cells)
-        np.testing.assert_array_equal(both.cells[L], cells)
+    assert pal.stats["gather_miss_lanes"] == 0
+    assert both.stats["gather_miss_lanes"] == 0
+    for run in (pal, blocked, both):
+        assert run.stats["gather_mode"] == "pallas"
+        assert run.stats["gather_lanes"] == plain.stats["gather_lanes"]
+        for L, cells in plain.cells.items():
+            np.testing.assert_array_equal(run.cells[L], cells)
 
 
-@pytest.mark.slow  # ~147 s: pallas kernel emulated on CPU, full-solve A/B
+def test_dense_solve_schedules_reach_kernels_only_for_a_sweep(monkeypatch):
+    """A solve schedules the count sweep's kernels only when it will run
+    the sweep: once the board's counts are known, a solve queues its
+    backward steps alone."""
+    from gamesmanmpi_tpu.solve import dense as dmod
+
+    kinds = []
+    real = dmod.schedule_kernel
+
+    def spy(game, kind, *args, **kwargs):
+        kinds.append(kind)
+        return real(game, kind, *args, **kwargs)
+
+    monkeypatch.setattr(dmod, "schedule_kernel", spy)
+    monkeypatch.setattr(dmod, "_REACH_COUNTS", {})
+    g = get_game("connect4:w=3,h=3,connect=3")
+    first = DenseSolver(g).solve()
+    assert "dense_reach" in kinds and "dense_step" in kinds
+    kinds.clear()
+    second = DenseSolver(g).solve()
+    assert "dense_step" in kinds and "dense_reach" not in kinds
+    assert second.stats["positions"] == first.stats["positions"] > 0
+
+
+def test_dense_pallas_gather_parity_4x3(monkeypatch):
+    """The Pallas window gather (interpret mode here) against the plain
+    XLA gather on the smallest board whose gathers span several kernel
+    blocks (up to 10 per step): every level table hashes the same, and no
+    lane missed both views."""
+    import hashlib
+
+    def digests(result):
+        return {L: hashlib.sha256(np.asarray(c).tobytes()).hexdigest()
+                for L, c in result.cells.items()}
+
+    g = get_game("connect4:w=4,h=3")
+    plain = DenseSolver(g).solve()
+    monkeypatch.setenv("GAMESMAN_DENSE_GATHER", "pallas")
+    pal = DenseSolver(g).solve()
+    assert pal.stats["gather_mode"] == "pallas"
+    assert pal.stats["gather_lanes"] == plain.stats["gather_lanes"] > 0
+    assert pal.stats["gather_miss_lanes"] == 0
+    assert (pal.value, pal.remoteness) == (plain.value, plain.remoteness)
+    assert digests(pal) == digests(plain)
+
+
+@pytest.mark.parametrize("backend,want", [("tpu", "pallas"),
+                                          ("cpu", "plain")])
+def test_dense_gather_default_by_platform(backend, want, monkeypatch):
+    """GAMESMAN_DENSE_GATHER unset: the window gather on an accelerator,
+    the plain XLA gather on the CPU."""
+    import jax
+
+    monkeypatch.delenv("GAMESMAN_DENSE_GATHER", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    g = get_game("connect4:w=3,h=3,connect=3")
+    assert DenseSolver(g).gather_mode == want
+
+
+@pytest.mark.slow  # pallas kernel emulated on CPU, full-solve A/B
 def test_dense_pallas_gather_matches_plain(monkeypatch):
     # GAMESMAN_DENSE_GATHER=pallas routes the monotone fill through the
-    # Mosaic monotone-window gather (interpret mode on CPU) with the
-    # lax.cond miss fallback; every cell of every level table must match
-    # the plain-gather solve. block_elems sized so the big 4x4 levels get
-    # cblock >= PALLAS_BLOCK (the rounded, row-aligned fast path) while
-    # small levels take the fallback — both paths in one solve.
+    # Mosaic window gather (interpret mode on CPU) with its miss fix-up;
+    # every cell of every level table must match the plain-gather solve.
+    # block_elems sized so the big 4x4 levels split into several blocks
+    # of rounded-up cblock >= PALLAS_BLOCK (row-aligned kernel blocks)
+    # while small levels keep cblock = C.
     g = get_game("connect4:w=4,h=4")
     plain = DenseSolver(g, block_elems=150_000).solve()
     monkeypatch.setenv("GAMESMAN_DENSE_GATHER", "pallas")
@@ -327,7 +387,7 @@ def test_dense_pallas_gather_matches_plain(monkeypatch):
         np.testing.assert_array_equal(pal.cells[L], cells)
 
 
-@pytest.mark.slow  # ~85 s: pallas int64 path emulated on CPU, full-solve A/B
+@pytest.mark.slow  # pallas int64 path emulated on CPU, full-solve A/B
 def test_dense_pallas_gather_int64_flat_matches_plain(monkeypatch):
     # int64 flat index spaces (6x6+, where the gather win matters most)
     # are pallas-eligible since r5: the kernel wrapper derives

@@ -6,6 +6,7 @@ classic solver's full tables across cutover placements, including the
 degenerate ends where one engine does almost all the work.
 """
 
+import numpy as np
 import pytest
 
 from gamesmanmpi_tpu.games import get_game
@@ -39,6 +40,27 @@ def test_hybrid_full_parity_3x3c3():
 @pytest.mark.slow  # ~64 s CPU full-board parity; 3x3c3 covers the seam fast
 def test_hybrid_full_parity_4x3():
     _full_parity("connect4:w=4,h=3", (5, 8))
+
+
+def test_hybrid_reports_pallas_gather_lanes(monkeypatch):
+    """The dense levels below the cutover run the Pallas window gather
+    (interpret mode here), exact against the plain gather, and the done
+    record counts their lanes; a second solve by the same solver reports
+    the same counts, not a running total."""
+    spec = "connect4:w=4,h=3"
+    plain = HybridSolver(get_game(spec), cutover=6).solve()
+    monkeypatch.setenv("GAMESMAN_DENSE_GATHER", "pallas")
+    hs = HybridSolver(get_game(spec), cutover=6)
+    first = hs.solve()
+    second = hs.solve()
+    assert plain.stats["gather_miss_lanes"] == 0
+    for run in (first, second):
+        assert (run.value, run.remoteness) == (plain.value, plain.remoteness)
+        assert run.stats["gather_lanes"] == plain.stats["gather_lanes"] > 0
+        assert (run.stats["gather_miss_lanes"]
+                == first.stats["gather_miss_lanes"])
+        for L, cells in plain.cells.items():
+            np.testing.assert_array_equal(run.cells[L], cells)
 
 
 def test_hybrid_validates_args():

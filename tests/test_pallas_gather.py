@@ -1,13 +1,11 @@
 """Monotone-window gather (ops/pallas_gather.py), interpret mode.
 
-These tests pin the kernel's SEMANTICS via the Pallas interpreter so the
-on-chip run (tools/pallas_chip_check.py) only has to answer "does Mosaic
-accept it and is it fast", not "is it correct". The round-4 chip session
-compiled a trivial Pallas kernel; this kernel's first compile attempt
-exposed a trace-time int64 recursion (fixed — see
-ops/pallas_gather._dyn_gather). The installed Mosaic still refuses the
-kernel ("Multiple source vregs along gather dimension"), pinned as a
-strict xfail in tests/test_tpu_compile.py.
+These tests pin the kernel's SEMANTICS via the Pallas interpreter, so a
+chip run only has to answer "is it fast": the output equals ``table[idx]``
+on every lane (lanes outside both views through the fix-up), and the miss
+count equals the lanes outside both views, recomputed independently
+(``_reference_ok_mask``). tests/test_tpu_compile.py compiles the same
+kernel for a described v5e.
 """
 
 import numpy as np
@@ -30,7 +28,7 @@ pytestmark = pytest.mark.smoke
 
 def _case(m, n, seed, span=None):
     rng = np.random.default_rng(seed)
-    table = rng.integers(0, 1 << 30, size=m, dtype=np.uint32)
+    table = rng.integers(0, 1 << 32, size=m, dtype=np.uint32)
     if span is None:
         idx = np.sort(rng.integers(0, m, size=n)).astype(np.int32)
     else:
@@ -41,87 +39,141 @@ def _case(m, n, seed, span=None):
     return table, idx
 
 
+def _gather(table, idx, **kw):
+    out, nmiss = monotone_window_gather(table, idx, interpret=True, **kw)
+    return np.asarray(out), int(nmiss)
+
+
 def test_matches_plain_gather_when_spans_fit():
     table, idx = _case(1 << 16, 5000, 0, span=3)
-    out, nmiss = monotone_window_gather(table, idx, block=256, window=2048,
-                                        interpret=True)
-    assert int(nmiss) == 0
-    np.testing.assert_array_equal(np.asarray(out), table[idx])
+    out, nmiss = _gather(table, idx, window=2048)
+    assert nmiss == 0
+    np.testing.assert_array_equal(out, table[idx])
 
 
 def test_int64_indices_match_int32():
     """int64 idx (6x6+ flat spaces) must produce bit-identical output:
-    the wrapper reduces both dtypes to the same block-local int32
-    offsets before Mosaic (r5 — VERDICT r4 #3)."""
+    the wrapper reduces them to block-local int32 offsets before Mosaic."""
     table, idx = _case(1 << 16, 5000, 3, span=3)
-    out32, nm32 = monotone_window_gather(table, idx, block=256,
-                                         window=2048, interpret=True)
-    out64, nm64 = monotone_window_gather(table, idx.astype(np.int64),
-                                         block=256, window=2048,
-                                         interpret=True)
-    assert int(nm32) == int(nm64) == 0
-    np.testing.assert_array_equal(np.asarray(out32), np.asarray(out64))
-    np.testing.assert_array_equal(np.asarray(out64), table[idx])
+    out32, nm32 = _gather(table, idx, window=2048)
+    out64, nm64 = _gather(table, idx.astype(np.int64), window=2048)
+    assert nm32 == nm64 == 0
+    np.testing.assert_array_equal(out32, out64)
+    np.testing.assert_array_equal(out64, table[idx])
 
 
 def test_int64_wide_jumps_miss_flagged():
     table, idx = _case(1 << 18, 4096, 4)
-    out, nmiss = monotone_window_gather(table, idx.astype(np.int64),
-                                        block=256, window=1024,
-                                        interpret=True)
-    assert int(nmiss) > 0
-    ok = _reference_ok_mask(table, idx, block=256, window=1024)
-    np.testing.assert_array_equal(np.asarray(out)[ok], table[idx[ok]])
-    assert int(nmiss) == int((~ok).sum())
+    out, nmiss = _gather(table, idx.astype(np.int64), window=1024)
+    ok = _reference_ok_mask(table, idx, block=1024, window=1024)
+    assert nmiss == int((~ok).sum()) > 0
+    np.testing.assert_array_equal(out, table[idx])
 
 
 def test_wide_jumps_are_miss_flagged_not_wrong():
-    # Random global indices jump across windows: misses must be counted,
-    # and every non-missed element must still be correct.
+    # Random global indices jump across views: the misses are counted, and
+    # the fix-up still gives every lane its exact value.
     table, idx = _case(1 << 18, 4096, 1)
-    out, nmiss = monotone_window_gather(table, idx, block=256, window=1024,
-                                        interpret=True)
-    assert int(nmiss) > 0  # adversarial case: spans exceed the window
-    ok = _reference_ok_mask(table, idx, block=256, window=1024)
-    np.testing.assert_array_equal(np.asarray(out)[ok], table[idx[ok]])
-    assert int(nmiss) == int((~ok).sum())
+    out, nmiss = _gather(table, idx, window=1024)
+    ok = _reference_ok_mask(table, idx, block=1024, window=1024)
+    assert nmiss == int((~ok).sum()) > 0
+    np.testing.assert_array_equal(out, table[idx])
 
 
 def test_u8_table_gathers_as_i32_exactly():
-    # The dense engine's tables are u8 cells; the kernel gathers them as
-    # i32 in VMEM (Mosaic's dynamic_gather targets 32-bit lanes) and must
-    # cast back exactly.
+    # The dense engine's tables are u8 cells; the kernel selects them from
+    # packed i32 words (Mosaic's dynamic_gather targets 32-bit lanes) and
+    # must narrow back exactly.
     rng = np.random.default_rng(7)
     table = rng.integers(0, 256, size=1 << 16, dtype=np.uint8)
     steps = rng.integers(0, 3, size=5000)
     idx = np.minimum(np.cumsum(steps), table.shape[0] - 1).astype(np.int32)
-    out, nmiss = monotone_window_gather(table, idx, block=256, window=2048,
-                                        interpret=True)
-    assert int(nmiss) == 0
-    assert np.asarray(out).dtype == np.uint8
-    np.testing.assert_array_equal(np.asarray(out), table[idx])
+    out, nmiss = _gather(table, idx)
+    assert nmiss == 0
+    assert out.dtype == np.uint8
+    np.testing.assert_array_equal(out, table[idx])
 
 
 def _reference_ok_mask(table, idx, block, window):
-    """The kernel's hit predicate, recomputed independently: element i
-    hits iff its offset from its block's clamped window base lies in
-    [0, 2*window)."""
+    """The kernel's hit predicate, recomputed independently: a lane hits
+    iff it lies in one of its block's two views — A from the tile of the
+    block's first index, B up to the end of the tile of its last — each
+    `window` elements, clamped inside the padded table."""
+    per_word = 4 // table.dtype.itemsize
+    row_elems = 128 * per_word
+    tile = 8 * row_elems
+    rows = max(-(-table.shape[0] // tile) * 8, window // row_elems)
+    hi = rows * row_elems - window
     n = idx.shape[0]
     ok = np.zeros(n, bool)
-    nwin = max(-(-table.shape[0] // window), 2)
     for b in range(-(-n // block)):
-        lo, hi = b * block, min((b + 1) * block, n)
-        base = min(max(idx[lo] // window, 0), nwin - 2) * window
-        off = idx[lo:hi] - base
-        ok[lo:hi] = (off >= 0) & (off < 2 * window)
+        lanes = idx[b * block:(b + 1) * block].astype(np.int64)
+        a = min(lanes[0] // tile * tile, hi)
+        v = min(max((lanes[-1] // tile + 1) * tile - window, 0), hi)
+        ok[b * block:(b + 1) * block] = (
+            ((lanes >= a) & (lanes < a + window))
+            | ((lanes >= v) & (lanes < v + window)))
     return ok
+
+
+def _one_jump(rng):
+    """Every block but a few runs locally; each of those has one jump far
+    beyond view A, which view B serves."""
+    table = rng.integers(0, 256, size=1 << 18, dtype=np.uint8)
+    idx = np.minimum(np.cumsum(rng.integers(0, 2, size=8192)), 40_000)
+    idx[1500:2048] += 100_000       # inside block 1, to its end
+    idx[2048:] += 150_000           # every later block local again
+    return table, idx.astype(np.int32), 0
+
+
+def _wider_than_views(rng):
+    """Blocks spanning far more than both views: the fix-up serves their
+    middle lanes exactly, and counts them."""
+    table = rng.integers(0, 256, size=1 << 20, dtype=np.uint8)
+    idx = np.sort(rng.integers(0, table.shape[0], size=3000))
+    return table, idx.astype(np.int32), None
+
+
+def _u8_int64(rng):
+    table = rng.integers(0, 256, size=1 << 17, dtype=np.uint8)
+    idx = np.minimum(np.cumsum(rng.integers(0, 4, size=6000)),
+                     table.shape[0] - 1)
+    return table, idx.astype(np.int64), 0
+
+
+def _monotone_fill(rng):
+    """The dense step's fill: runs that repeat one slot (invalid rows, pad
+    ranks) between strictly increasing child ranks, row after row."""
+    table = rng.integers(0, 256, size=1 << 16, dtype=np.uint8)
+    parts, base = [], 0
+    for _ in range(6):
+        run = base + np.cumsum(rng.integers(1, 3, size=900))
+        parts += [run, np.full(300, run[-1])]
+        base = run[-1] + int(rng.integers(0, 5000))
+    return table, np.concatenate(parts).astype(np.int32), 0
+
+
+@pytest.mark.parametrize("make", [_one_jump, _wider_than_views, _u8_int64,
+                                  _monotone_fill],
+                         ids=["one_jump", "wider_than_views", "u8_int64",
+                              "monotone_fill"])
+def test_structured_index_patterns(make):
+    table, idx, want_miss = make(np.random.default_rng(11))
+    out, nmiss = _gather(table, idx)
+    np.testing.assert_array_equal(out, table[idx])
+    ok = _reference_ok_mask(table, idx, block=1024, window=8192)
+    assert nmiss == int((~ok).sum())
+    if want_miss is not None:
+        assert nmiss == want_miss
+    else:
+        assert nmiss > 0
 
 
 @settings(max_examples=25, deadline=None)
 @given(
     logm=st.integers(10, 16),
     n=st.integers(1, 4000),
-    block=st.sampled_from([128, 256, 512]),
+    block=st.sampled_from([1024, 2048]),
     window=st.sampled_from([1024, 2048, 4096]),
     local=st.booleans(),
     seed=st.integers(0, 2**31),
@@ -129,26 +181,18 @@ def _reference_ok_mask(table, idx, block, window):
 def test_property_hits_exact_misses_flagged(logm, n, block, window, local,
                                             seed):
     # For ANY table size, length, block/window config and ANY
-    # non-decreasing index vector: (a) every non-missed element equals
-    # table[idx]; (b) nmiss == 0 exactly when no real element misses;
-    # (c) when misses exist, nmiss covers at least the real ones (tail
-    # padding replicas may inflate it, per the contract).
+    # non-decreasing index vector: every lane equals table[idx], and the
+    # miss count is exactly the lanes outside both views.
     table, idx = _case(1 << logm, n, seed, span=3 if local else None)
-    out, nmiss = monotone_window_gather(table, idx, block=block,
-                                        window=window, interpret=True)
+    out, nmiss = _gather(table, idx, block=block, window=window)
+    np.testing.assert_array_equal(out, table[idx])
     ok = _reference_ok_mask(table, idx, block, window)
-    np.testing.assert_array_equal(np.asarray(out)[ok], table[idx[ok]])
-    real_misses = int((~ok).sum())
-    if real_misses == 0:
-        assert int(nmiss) == 0
-    else:
-        assert int(nmiss) >= real_misses
+    assert nmiss == int((~ok).sum())
 
 
-@pytest.mark.parametrize("n", [1, 255, 256, 257, 5000])
+@pytest.mark.parametrize("n", [1, 255, 256, 257, 5000, 1023, 1025, 65537])
 def test_ragged_lengths(n):
     table, idx = _case(1 << 14, n, n, span=2)
-    out, nmiss = monotone_window_gather(table, idx, block=256, window=2048,
-                                        interpret=True)
-    assert int(nmiss) == 0
-    np.testing.assert_array_equal(np.asarray(out), table[idx])
+    out, nmiss = _gather(table, idx, window=2048)
+    assert nmiss == 0
+    np.testing.assert_array_equal(out, table[idx])

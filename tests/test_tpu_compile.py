@@ -94,20 +94,36 @@ def test_fused_dedup_scatterinv_compiles(one_chip):
         .lower(flat).compile()
 
 
-def test_dense_mid_level_step_compiles(one_chip):
-    """One mid-level backward kernel of the dense engine for 6x4, the
-    engine --engine auto picks on the TPU."""
+def _compile_dense_step(level, gather_mode, one_chip, monkeypatch):
     from gamesmanmpi_tpu.solve.dense import DenseSolver, build_dense_step
 
     ds = DenseSolver(get_game("connect4:w=6,h=4"), store_tables=False)
-    level = ds.tables.ncells // 2
+    ds.gather_mode = gather_mode
     cblock, _ = ds._cblock(level)
+    # build_dense_step interprets a Pallas kernel when the default
+    # backend is the CPU, as it is here: build the step as the TPU would.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     fn = build_dense_step(ds.tables, level, cblock, ds._rank_dtype,
                           ds._flat_dtype, ds.use_onehot,
-                          fused_rank=ds.use_fused, gather_mode="plain")
+                          fused_rank=ds.use_fused, gather_mode=gather_mode)
     avals = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
              for a in ds._avals(level, cblock, for_reach=False)]
-    jax.jit(fn).lower(*avals).compile()
+    return jax.jit(fn).lower(*avals).compile()
+
+
+def test_dense_mid_level_step_compiles(one_chip, monkeypatch):
+    """One mid-level backward kernel of the dense engine for 6x4 with the
+    plain XLA gather."""
+    _compile_dense_step(12, "plain", one_chip, monkeypatch)
+
+
+@pytest.mark.parametrize("level", [12, 23])
+def test_dense_pallas_step_compiles(level, one_chip, monkeypatch):
+    """The dense backward step --engine auto runs on the TPU: 6x4's middle
+    level and level 23 (the widest colex jumps) with the Pallas window
+    gather, which must be in the program as a Mosaic custom call."""
+    compiled = _compile_dense_step(level, "pallas", one_chip, monkeypatch)
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_sharded_forward_step_compiles_with_all_to_all(mesh4, accel_knobs):
@@ -124,16 +140,6 @@ def test_sharded_forward_step_compiles_with_all_to_all(mesh4, accel_knobs):
     assert "all-to-all" in compiled.as_text()
 
 
-class PinnedMosaicRejection(Exception):
-    """The one failure the Pallas gather test expects."""
-
-
-@pytest.mark.xfail(
-    strict=True, raises=PinnedMosaicRejection,
-    reason="Mosaic: Not implemented: Multiple source vregs along gather "
-    "dimension (tpu.dynamic_gather over vector<128x128xi32>); flip when "
-    "ops/pallas_gather.py is repaired or deleted",
-)
 @pytest.mark.parametrize("idx_dtype", ["int32", "int64"])
 def test_pallas_monotone_gather_compiles(idx_dtype, one_chip):
     from gamesmanmpi_tpu.ops.pallas_gather import monotone_window_gather
@@ -141,10 +147,5 @@ def test_pallas_monotone_gather_compiles(idx_dtype, one_chip):
     table = jax.ShapeDtypeStruct((1 << 22,), jnp.uint8, sharding=one_chip)
     idx = jax.ShapeDtypeStruct((1 << 20,), jnp.dtype(idx_dtype),
                                sharding=one_chip)
-    try:
-        jax.jit(lambda t, i: monotone_window_gather(
-            t, i, block=2048, window=8192)).lower(table, idx).compile()
-    except Exception as e:
-        if "Multiple source vregs along gather dimension" in str(e):
-            raise PinnedMosaicRejection(str(e)) from e
-        raise
+    compiled = jax.jit(monotone_window_gather).lower(table, idx).compile()
+    assert "tpu_custom_call" in compiled.as_text()

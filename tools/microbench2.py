@@ -223,37 +223,6 @@ def main():
             print(f"pallas unavailable: {type(e).__name__}: {e}"[:200],
                   flush=True)
 
-    # 7b. the monotone-window gather scaffold (ops/pallas_gather.py):
-    # Mosaic go/no-go + throughput vs XLA's gather on the same sorted
-    # indices — the dense backward's candidate kernel.
-    if not quick:
-        try:
-            from gamesmanmpi_tpu.ops.pallas_gather import (
-                monotone_window_gather,
-            )
-
-            m8 = 8 * 1024 * 1024
-            tb = jnp.asarray(
-                rng.integers(0, 1 << 30, size=m8, dtype=np.uint32)
-            )
-            # Sorted-random over the full table (NOT a cumsum, which would
-            # saturate at m8 and degenerate into re-reading one element).
-            mono = jnp.asarray(np.sort(
-                rng.integers(0, m8, size=N)
-            ).astype(np.int32))
-            timeit(
-                f"pallas monotone gather [{N>>20}M from 8M]",
-                lambda t, i: monotone_window_gather(t, i)[0], tb, mono,
-                bytes_moved=4 * N,
-            )
-            timeit(
-                f"xla gather same monotone idx [{N>>20}M from 8M]",
-                lambda t, i: t[i], tb, mono, bytes_moved=4 * N,
-            )
-        except Exception as e:  # pragma: no cover
-            print(f"pallas monotone gather unavailable: "
-                  f"{type(e).__name__}: {e}"[:200], flush=True)
-
     # 8. u64 sort (the 6x5+ board dtype)
     keys64 = keys.astype(jnp.uint64)
     timeit(f"sort u64 [{N>>20}M]", jnp.sort, keys64, bytes_moved=2 * 8 * N)
