@@ -106,6 +106,7 @@ from gamesmanmpi_tpu.obs import (
     Span,
     default_registry,
     maybe_status_server,
+    trace_span,
 )
 from gamesmanmpi_tpu.obs import flightrec
 from gamesmanmpi_tpu.obs import status as obs_status
@@ -215,6 +216,14 @@ def _all_to_all(x):
                                   tiled=True)
 
 
+@jax.named_scope("route")
+def _gather_counts(count, counts):
+    """The forward step's control plane, replicated on every shard: [S]
+    unique counts and [S, S] per-(source, destination) send counts."""
+    return jax.lax.all_gather(count, AXIS), jax.lax.all_gather(counts, AXIS)
+
+
+@jax.named_scope("route")
 def _route_by_owner(flat, S: int, cap_out: int, sentinel):
     """Bucket a flat state array by owner shard for all_to_all routing.
 
@@ -267,11 +276,12 @@ def _sharded_forward_step(game: TensorGame, S: int, route_cap: int, local,
     """
     sentinel = game.sentinel
     local = local[0]
-    valid = local != sentinel
-    prim = game.primitive(local)
-    active = valid & (prim == UNDECIDED)
-    children, _ = canonical_children(game, local, active)
-    flat = children.reshape(-1)
+    with jax.named_scope("expand"):
+        valid = local != sentinel
+        prim = game.primitive(local)
+        active = valid & (prim == UNDECIDED)
+        children, _ = canonical_children(game, local, active)
+        flat = children.reshape(-1)
     send, counts, s_owner, pos, order = _route_by_owner(
         flat, S, route_cap, sentinel
     )
@@ -289,8 +299,7 @@ def _sharded_forward_step(game: TensorGame, S: int, route_cap: int, local,
                                             fused, merge, compact)
         else:
             uniq, count = sort_unique(routed.reshape(-1), merge, compact)
-        all_counts = jax.lax.all_gather(count, AXIS)  # [S] replicated
-        all_sends = jax.lax.all_gather(counts, AXIS)  # [S, S] replicated
+        all_counts, all_sends = _gather_counts(count, counts)
         return uniq[None], all_counts, all_sends
     if fused:
         uniq, count, uidx = fused_dedup_provenance(
@@ -307,11 +316,12 @@ def _sharded_forward_step(game: TensorGame, S: int, route_cap: int, local,
     # slot[j]: where child slot j's reply lives in eidx.reshape(-1). Out-of-
     # range rows (sentinel children, owner==S) and overflow (pos >=
     # route_cap — the host retries it at a larger capacity) map to -1.
-    in_range = (s_owner < S) & (pos < route_cap)
-    lin = jnp.where(in_range, s_owner * route_cap + pos, -1).astype(jnp.int32)
-    slot = jnp.full((flat.shape[0],), -1, jnp.int32).at[order].set(lin)
-    all_counts = jax.lax.all_gather(count, AXIS)  # [S] replicated
-    all_sends = jax.lax.all_gather(counts, AXIS)  # [S, S] replicated
+    with jax.named_scope("route"):
+        in_range = (s_owner < S) & (pos < route_cap)
+        lin = jnp.where(in_range, s_owner * route_cap + pos,
+                        -1).astype(jnp.int32)
+        slot = jnp.full((flat.shape[0],), -1, jnp.int32).at[order].set(lin)
+    all_counts, all_sends = _gather_counts(count, counts)
     return uniq[None], eidx.reshape(-1)[None], slot[None], all_counts, \
         all_sends
 
@@ -325,10 +335,11 @@ def _route_core(game: TensorGame, S: int, qcap: int, local):
     step and the streamed route phase so the two can never drift.
     """
     sentinel = game.sentinel
-    prim = game.primitive(local)
-    undecided = (local != sentinel) & (prim == UNDECIDED)
-    children, _ = canonical_children(game, local, undecided)
-    flat = children.reshape(-1)
+    with jax.named_scope("expand"):
+        prim = game.primitive(local)
+        undecided = (local != sentinel) & (prim == UNDECIDED)
+        children, _ = canonical_children(game, local, undecided)
+        flat = children.reshape(-1)
     send, qcounts, s_owner, pos, order = _route_by_owner(
         flat, S, qcap, sentinel
     )
@@ -347,33 +358,38 @@ def _reply_core(game: TensorGame, S: int, qcap: int, local, reply, s_owner,
     misses scalar, NOT yet psum'd).
     """
     sentinel = game.sentinel
-    valid = local != sentinel
-    prim = game.primitive(local)
-    undecided = valid & (prim == UNDECIDED)
-    children, mask = canonical_children(game, local, undecided)
+    with jax.named_scope("expand"):
+        valid = local != sentinel
+        prim = game.primitive(local)
+        undecided = valid & (prim == UNDECIDED)
+        children, mask = canonical_children(game, local, undecided)
     B, M = children.shape
-    if qcap == 0:
-        child_vals = jnp.full((B, M), UNDECIDED, dtype=jnp.uint8)
-        child_rem = jnp.zeros((B, M), dtype=jnp.int32)
-        hit = jnp.zeros((B, M), dtype=bool)
-    else:
-        in_range = (s_owner < S) & (pos < qcap)
-        got = reply[jnp.clip(s_owner, 0, S - 1), jnp.clip(pos, 0, qcap - 1)]
-        got = jnp.where(in_range, got, 0)
-        flat_reply = (
-            jnp.zeros((B * M,), dtype=reply.dtype).at[order].set(got)
+    with jax.named_scope("gather"):
+        if qcap == 0:
+            child_vals = jnp.full((B, M), UNDECIDED, dtype=jnp.uint8)
+            child_rem = jnp.zeros((B, M), dtype=jnp.int32)
+            hit = jnp.zeros((B, M), dtype=bool)
+        else:
+            in_range = (s_owner < S) & (pos < qcap)
+            got = reply[jnp.clip(s_owner, 0, S - 1),
+                        jnp.clip(pos, 0, qcap - 1)]
+            got = jnp.where(in_range, got, 0)
+            flat_reply = (
+                jnp.zeros((B * M,), dtype=reply.dtype).at[order].set(got)
+            )
+            child_vals, child_rem = unpack_cells(flat_reply.reshape(B, M))
+            hit = child_vals != UNDECIDED
+    with jax.named_scope("combine"):
+        values, remoteness = combine_children(child_vals, child_rem, mask)
+        values = jnp.where(undecided, values,
+                           jnp.where(valid, prim, UNDECIDED))
+        remoteness = jnp.where(undecided, remoteness, 0)
+        # Consistency counters (SURVEY.md §5.2): missed child lookups
+        # (including routing overflow, which the host retries) + zero-move
+        # UNDECIDED positions (see engine.resolve_level).
+        misses = jnp.sum(mask & ~hit) + jnp.sum(
+            undecided & ~jnp.any(mask, axis=-1)
         )
-        child_vals, child_rem = unpack_cells(flat_reply.reshape(B, M))
-        hit = child_vals != UNDECIDED
-    values, remoteness = combine_children(child_vals, child_rem, mask)
-    values = jnp.where(undecided, values, jnp.where(valid, prim, UNDECIDED))
-    remoteness = jnp.where(undecided, remoteness, 0)
-    # Consistency counters (SURVEY.md §5.2): missed child lookups (including
-    # routing overflow, which the host retries) + zero-move UNDECIDED
-    # positions (see engine.resolve_level).
-    misses = jnp.sum(mask & ~hit) + jnp.sum(
-        undecided & ~jnp.any(mask, axis=-1)
-    )
     return values, remoteness, misses
 
 
@@ -408,14 +424,16 @@ def _sharded_backward_step(game: TensorGame, S: int, qcap: int, local,
             game, S, qcap, local
         )
         vals, rems, _ = lookup_window(queries.reshape(-1), window, method)
-        reply = pack_cells(vals, rems).reshape(S, qcap)
+        with jax.named_scope("lookup"):
+            reply = pack_cells(vals, rems).reshape(S, qcap)
         reply = _all_to_all(reply)
     values, remoteness, misses = _reply_core(
         game, S, qcap, local, reply, s_owner, pos, order
     )
     # Control plane replicated for multi-host readability (see forward step).
-    total_misses = jax.lax.psum(misses, AXIS)
-    all_qcounts = jax.lax.all_gather(qcounts, AXIS)  # [S, S] replicated
+    with jax.named_scope("route"):
+        total_misses = jax.lax.psum(misses, AXIS)
+        all_qcounts = jax.lax.all_gather(qcounts, AXIS)  # [S, S] replicated
     return values[None], remoteness[None], total_misses, all_qcounts
 
 
@@ -430,10 +448,12 @@ def _sharded_route_step(game: TensorGame, S: int, qcap: int, local):
     """
     local = local[0]
     queries, qcounts, s_owner, pos, order = _route_core(game, S, qcap, local)
-    all_qcounts = jax.lax.all_gather(qcounts, AXIS)  # [S, S] replicated
-    # The accumulator is born on device here (one extra output) — creating
-    # it outside would cost a dedicated zeros kernel compile per shape.
-    acc = jnp.zeros(queries.shape, dtype=jnp.uint32)
+    with jax.named_scope("route"):
+        all_qcounts = jax.lax.all_gather(qcounts, AXIS)  # [S, S] replicated
+        # The accumulator is born on device here (one extra output) —
+        # creating it outside would cost a dedicated zeros kernel compile
+        # per shape.
+        acc = jnp.zeros(queries.shape, dtype=jnp.uint32)
     return (
         queries[None],
         acc[None],
@@ -456,8 +476,9 @@ def _sharded_lookup_acc_step(queries, acc, wstates, wvals, wrem,
     """
     q = queries[0].reshape(-1)
     v, r, h = lookup_sorted(q, wstates[0], wvals[0], wrem[0], method)
-    cell = pack_cells(v, r)
-    out = jnp.where(h, cell, acc[0].reshape(-1))
+    with jax.named_scope("lookup"):
+        cell = pack_cells(v, r)
+        out = jnp.where(h, cell, acc[0].reshape(-1))
     return out.reshape(acc[0].shape)[None]
 
 
@@ -474,7 +495,8 @@ def _sharded_reply_step(game: TensorGame, S: int, qcap: int, local, acc,
     values, remoteness, misses = _reply_core(
         game, S, qcap, local, reply, s_owner[0], pos[0], order[0]
     )
-    total_misses = jax.lax.psum(misses, AXIS)
+    with jax.named_scope("route"):
+        total_misses = jax.lax.psum(misses, AXIS)
     return values[None], remoteness[None], total_misses
 
 
@@ -489,10 +511,12 @@ def _sharded_edges_route_step(S: int, ecap: int, eidx):
     """
     e = eidx[0].reshape(S, ecap)
     q = _all_to_all(e)
-    acc = jnp.zeros((S * ecap,), dtype=jnp.uint32)
+    with jax.named_scope("route"):
+        acc = jnp.zeros((S * ecap,), dtype=jnp.uint32)
     return q.reshape(-1)[None], acc[None]
 
 
+@jax.named_scope("gather")
 def _sharded_edges_gather_step(q, acc, wvals, wrem, off):
     """Edge-cached backward, phase 2 (once per window block): owner gather.
 
@@ -525,19 +549,25 @@ def _sharded_edges_reply_step(game: TensorGame, S: int, ecap: int, local,
     local = local[0]
     reply = _all_to_all(acc[0].reshape(S, ecap)).reshape(-1)
     sl = slot[0]
-    got = jnp.where(
-        sl >= 0, reply[jnp.clip(sl, 0, reply.shape[0] - 1)], jnp.uint32(0)
-    )
-    cv, cr, mask = combine_edge_cells(got, game.max_moves)
-    valid = local != game.sentinel
-    prim = game.primitive(local)
-    undecided = valid & (prim == UNDECIDED)
-    mask = mask & undecided[:, None]
-    values, remoteness = combine_children(cv, cr, mask)
-    values = jnp.where(undecided, values, jnp.where(valid, prim, UNDECIDED))
-    remoteness = jnp.where(undecided, remoteness, 0)
-    misses = jnp.sum(undecided & ~jnp.any(mask, axis=-1))
-    return values[None], remoteness[None], jax.lax.psum(misses, AXIS)
+    with jax.named_scope("gather"):
+        got = jnp.where(
+            sl >= 0, reply[jnp.clip(sl, 0, reply.shape[0] - 1)],
+            jnp.uint32(0)
+        )
+    with jax.named_scope("combine"):
+        cv, cr, mask = combine_edge_cells(got, game.max_moves)
+        valid = local != game.sentinel
+        prim = game.primitive(local)
+        undecided = valid & (prim == UNDECIDED)
+        mask = mask & undecided[:, None]
+        values, remoteness = combine_children(cv, cr, mask)
+        values = jnp.where(undecided, values,
+                           jnp.where(valid, prim, UNDECIDED))
+        remoteness = jnp.where(undecided, remoteness, 0)
+        misses = jnp.sum(undecided & ~jnp.any(mask, axis=-1))
+    with jax.named_scope("route"):
+        total_misses = jax.lax.psum(misses, AXIS)
+    return values[None], remoteness[None], total_misses
 
 
 class _HostSpill:
@@ -590,7 +620,7 @@ class _SLevel:
     — lookup backward for this level).
     """
 
-    __slots__ = ("counts", "dev", "host", "eidx", "slot", "ecap")
+    __slots__ = ("counts", "dev", "host", "eidx", "slot", "ecap", "sent")
 
     def __init__(self, counts: np.ndarray, dev, host):
         self.counts = counts  # np [S] real (non-sentinel) per-shard counts
@@ -599,6 +629,9 @@ class _SLevel:
         self.eidx = None  # [S, S*ecap] int32 edge indices (see class doc)
         self.slot = None  # [S, cap*M] int32 reply-slot map
         self.ecap = 0  # per-(src,dst) routing capacity the edges used
+        #: children this level routed in forward, one per move edge (the
+        #: edge-cached backward routes the same edges back); None unknown
+        self.sent = None
 
     def host_shards(self) -> List[np.ndarray]:
         if self.host is None:
@@ -735,6 +768,10 @@ class ShardedSolver:
         # against ICI/HBM rooflines (docs/ARCHITECTURE.md "Efficiency
         # accounting").
         self.bytes_routed = 0
+        #: the all_to_all buffers' bytes, capacity padding included —
+        #: what the collectives moved (bytes_routed counts only the real
+        #: entries in them), every overflow retry's attempt counted.
+        self.bytes_routed_padded = 0
         self.bytes_sorted = 0
         self.bytes_gathered = 0
         #: transient level-step failures absorbed by retry (stats field).
@@ -785,6 +822,13 @@ class ShardedSolver:
         # (same shard count over different device sets must not share).
         self._mesh_key = tuple(d.id for d in self.mesh.devices.flat)
         self._sharding = NamedSharding(self.mesh, P(AXIS))
+
+    def _download(self, level: int, kind: str, nbytes: int):
+        """The ``download`` span (docs/OBSERVABILITY.md) around one copy
+        of device arrays to the host: a level's ``table``, a spilled
+        ``window`` or ``edges``, or a ``level`` kept on the host."""
+        return trace_span("download", logger=self.logger, level=int(level),
+                          kind=kind, bytes=int(nbytes))
 
     def _check_preempt(self, phase: str, level) -> None:
         """Rank-coordinated level-boundary preemption point (ISSUE 12).
@@ -1251,6 +1295,9 @@ class ShardedSolver:
         mesh = self.mesh
 
         def build(game):
+            # The dedup's output trimmed (or padded) to the next level's
+            # capacity bucket: the tail of the dedup.
+            @jax.named_scope("dedup")
             def per_shard(local):
                 x = local[0]
                 if out_cap <= in_cap:
@@ -1377,16 +1424,18 @@ class ShardedSolver:
         def build(game):
             def per_shard(states, values, rem, query):
                 ts, tv, tr = states[0], values[0], rem[0]
-                idx = jnp.clip(
-                    jnp.searchsorted(ts, query[0]), 0, ts.shape[0] - 1
-                )
-                hit = ts[idx] == query[0]
-                v = jnp.where(hit, tv[idx].astype(jnp.int32), 0)
-                r = jnp.where(hit, tr[idx], 0)
-                return (
-                    jax.lax.psum(v, AXIS),
-                    jax.lax.psum(r, AXIS),
-                )
+                with jax.named_scope("lookup"):
+                    idx = jnp.clip(
+                        jnp.searchsorted(ts, query[0]), 0, ts.shape[0] - 1
+                    )
+                    hit = ts[idx] == query[0]
+                    v = jnp.where(hit, tv[idx].astype(jnp.int32), 0)
+                    r = jnp.where(hit, tr[idx], 0)
+                with jax.named_scope("route"):
+                    return (
+                        jax.lax.psum(v, AXIS),
+                        jax.lax.psum(r, AXIS),
+                    )
 
             return shard_map(
                 per_shard,
@@ -1415,6 +1464,7 @@ class ShardedSolver:
             # resolved at cache-key time
             mb, cm = use_merge_sort(), compact_method()
 
+            @jax.named_scope("dedup")
             def per_shard(pool, kids, target):
                 p, c = pool[0], kids[0]
                 lv = jnp.where(
@@ -1424,7 +1474,8 @@ class ShardedSolver:
                 uniq, count = sort_unique(
                     jnp.concatenate([p, sel]), mb, cm
                 )
-                return uniq[None], jax.lax.all_gather(count, AXIS)
+                with jax.named_scope("route"):
+                    return uniq[None], jax.lax.all_gather(count, AXIS)
 
             return shard_map(
                 per_shard,
@@ -1455,15 +1506,18 @@ class ShardedSolver:
 
             def per_shard(kids, kmin, kmax):
                 c = kids[0]
-                valid = c != game.sentinel
-                lv = jnp.where(valid, game.level_of(c), -1)
-                bad = jnp.sum(
-                    valid & ((lv <= kmin[0]) | (lv > kmax[0]))
-                )
-                per = jnp.stack(
-                    [jnp.sum(lv == kmin[0] + 1 + j) for j in range(J)]
-                )
-                return jax.lax.psum(bad, AXIS), jax.lax.psum(per, AXIS)
+                with jax.named_scope("expand"):
+                    valid = c != game.sentinel
+                    lv = jnp.where(valid, game.level_of(c), -1)
+                    bad = jnp.sum(
+                        valid & ((lv <= kmin[0]) | (lv > kmax[0]))
+                    )
+                    per = jnp.stack(
+                        [jnp.sum(lv == kmin[0] + 1 + j) for j in range(J)]
+                    )
+                with jax.named_scope("route"):
+                    return (jax.lax.psum(bad, AXIS),
+                            jax.lax.psum(per, AXIS))
 
             return shard_map(
                 per_shard,
@@ -1564,17 +1618,23 @@ class ShardedSolver:
             k = start_level
             self._ckpt_forward_level(k, levels[k])
         stored_bytes = frontier.nbytes
+        item = np.dtype(g.state_dtype).itemsize
+        # Routed bytes per child: its state out, and with edges the uid
+        # reply riding back.
+        per_child = item + 4 if self.use_edges else item
         while True:
-            t0 = time.perf_counter()
+            n = int(levels[k].counts.sum())
+            sp = Span("forward", logger=self.logger, level=k)
             self.progress = {
                 "phase": "forward", "level": k, "rank": self.rank,
-                "frontier": int(levels[k].counts.sum()),
+                "frontier": n,
             }
             # Level boundary: level k's incremental frontier (and edge)
             # files are already enqueued/sealed — a grace signal stops
             # HERE and resume re-expands from the deepest sealed level.
             self._check_preempt("forward", k)
-            b0 = (self.bytes_routed, self.bytes_sorted)
+            b0 = (self.bytes_routed, self.bytes_sorted,
+                  self.bytes_routed_padded)
             disp0 = self.dispatch_total
             route_cap = self._initial_route_cap(cap)
             eidx = slot = None
@@ -1590,26 +1650,28 @@ class ShardedSolver:
                     else:
                         u, c, sc = self._forward_fn(cap, route_cap)(frontier)
                         e = sl = None
-                    return u, e, sl, c, int(np.asarray(sc).max())
+                    return u, e, sl, c, np.asarray(sc)
 
-                uniq, eidx, slot, count, max_sent = self._retry(
+                uniq, eidx, slot, count, sends = self._retry(
                     "sharded.forward", _step, level=k,
                     entry=lambda k=k: faults.fire("sharded.forward",
                                                   level=k),
                 )
+                self.bytes_routed_padded += S * S * route_cap * per_child
+                max_sent = int(sends.max())
                 if max_sent <= route_cap:
                     break
                 self.spill_retries += 1
                 route_cap = bucket_size(max_sent)
-            item = np.dtype(g.state_dtype).itemsize
+            sent = int(sends.sum())
+            levels[k].sent = sent
+            self.bytes_routed += sent * per_child
             compaction = compaction_sort_bytes(item)
             # Fused dedup changes the sort-operand denominator (ISSUE 14):
             # callback = one numpy radix pass over the routed block;
             # scatterinv = ONE pair sort + compaction instead of two.
             fz = fused_dedup_method() if fused_enabled() else None
             if self.use_edges:
-                # States out + the uid reply riding back.
-                self.bytes_routed += S * S * route_cap * (item + 4)
                 if fz == "callback":
                     prov_bytes = item
                 elif fz == "scatterinv":
@@ -1618,17 +1680,15 @@ class ShardedSolver:
                     prov_bytes = provenance_sort_bytes(item, compaction)
                 self.bytes_sorted += S * S * route_cap * prov_bytes
             else:
-                self.bytes_routed += S * S * route_cap * item
                 self.bytes_sorted += S * S * route_cap * (
                     item if fz == "callback" else item + compaction
                 )
             counts = np.asarray(count).reshape(-1).astype(np.int64)
             total = int(counts.sum())
             if total == 0:
-                self.status_tracker.forward_level(
-                    k, int(levels[k].counts.sum()),
-                    time.perf_counter() - t0,
-                )
+                # Terminal probe: the span is traced, but writes no JSONL
+                # record (no level was discovered).
+                self.status_tracker.forward_level(k, n, sp.end(log=False))
                 flightrec.boundary("forward", k)
                 break
             if self.use_edges:
@@ -1645,8 +1705,9 @@ class ShardedSolver:
                     cur.eidx, cur.slot = eidx, slot
                     stored_bytes += extra
                 else:
-                    cur.eidx = _HostSpill.download(eidx)
-                    cur.slot = _HostSpill.download(slot)
+                    with self._download(k, "edges", extra):
+                        cur.eidx = _HostSpill.download(eidx)
+                        cur.slot = _HostSpill.download(slot)
                     self.edges_bytes_spilled += extra
                     # Disk tier (ISSUE 11): when the host-RAM tier (the
                     # store cache budget) is exhausted too AND the edge
@@ -1680,7 +1741,8 @@ class ShardedSolver:
                 # Device-store budget exhausted: keep this level on host only
                 # (backward re-uploads it); the live frontier still chains on
                 # device.
-                rec.host_shards()
+                with self._download(k + 1, "level", nxt.nbytes):
+                    rec.host_shards()
                 rec.dev = None
             else:
                 stored_bytes += nxt.nbytes
@@ -1688,26 +1750,19 @@ class ShardedSolver:
             frontier = nxt
             cap = next_cap
             self._ckpt_forward_level(k + 1, rec)
-            lvl_secs = time.perf_counter() - t0
-            if self.logger is not None:
-                self.logger.log(
-                    {
-                        "phase": "forward",
-                        "level": k,
-                        "frontier": int(levels[k].counts.sum()),
-                        "children": total,
-                        "shards": S,
-                        "route_cap": route_cap,
-                        "bytes_routed": self.bytes_routed - b0[0],
-                        "bytes_sorted": self.bytes_sorted - b0[1],
-                        "bytes_hbm": self.bytes_sorted - b0[1],
-                        "dispatches": self.dispatch_total - disp0,
-                        "secs": lvl_secs,
-                    }
-                )
-            self.status_tracker.forward_level(
-                k, int(levels[k].counts.sum()), lvl_secs
+            lvl_secs = sp.end(
+                n=n,
+                frontier=n,
+                children=total,
+                shards=S,
+                route_cap=route_cap,
+                bytes_routed=self.bytes_routed - b0[0],
+                bytes_routed_padded=self.bytes_routed_padded - b0[2],
+                bytes_sorted=self.bytes_sorted - b0[1],
+                bytes_hbm=self.bytes_sorted - b0[1],
+                dispatches=self.dispatch_total - disp0,
             )
+            self.status_tracker.forward_level(k, n, lvl_secs)
             flightrec.boundary("forward", k)
             k += 1
         return levels
@@ -1734,13 +1789,15 @@ class ShardedSolver:
         #: level -> (dev [S, cap] per-shard sorted pool, np [S] counts)
         pools: Dict[int, tuple] = {start_level: (frontier0, counts)}
         stored_bytes = 0
+        item = np.dtype(g.state_dtype).itemsize
         while pools:
             k = min(pools)
-            t0 = time.perf_counter()
+            sp = Span("forward", logger=self.logger, level=k)
             self.progress = {"phase": "forward", "level": k,
                              "rank": self.rank}
             self._check_preempt("forward", k)
-            b0 = (self.bytes_routed, self.bytes_sorted)
+            b0 = (self.bytes_routed, self.bytes_sorted,
+                  self.bytes_routed_padded)
             disp0 = self.dispatch_total
             frontier, counts = pools.pop(k)
             rec = _SLevel(counts, frontier, None)
@@ -1751,7 +1808,8 @@ class ShardedSolver:
             pending_bytes = sum(p.nbytes for p, _ in pools.values())
             if (stored_bytes + pending_bytes + frontier.nbytes
                     > self.device_store_bytes):
-                rec.host_shards()
+                with self._download(k, "level", frontier.nbytes):
+                    rec.host_shards()
                 rec.dev = None
             else:
                 stored_bytes += frontier.nbytes
@@ -1760,21 +1818,22 @@ class ShardedSolver:
             while True:
                 def _step(cap=cap, route_cap=route_cap, frontier=frontier):
                     u, c, sc = self._forward_fn(cap, route_cap)(frontier)
-                    return u, c, int(np.asarray(sc).max())
+                    return u, c, np.asarray(sc)
 
-                uniq, count, max_sent = self._retry(
+                uniq, count, sends = self._retry(
                     "sharded.forward", _step, level=k,
                     entry=lambda k=k: faults.fire("sharded.forward",
                                                   level=k),
                 )
+                self.bytes_routed_padded += S * S * route_cap * item
+                max_sent = int(sends.max())
                 if max_sent <= route_cap:
                     break
                 self.spill_retries += 1
                 route_cap = bucket_size(max_sent)
-            item = np.dtype(g.state_dtype).itemsize
+            self.bytes_routed += int(sends.sum()) * item
             compaction = compaction_sort_bytes(item)
             fz = fused_dedup_method() if fused_enabled() else None
-            self.bytes_routed += S * S * route_cap * item
             self.bytes_sorted += S * S * route_cap * (
                 item if fz == "callback" else item + compaction
             )
@@ -1852,31 +1911,29 @@ class ShardedSolver:
                     self.bytes_sorted += (
                         S * (pool.shape[1] + ccap) * (item + compaction)
                     )
-            lvl_secs = time.perf_counter() - t0
-            if self.logger is not None:
-                self.logger.log(
-                    {
-                        "phase": "forward",
-                        "level": k,
-                        "frontier": int(counts.sum()),
-                        "children": total,
-                        "shards": S,
-                        "route_cap": route_cap,
-                        "bytes_routed": self.bytes_routed - b0[0],
-                        "bytes_sorted": self.bytes_sorted - b0[1],
-                        "bytes_hbm": self.bytes_sorted - b0[1],
-                        "dispatches": self.dispatch_total - disp0,
-                        "secs": lvl_secs,
-                    }
-                )
-            self.status_tracker.forward_level(k, int(counts.sum()),
-                                              lvl_secs)
+            n = int(counts.sum())
+            lvl_secs = sp.end(
+                n=n,
+                frontier=n,
+                children=total,
+                shards=S,
+                route_cap=route_cap,
+                bytes_routed=self.bytes_routed - b0[0],
+                bytes_routed_padded=self.bytes_routed_padded - b0[2],
+                bytes_sorted=self.bytes_sorted - b0[1],
+                bytes_hbm=self.bytes_sorted - b0[1],
+                dispatches=self.dispatch_total - disp0,
+            )
+            self.status_tracker.forward_level(k, n, lvl_secs)
             flightrec.boundary("forward", k)
         return levels
 
     def _run_backward_step(self, stacked, cap: int, window_caps: tuple,
                            window_flat) -> tuple:
         """One backward kernel call with the qcap overflow-retry loop."""
+        S = self.S
+        # Queries out (state bytes) + packed cells back.
+        item = np.dtype(self.game.state_dtype).itemsize
         qcap = self._initial_route_cap(cap) if window_caps else 0
         while True:
             values, rem, misses, qcounts = self._backward_fn(
@@ -1884,16 +1941,15 @@ class ShardedSolver:
             )(stacked, *window_flat)
             if qcap == 0:
                 break
-            max_sent = int(np.asarray(qcounts).max())
+            qcounts = np.asarray(qcounts)
+            self.bytes_routed_padded += S * S * qcap * (item + 4)
+            max_sent = int(qcounts.max())
             if max_sent <= qcap:
                 break
             self.spill_retries += 1
             qcap = bucket_size(max_sent)
         if qcap:
-            S = self.S
-            item = np.dtype(self.game.state_dtype).itemsize
-            # Queries out (state bytes) + packed cells back.
-            self.bytes_routed += S * S * qcap * (item + 4)
+            self.bytes_routed += int(qcounts.sum()) * (item + 4)
             if search_method() == "sort":
                 # Sort-merge join operands + fused payload gather w/ idx.
                 self.bytes_sorted += (
@@ -1921,19 +1977,21 @@ class ShardedSolver:
         fix direction is a rotating HBM pool of window blocks shared across
         resolve blocks; not needed below 7x6 scale.
         """
+        S = self.S
+        item = np.dtype(self.game.state_dtype).itemsize
         qcap = self._initial_route_cap(cap)
         while True:
             queries, acc, s_owner, pos, order, qcounts = self._route_fn(
                 cap, qcap
             )(stacked)
-            max_sent = int(np.asarray(qcounts).max())
+            qcounts = np.asarray(qcounts)
+            self.bytes_routed_padded += S * S * qcap * (item + 4)
+            max_sent = int(qcounts.max())
             if max_sent <= qcap:
                 break
             self.spill_retries += 1
             qcap = bucket_size(max_sent)
-        S = self.S
-        item = np.dtype(self.game.state_dtype).itemsize
-        self.bytes_routed += S * S * qcap * (item + 4)
+        self.bytes_routed += int(qcounts.sum()) * (item + 4)
         for ws, wv, wr in windows:
             wb = min(self.window_block, ws.cap)
             for off in range(0, ws.cap, wb):
@@ -2040,7 +2098,8 @@ class ShardedSolver:
             self._schedule_backward_edges(levels, completed)
         order = sorted(levels, reverse=True)
         for i, k in enumerate(order):
-            b0 = (self.bytes_routed, self.bytes_sorted, self.bytes_gathered)
+            b0 = (self.bytes_routed, self.bytes_sorted, self.bytes_gathered,
+                  self.bytes_routed_padded)
             io0 = self.store.stats()["io_wait_secs"]
             rec = levels[k]
             self.progress = {
@@ -2170,9 +2229,12 @@ class ShardedSolver:
                             # Move (not copy) the resident level to the host
                             # cache: one download, no double memory, and
                             # shallower levels that window on L reuse it.
-                            host_cache[L] = tuple(
-                                _HostSpill.download(a) for a in dev_cache[L]
-                            )
+                            with self._download(L, "window", sum(
+                                    a.nbytes for a in dev_cache[L])):
+                                host_cache[L] = tuple(
+                                    _HostSpill.download(a)
+                                    for a in dev_cache[L]
+                                )
                             del dev_cache[L]
                         windows.append(host_cache[L])
 
@@ -2225,10 +2287,12 @@ class ShardedSolver:
                 # Too wide to keep resident as a window: spill to host (via
                 # addressable shards — multi-host safe), to be streamed back
                 # in blocks by shallower levels' lookups.
-                host_cache[k] = tuple(
-                    _HostSpill.download(a)
-                    for a in (rec.dev, values_dev, rem_dev)
-                )
+                triple = (rec.dev, values_dev, rem_dev)
+                with self._download(k, "window",
+                                    sum(a.nbytes for a in triple)):
+                    host_cache[k] = tuple(
+                        _HostSpill.download(a) for a in triple
+                    )
             rec.dev = None  # the cache owns the device copy now
             rec.eidx = rec.slot = None  # edges can never be read again
             if not self.store_tables:
@@ -2243,6 +2307,7 @@ class ShardedSolver:
                 mode=mode,
                 resumed=from_checkpoint,
                 bytes_routed=self.bytes_routed - b0[0],
+                bytes_routed_padded=self.bytes_routed_padded - b0[3],
                 bytes_sorted=self.bytes_sorted - b0[1],
                 bytes_gathered=self.bytes_gathered - b0[2],
                 bytes_hbm=(self.bytes_sorted - b0[1])
@@ -2394,9 +2459,12 @@ class ShardedSolver:
             return None  # big-run mode: nothing leaves the device
         # Global table for this level (kept sharded on device during the
         # solve; materialized for the result).
-        shards = rec.host_shards()
-        values = _fetch_global(values_dev)
-        remoteness = _fetch_global(rem_dev)
+        size = values_dev.nbytes + rem_dev.nbytes + (
+            rec.dev.nbytes if rec.host is None else 0)
+        with self._download(k, "table", size):
+            shards = rec.host_shards()
+            values = _fetch_global(values_dev)
+            remoteness = _fetch_global(rem_dev)
         gs, gv, gr = [], [], []
         for s in range(self.S):
             n = int(rec.counts[s])
@@ -2472,6 +2540,7 @@ class ShardedSolver:
         if any(e.shape[0] != self.S * ecap for e in es) or any(
                 sl.shape[0] != cap * self.game.max_moves for sl in ss):
             return None
+        rec.sent = int(sum(np.count_nonzero(e >= 0) for e in es))
         return (jax.device_put(np.stack(es), self._sharding),
                 jax.device_put(np.stack(ss), self._sharding), ecap)
 
@@ -2494,7 +2563,12 @@ class ShardedSolver:
         # AOT executables were compiled for (plain np arrays would not).
         repl = NamedSharding(self.mesh, P())
         q, acc = self._eroute_fn(ecap)(eidx)
-        self.bytes_routed += S * S * ecap * 4  # i32 index queries out
+        # i32 index queries out, packed u32 cells back: the buffers whole,
+        # and the level's real edges (every slot of the buffers when the
+        # count is unknown).
+        self.bytes_routed_padded += S * S * ecap * 8
+        self.bytes_routed += (S * S * ecap if rec.sent is None
+                              else rec.sent) * 8
         if wdev is not None:
             _, wv, wr = wdev
             acc = self._egather_fn(ecap, wv.shape[1])(
@@ -2512,7 +2586,6 @@ class ShardedSolver:
                 )
                 self.window_stream_blocks += 1
                 self.bytes_gathered += S * S * ecap * 8
-        self.bytes_routed += S * S * ecap * 4  # packed cells back
         return self._ereply_fn(rec.dev.shape[1], ecap)(rec.dev, acc, slot)
 
     def _shard_ranks(self) -> List[int]:
@@ -3016,6 +3089,7 @@ class ShardedSolver:
             "secs_total": t_total,
             "positions_per_sec": num_positions / max(t_total, 1e-9),
             "bytes_routed": self.bytes_routed,
+            "bytes_routed_padded": self.bytes_routed_padded,
             "bytes_sorted": self.bytes_sorted,
             "bytes_gathered": self.bytes_gathered,
             # ISSUE 14 dispatch economy (see engine stats of the same
@@ -3040,6 +3114,13 @@ class ShardedSolver:
                        else 1),
             ),
             **self.store_stats(),
+            # Reachable positions per level, from the per-shard counts:
+            # the same record field as the dense engine's, whichever
+            # backward each level ran.
+            "reachable_per_level": {
+                int(k): int(rec.counts.sum())
+                for k, rec in sorted(levels.items())
+            },
         }
         self.progress = {"phase": "done", "rank": self.rank}
         if self.logger is not None:
