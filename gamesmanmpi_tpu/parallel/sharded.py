@@ -66,6 +66,7 @@ replacing the reference's `mpirun -np 1` vs `-np N` (SURVEY.md §4.2).
 from __future__ import annotations
 
 import time
+from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Dict, List
 
 import jax
@@ -206,6 +207,123 @@ def _fetch_global(arr) -> np.ndarray:
 
         return np.asarray(multihost_utils.process_allgather(arr))
     return np.asarray(arr)
+
+
+def _host_addressable(*arrays) -> bool:
+    """Whether this process alone can copy every array to the host: each
+    is on this process's devices, so no copy is a gather collective that
+    must keep its place in the collective order on the solve thread."""
+    return all(a.is_fully_addressable for a in arrays
+               if isinstance(a, jax.Array))
+
+
+def _merge_level(shards: List[np.ndarray], values: np.ndarray,
+                 remoteness: np.ndarray, counts: np.ndarray) -> LevelTable:
+    """One level's global table from its per-shard sorted slices and the
+    ``[S, cap]`` value/remoteness rows: the real rows concatenated and put
+    in state order. States are unique within a level, so the stable sort
+    (a merge of the sorted runs) gives the one order there is."""
+    gs, gv, gr = [], [], []
+    for s, n in enumerate(counts):
+        n = int(n)
+        gs.append(shards[s])
+        gv.append(values[s, :n])
+        gr.append(remoteness[s, :n])
+    states = np.concatenate(gs)
+    order = np.argsort(states, kind="stable")
+    return LevelTable(
+        states=states[order],
+        values=np.concatenate(gv)[order],
+        remoteness=np.concatenate(gr)[order],
+    )
+
+
+class _TableAssembler:
+    """The sharded backward's level tables, assembled on one background
+    thread so the solve thread goes straight on to the next level's
+    dispatch instead of idling the mesh through each copy and merge.
+
+    A job holds its own references to the level's arrays (the solve thread
+    drops ``rec.dev``/``rec.host`` right after submitting) and never reads
+    the level record. Jobs run in submission order; a job's error is
+    re-raised on the solve thread by ``poll``/``join``; ``close`` cancels
+    what has not started and waits out the one running, on every path.
+    """
+
+    def __init__(self, logger=None):
+        self.logger = logger
+        self._pool = None
+        self._pending: Dict[int, Future] = {}
+        self._tables: Dict[int, LevelTable] = {}
+        self._ahead = None  # the last submitted level's values on device
+        #: levels handed to the worker
+        self.levels = 0
+        #: seconds the solve thread spent blocked in join
+        self.wait_s = 0.0
+
+    def submit(self, k: int, counts: np.ndarray, host, dev, values_dev,
+               rem_dev, nbytes: int) -> None:
+        """Queue level k: states from ``host`` (per-shard slices) or else
+        from ``dev`` (``[S, cap]``); the device-to-host copies start now.
+        Returns once the previously submitted level's step has run."""
+        for a in (dev, values_dev, rem_dev):
+            if a is not None:
+                a.copy_to_host_async()
+        # The synchronous copy this replaces also held the solve thread
+        # within a level of the device. The edge-cached steps have no other
+        # sync, so without this wait the loop would queue every remaining
+        # level, each holding its buffers and re-uploaded edges on the
+        # device at once. Level k is queued behind the one awaited here,
+        # so the mesh stays busy while the solve thread waits.
+        if self._ahead is not None:
+            jax.block_until_ready(self._ahead)
+        self._ahead = values_dev
+        if self._pool is None:
+            self._pool = ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix="gamesman-assemble"
+            )
+        self._pending[k] = self._pool.submit(
+            self._assemble, k, counts, host, dev, values_dev, rem_dev,
+            nbytes,
+        )
+        self.levels += 1
+
+    def _assemble(self, k, counts, host, dev, values_dev, rem_dev, nbytes):
+        with trace_span("assemble", logger=self.logger, level=int(k),
+                        kind="table", bytes=int(nbytes),
+                        n=int(counts.sum())):
+            if host is None:
+                stacked = np.asarray(dev)
+                host = [stacked[s, :int(n)] for s, n in enumerate(counts)]
+            return _merge_level(host, np.asarray(values_dev),
+                                np.asarray(rem_dev), counts)
+
+    def poll(self) -> None:
+        """Collect the finished jobs; a failed one raises here."""
+        for k, fut in list(self._pending.items()):
+            if fut.done():
+                del self._pending[k]
+                self._tables[k] = fut.result()
+
+    def join(self, on_wait=None) -> Dict[int, LevelTable]:
+        """Every submitted level's table, waiting for the worker;
+        ``on_wait(k)`` runs before each wait (watchdog progress)."""
+        if not self._pending:
+            return self._tables
+        t0 = time.perf_counter()
+        try:
+            for k in list(self._pending):
+                if on_wait is not None:
+                    on_wait(k)
+                self._tables[k] = self._pending.pop(k).result()
+        finally:
+            self.wait_s += time.perf_counter() - t0
+        return self._tables
+
+    def close(self) -> None:
+        if self._pool is not None:
+            self._pool.shutdown(wait=True, cancel_futures=True)
+            self._pool = None
 
 
 def _all_to_all(x):
@@ -762,6 +880,10 @@ class ShardedSolver:
         #: even in big-run mode (the boundary join reads it); plain solves
         #: leave it False and take the device-replicated root answer only.
         self.materialize_root_table = False
+        #: backward level tables assembled off the solve thread, and the
+        #: seconds the solve thread waited for them (done record fields).
+        self.tables_async_levels = 0
+        self.table_wait_s = 0.0
         # Analytic traffic counters (SURVEY.md §5.5): payload bytes of the
         # all_to_all collectives and operand bytes of the sort/gather
         # kernels — the denominators that make positions/sec readable
@@ -2075,7 +2197,33 @@ class ShardedSolver:
         on host (plus whatever the checkpointer persists) — the big-run mode
         where accumulating every level's table in host RAM is the remaining
         O(total-positions) cost (docs/ARCHITECTURE.md capacity plan).
+
+        Level tables are assembled on a background worker where this
+        process can copy the level by itself (_materialize_level); every
+        one is joined before the tables are returned.
         """
+        assembler = _TableAssembler(self.logger)
+        try:
+            resolved = self._backward_levels(levels, root_level, init,
+                                             assembler)
+
+            def _waiting(k):
+                self.progress = {"phase": "assemble", "level": k,
+                                 "rank": self.rank}
+
+            resolved.update(assembler.join(on_wait=_waiting))
+        finally:
+            assembler.close()
+            self.tables_async_levels = assembler.levels
+            self.table_wait_s = assembler.wait_s
+        return resolved
+
+    def _backward_levels(self, levels: Dict[int, _SLevel], root_level: int,
+                         init, assembler: _TableAssembler
+                         ) -> Dict[int, LevelTable]:
+        """The deepest-first resolve loop of _backward: the tables of the
+        levels assembled on the solve thread; ``assembler`` holds the
+        others."""
         g = self.game
         S = self.S
         # Forward's seals (edges + frontier levels) must all be visible
@@ -2107,6 +2255,7 @@ class ShardedSolver:
                 "n": int(rec.counts.sum()),
             }
             self._check_preempt("backward", k)
+            assembler.poll()  # a failed table assembly fails the solve now
             # Batched readahead from the level schedule: while THIS
             # level resolves, the store's pool decodes the NEXT level's
             # sealed checkpoint/edge shards — the solve thread's loads
@@ -2191,7 +2340,7 @@ class ShardedSolver:
                         "non-primitive positions)"
                     )
                 table = self._materialize_level(
-                    k, rec, values_dev, rem_dev, root_level
+                    k, rec, values_dev, rem_dev, root_level, assembler
                 )
             else:
                 window_levels = [
@@ -2254,7 +2403,7 @@ class ShardedSolver:
                         "lookups or zero-move non-primitive positions)"
                     )
                 table = self._materialize_level(
-                    k, rec, values_dev, rem_dev, root_level
+                    k, rec, values_dev, rem_dev, root_level, assembler
                 )
             if table is not None and (self.store_tables or k == root_level):
                 resolved[k] = table
@@ -2444,8 +2593,9 @@ class ShardedSolver:
         return pv, pr, table
 
     def _materialize_level(self, k: int, rec, values_dev, rem_dev,
-                           root_level: int):
-        """Global LevelTable of one resolved level, or None in big-run mode.
+                           root_level: int, assembler: _TableAssembler):
+        """Global LevelTable of one resolved level, or None in big-run mode
+        or where ``assembler`` builds it.
 
         Checkpointing no longer forces a global table: levels are
         checkpointed per shard (VERDICT r2 item 4), so big-run + checkpoint
@@ -2453,31 +2603,29 @@ class ShardedSolver:
         needs ITS root level (= the cutover boundary) as a table even in
         big-run mode — in plain solves the root answer instead leaves the
         device via _root_fn and no table materializes.
+
+        Where this process can copy the level by itself the copy and merge
+        go to the assembler's worker, off the solve thread. Under
+        multi-process execution each copy is a gather collective, so it
+        stays here, in its place in the collective order.
         """
         if not (self.store_tables or (
                 k == root_level and self.materialize_root_table)):
             return None  # big-run mode: nothing leaves the device
         # Global table for this level (kept sharded on device during the
         # solve; materialized for the result).
+        dev = rec.dev if rec.host is None else None
         size = values_dev.nbytes + rem_dev.nbytes + (
-            rec.dev.nbytes if rec.host is None else 0)
+            dev.nbytes if dev is not None else 0)
+        if _host_addressable(values_dev, rem_dev, dev):
+            assembler.submit(k, rec.counts.copy(), rec.host, dev,
+                             values_dev, rem_dev, size)
+            return None
         with self._download(k, "table", size):
             shards = rec.host_shards()
             values = _fetch_global(values_dev)
             remoteness = _fetch_global(rem_dev)
-        gs, gv, gr = [], [], []
-        for s in range(self.S):
-            n = int(rec.counts[s])
-            gs.append(shards[s])
-            gv.append(values[s, :n])
-            gr.append(remoteness[s, :n])
-        states = np.concatenate(gs)
-        order = np.argsort(states)
-        return LevelTable(
-            states=states[order],
-            values=np.concatenate(gv)[order],
-            remoteness=np.concatenate(gr)[order],
-        )
+        return _merge_level(shards, values, remoteness, rec.counts)
 
     def _edges_available(self, k: int, rec) -> bool:
         """Cheap pre-Span predicate: will _load_edges plausibly succeed?
@@ -3084,6 +3232,8 @@ class ShardedSolver:
             "edges_bytes_disk": self.edges_bytes_disk,
             "ckpt_bytes_raw": self.ckpt_bytes_raw,
             "ckpt_bytes_stored": self.ckpt_bytes_stored,
+            "tables_async_levels": self.tables_async_levels,
+            "table_wait_s": self.table_wait_s,
             "secs_forward": t_forward,
             "secs_backward": t_total - t_forward,
             "secs_total": t_total,

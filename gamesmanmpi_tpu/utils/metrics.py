@@ -12,6 +12,7 @@ from __future__ import annotations
 import json
 import os
 import sys
+import threading
 import time
 
 
@@ -30,16 +31,21 @@ class _ClosingLogger:
 
 
 class JsonlLogger(_ClosingLogger):
-    """Appends one JSON object per record to a file."""
+    """Appends one JSON object per record to a file. Records may come from
+    several threads (the sharded backward's table worker logs its spans);
+    a text file object is not thread-safe, so writes take a lock."""
 
     def __init__(self, path: str):
         self.path = path
         self._fh = open(path, "a", buffering=1)
         self._t0 = time.time()
+        self._lock = threading.Lock()
 
     def log(self, record: dict) -> None:
         rec = {"t": round(time.time() - self._t0, 6), **record}
-        self._fh.write(json.dumps(rec, default=str) + "\n")
+        line = json.dumps(rec, default=str) + "\n"
+        with self._lock:
+            self._fh.write(line)
 
     def close(self) -> None:
         """Flush-and-fsync, tolerating double-close: an aborted solve's

@@ -84,11 +84,16 @@ def test_sharded_solve_matches_the_reference(devices, truth, tmp_path):
     assert seen == memo
 
     # The mesh's spans reach the JSONL: a forward record per discovered
-    # level, a download per table copied to the host.
+    # level, an assemble per table copied to the host off the solve
+    # thread (and so no table download, which would block it).
     forward = [r for r in records if r["phase"] == "forward"]
     assert sorted(r["level"] for r in forward) == list(range(16))
     assert all(r["n"] == got[r["level"]] and r["children"] > 0
                and r["bytes_routed"] > 0 for r in forward)
-    downloads = [r for r in records if r["phase"] == "download"]
-    assert {r["level"] for r in downloads if r["kind"] == "table"} \
-        == set(range(17))
+    assembled = [r for r in records if r["phase"] == "assemble"]
+    assert sorted(r["level"] for r in assembled) == list(range(17))
+    assert all(r["kind"] == "table" and r["n"] == got[r["level"]]
+               for r in assembled)
+    assert not [r for r in records
+                if r["phase"] == "download" and r["kind"] == "table"]
+    assert done["tables_async_levels"] == 17
