@@ -2181,17 +2181,12 @@ def inner() -> int:
             # 0.0 for in-memory solves; future BENCH_*.json track I/O
             # overlap alongside throughput.
             "io_wait_secs": round(stats.get("io_wait_secs", 0.0), 3),
-            # ISSUE 14 dispatch economy: total/per-level device dispatches
-            # the engine issued, the fused/pipeline gates that ran, and
-            # the host seconds the pingpong pipeline overlapped with
-            # device execution — the record proves dispatch count went
-            # down, not just wall clock.
+            # Dispatch economy: total/per-level device dispatches the
+            # engine issued.
             "dispatches": {
                 "total": stats.get("dispatches_total", 0),
                 "per_level": stats.get("dispatches_per_level", 0.0),
             },
-            "overlap_secs": round(stats.get("overlap_secs", 0.0), 3),
-            "fused": bool(stats.get("fused", False)),
             # Roofline fields: analytic HBM operand throughput
             # and the headline per-chip rate — what bench_compare diffs
             # across the committed BENCH_* trajectory.
@@ -2217,73 +2212,6 @@ def inner() -> int:
         if "backward" in stats:
             rec["backward"] = stats["backward"]
         return rec
-
-    def _fused_ab_run(game_spec: str) -> dict:
-        """Fused-vs-unfused A/B (ISSUE 14): same board, same host, same
-        classic engine; one warmup + one timed solve per arm. Parity is
-        byte-level: sha256 over every level's (states, values, remoteness)
-        arrays — the exact arrays --table-out serializes — must match
-        between arms. The per-arm dispatches_per_level pair is the record's
-        proof that the fused path dispatches less, not just runs faster."""
-        import hashlib
-
-        import numpy as np
-
-        arms = (
-            ("unfused", {"GAMESMAN_FUSED": "0",
-                         "GAMESMAN_PIPELINE": "level"}),
-            ("fused", {"GAMESMAN_FUSED": "1",
-                       "GAMESMAN_PIPELINE": "pingpong"}),
-        )
-        out: dict = {"spec": game_spec}
-        digests = {}
-        for arm, env in arms:
-            saved = {k: os.environ.get(k) for k in env}
-            os.environ.update(env)
-            try:
-                game = get_game(game_spec)
-                Solver(game, store_tables=True).solve()  # warm: compiles
-                solver = Solver(game, store_tables=True)
-                t0 = time.perf_counter()
-                result = solver.solve()
-                dt = time.perf_counter() - t0
-            finally:
-                for k, v in saved.items():
-                    if v is None:
-                        os.environ.pop(k, None)
-                    else:
-                        os.environ[k] = v
-            h = hashlib.sha256()
-            for lvl in sorted(result.levels):
-                t = result.levels[lvl]
-                h.update(np.asarray(t.states).tobytes())
-                h.update(np.asarray(t.values).tobytes())
-                h.update(np.asarray(t.remoteness).tobytes())
-            digests[arm] = h.hexdigest()
-            out[arm] = {
-                "pps": round(result.num_positions / dt, 1),
-                "secs_forward": round(result.stats["secs_forward"], 3),
-                "secs_backward": round(result.stats["secs_backward"], 3),
-                "dispatches_per_level":
-                    result.stats.get("dispatches_per_level", 0.0),
-                "dispatches_total":
-                    result.stats.get("dispatches_total", 0),
-                "overlap_secs": round(
-                    result.stats.get("overlap_secs", 0.0), 3),
-                "table_sha256": digests[arm],
-            }
-            print(
-                f"fused A/B [{arm}]: {out[arm]['pps']:,.0f} pos/s, "
-                f"{out[arm]['dispatches_per_level']} dispatches/level",
-                file=sys.stderr,
-            )
-        out["parity_ok"] = digests["fused"] == digests["unfused"]
-        out["speedup"] = round(
-            out["fused"]["pps"] / max(out["unfused"]["pps"], 1e-9), 3)
-        out["dispatch_reduction"] = round(
-            out["unfused"]["dispatches_per_level"]
-            / max(out["fused"]["dispatches_per_level"], 1e-9), 2)
-        return out
 
     def run_solves(game_spec: str, nruns: int, provisional: bool = False,
                    nwarmup: int = 0):
@@ -2382,20 +2310,6 @@ def inner() -> int:
     # line instead of discarding a completed accelerator run (the enriched
     # record printed at the end wins when everything succeeds).
     print(json.dumps(record), flush=True)
-
-    # ISSUE 14: fused/unfused A/B on the primary board — the standard
-    # record carries the delta (speedup, per-level dispatch reduction,
-    # table byte-parity) so every future bench round re-proves the fused
-    # path instead of trusting an old one. BENCH_FUSED_AB=0 disables.
-    fused_ab = None
-    if os.environ.get("BENCH_FUSED_AB", "1") not in ("0", "off"):
-        try:
-            fused_ab = _fused_ab_run(spec)
-        except Exception as e:  # pragma: no cover - diagnostic only
-            print(f"fused A/B failed: {e!r}", file=sys.stderr)
-            fused_ab = {"error": f"{type(e).__name__}: {e}"}
-        record["fused_ab"] = fused_ab
-        print(json.dumps(record), flush=True)
 
     # Secondary: the mirror-symmetry variant (halves the 6x6+ table; the
     # capacity plan depends on its throughput cost — VERDICT.md r2 item 7).

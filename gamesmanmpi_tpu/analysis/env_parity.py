@@ -150,8 +150,9 @@ def check(project: Project) -> List[Diagnostic]:
                 note(var, src.rel, i)
 
     doc_text = project.config_md
-    # Exact-token matching, never substring: GAMESMAN_SORT must not count
-    # as documented just because GAMESMAN_SORT_ROW's row contains it.
+    # Exact-token matching, never substring: GAMESMAN_DENSE_GATHER must
+    # not count as documented just because the row of
+    # GAMESMAN_DENSE_GATHER_PALLAS_MESH contains it.
     # "Documented" = a table row (first cell) or any backticked mention.
     doc_rows: Set[str] = set()
     for line in doc_text.splitlines():

@@ -8,7 +8,7 @@ checker reports. The checker runs gamedsl's static validator
 (gamesmanmpi_tpu.gamedsl.spec — stdlib-only, no kernel tracing, in
 keeping with the runner's never-import-the-code rule for accelerator
 safety) over every committed spec and reports error-severity findings;
-warnings (e.g. GS102's fused-table-gate note) are advisory and stay out
+warnings (e.g. GS104's dead-direction note) are advisory and stay out
 of CI.
 
 | id | finding |

@@ -66,11 +66,10 @@ _BUILDER_FUNNELS = {"get_kernel", "schedule_kernel"}
 
 #: Host-callback funnels: a function passed into these from traced code
 #: runs on the HOST with concrete numpy arrays, not tracers — its numpy
-#: calls, branches and host syncs are the whole point (the fused dedup's
-#: np.unique callback, compat/shim's scalar-game lifts). Without this
-#: exemption the callback rule below would re-enqueue those bodies as
-#: traced and flag every np.* call in them (GM105 false positives on the
-#: ISSUE 14 fused kernels).
+#: calls, branches and host syncs are the whole point (compat/shim's
+#: scalar-game lifts). Without this exemption the callback rule below
+#: would re-enqueue those bodies as traced and flag every np.* call in
+#: them (GM105 false positives).
 _HOST_CALLBACK_FUNNELS = {"pure_callback", "io_callback", "debug_callback"}
 
 #: Per-module cap on (function, taint-set) walks — a loop breaker, set

@@ -73,11 +73,6 @@ SYMMETRY_GENERATORS = {
 #: the only generator compatible with gravity (drop games): column mirror
 DROP_SYMMETRY_GENERATORS = ("mirror_h",)
 
-#: fused value-table backward gate (ops/fused.py `_bwdt`, default
-#: GAMESMAN_FUSED_TABLE_BITS): wider states still solve, but lose that path
-FUSED_TABLE_BITS = 26
-
-
 class SpecError(ValueError):
     """A GameSpec document is structurally or semantically invalid."""
 
@@ -363,14 +358,6 @@ def spec_problems(spec: GameSpec) -> list:
             "error", "GS101",
             f"packed state needs {bits} bits (> 63): the board does not "
             f"fit the engine's uint64 encoding — shrink the board",
-        ))
-    elif bits > FUSED_TABLE_BITS:
-        problems.append(_problem(
-            "warning", "GS102",
-            f"packed state needs {bits} bits (> {FUSED_TABLE_BITS}): "
-            f"outside the fused value-table backward's default "
-            f"GAMESMAN_FUSED_TABLE_BITS gate — fused solves will take "
-            f"the provenance backward instead",
         ))
     if spec.exact and spec.family == "drop":
         problems.append(_problem(

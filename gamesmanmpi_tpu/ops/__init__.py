@@ -9,10 +9,6 @@ searchsorted in ops.lookup; frontier dedup is ops.dedup.sort_unique.
 
 from gamesmanmpi_tpu.ops.padding import bucket_size, pad_to_bucket
 from gamesmanmpi_tpu.ops.dedup import sort_unique
-from gamesmanmpi_tpu.ops.fused import (
-    fused_dedup_provenance,
-    fused_sort_unique,
-)
 from gamesmanmpi_tpu.ops.lookup import lookup_sorted, lookup_window
 from gamesmanmpi_tpu.ops.combine import combine_children
 from gamesmanmpi_tpu.ops.provenance import dedup_provenance, gather_cells
@@ -21,8 +17,6 @@ __all__ = [
     "bucket_size",
     "pad_to_bucket",
     "sort_unique",
-    "fused_sort_unique",
-    "fused_dedup_provenance",
     "lookup_sorted",
     "lookup_window",
     "combine_children",

@@ -12,10 +12,6 @@ import jax.numpy as jnp
 
 from gamesmanmpi_tpu.core.bitops import sentinel_for
 from gamesmanmpi_tpu.utils.platform import platform_auto_flag
-# sort1 dispatches to XLA's sort network, or to the merge ladder under
-# GAMESMAN_SORT=merge (resolved at build time by kernel builders — see
-# sort1's docstring; engine.get_kernel keys its cache on the flag).
-from gamesmanmpi_tpu.ops.mergesort import sort1 as _sort
 
 
 def compact_method() -> str:
@@ -41,8 +37,7 @@ def compaction_sort_bytes(itemsize: int) -> int:
 
 
 @jax.named_scope("dedup")
-def compact_sorted(s, keep, merge: bool | None = None,
-                   compact: str | None = None):
+def compact_sorted(s, keep, compact: str | None = None):
     """Compact kept entries of a SORTED array to the front (sorted order
     preserved), sentinel tail. keep must be False on sentinel entries.
     compact: lowering; kernel builders resolve via compact_method() at
@@ -59,12 +54,11 @@ def compact_sorted(s, keep, merge: bool | None = None,
         return jnp.full_like(s, sentinel).at[
             jnp.where(keep, idx, n)
         ].set(s, mode="drop")
-    return _sort(jnp.where(keep, s, sentinel), merge)
+    return jnp.sort(jnp.where(keep, s, sentinel))
 
 
 @jax.named_scope("dedup")
-def sort_unique(states, merge: bool | None = None,
-                compact: str | None = None):
+def sort_unique(states, compact: str | None = None):
     """Sort states, drop duplicates/sentinels, compact to the front.
 
     Input: [N] uint32/uint64 (may contain SENTINEL padding of the same dtype).
@@ -75,9 +69,9 @@ def sort_unique(states, merge: bool | None = None,
     on accelerators, cumsum+scatter on CPU — see compact_method).
     """
     sentinel = sentinel_for(states.dtype)
-    s = _sort(states, merge)
+    s = jnp.sort(states)
     first = jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]])
     keep = first & (s != sentinel)
-    out = compact_sorted(s, keep, merge, compact)
+    out = compact_sorted(s, keep, compact)
     count = jnp.sum(keep).astype(jnp.int32)
     return out, count

@@ -91,32 +91,6 @@ def _dyn_gather(x, idx, axis: int):
         mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
 
 
-@jax.named_scope("gather")
-def cells_table_gather(cells, idx, valid):
-    """Direct-address packed-cell gather: cells[idx] where valid, else 0.
-
-    cells: [T] uint32 packed (value, remoteness) cells indexed by packed
-    STATE (the fused backward's persistent value table — T = 2^state_bits,
-    gated by ops.fused.use_value_table). idx: [...] unsigned states (may
-    hold sentinel / garbage on invalid lanes). valid: [...] bool.
-
-    The gather indices are states in frontier order — NOT monotone — so
-    the monotone-window kernel below does not apply; XLA's plain gather is
-    the right lowering on both backends (measured 0.015 s for 4M lanes
-    from a 128 MB table on this host's CPU, vs 0.148 s for the binary
-    search it replaces). Cell 0 is UNDECIDED, so the same zero doubles as
-    the miss flag downstream (ops.provenance.combine_edge_cells contract).
-    Kept beside the pallas kernel because it shares its one constraint:
-    indices enter the gather clamped in-bounds, with validity handled by
-    select — PROMISE_IN_BOUNDS-style lowering with no branch.
-    """
-    t = cells.shape[0]
-    safe = jnp.clip(idx, 0, t - 1).astype(
-        jnp.uint32 if t <= (1 << 32) else jnp.uint64
-    )
-    return jnp.where(valid, cells[safe], jnp.uint32(0))
-
-
 def _pack_words(table, window: int):
     """A 1-D table of 1-, 2- or 4-byte elements as [R, 128] int32 words
     (module docstring), padded to whole tiles and to at least one view."""

@@ -32,12 +32,10 @@ from gamesmanmpi_tpu.core.bitops import sentinel_for
 from gamesmanmpi_tpu.core.codec import pack_cells, unpack_cells
 from gamesmanmpi_tpu.core.values import UNDECIDED
 from gamesmanmpi_tpu.ops.dedup import compact_sorted
-from gamesmanmpi_tpu.ops.mergesort import sort_with_payload
 
 
 @jax.named_scope("dedup")
-def dedup_provenance(flat, merge: bool | None = None,
-                     compact: str | None = None):
+def dedup_provenance(flat, compact: str | None = None):
     """Sort-unique [N] states AND report where each input slot landed.
 
     Returns (uniq [N] sorted uniques first + sentinel tail, count int32,
@@ -46,21 +44,19 @@ def dedup_provenance(flat, merge: bool | None = None,
     the survivor's unique-index (cumsum over run-first markers is
     constant within the run).
 
-    merge/compact: sort-backend and compaction lowerings, resolved at
-    BUILD time by kernel builders (None = read env/platform at trace
-    time; see ops.mergesort.sort1, ops.dedup.compact_method).
+    compact: compaction lowering, resolved at BUILD time by kernel
+    builders (None = read the platform at trace time; see
+    ops.dedup.compact_method).
     """
     sentinel = sentinel_for(flat.dtype)
     origin = jax.lax.iota(jnp.int32, flat.shape[0])
-    # Sorts dispatch through ops.mergesort: XLA's network by default, the
-    # elementwise merge ladder under GAMESMAN_SORT=merge.
-    s, o = sort_with_payload(flat, origin, merge)
+    s, o = jax.lax.sort((flat, origin), num_keys=1, is_stable=False)
     first = jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]])
     keep = first & (s != sentinel)
     uid = jnp.cumsum(keep.astype(jnp.int32)) - 1
     uid = jnp.where(s != sentinel, uid, -1)
-    _, uidx = sort_with_payload(o, uid, merge)
-    uniq = compact_sorted(s, keep, merge, compact)
+    _, uidx = jax.lax.sort((o, uid), num_keys=1, is_stable=False)
+    uniq = compact_sorted(s, keep, compact)
     count = jnp.sum(keep).astype(jnp.int32)
     return uniq, count, uidx
 

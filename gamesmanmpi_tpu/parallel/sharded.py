@@ -90,7 +90,6 @@ from gamesmanmpi_tpu.ops.dedup import (
     compaction_sort_bytes,
     sort_unique,
 )
-from gamesmanmpi_tpu.ops.mergesort import backend_key, use_merge_sort
 from gamesmanmpi_tpu.ops.lookup import (
     lookup_sorted,
     lookup_window,
@@ -136,12 +135,6 @@ from gamesmanmpi_tpu.utils.env import (
     env_float as _env_float,
     env_opt,
     env_str,
-)
-from gamesmanmpi_tpu.ops.fused import (
-    fused_dedup_method,
-    fused_dedup_provenance,
-    fused_enabled,
-    fused_sort_unique,
 )
 from gamesmanmpi_tpu.solve.engine import (
     LevelTable,
@@ -369,10 +362,8 @@ def _route_by_owner(flat, S: int, cap_out: int, sentinel):
 
 
 def _sharded_forward_step(game: TensorGame, S: int, route_cap: int, local,
-                          merge: bool | None = None,
                           compact: str | None = None,
-                          provenance: bool = False,
-                          fused: str | None = None):
+                          provenance: bool = False):
     """Per-shard forward body: expand -> owner-bucket -> all_to_all -> dedup.
 
     local: [1, cap] this shard's frontier slice (shard_map gives the leading
@@ -404,28 +395,11 @@ def _sharded_forward_step(game: TensorGame, S: int, route_cap: int, local,
         flat, S, route_cap, sentinel
     )
     routed = _all_to_all(send)
-    # fused (ISSUE 14): the dedup after the route runs through the fused
-    # rank/sort+dedup stage (ops/fused) — per-shard callback on CPU,
-    # single-pair-sort scatterinv on accelerators. The routed buffer has
-    # no dense real prefix (each source row is sentinel-padded), so no
-    # count limit applies; the collectives around the dedup are untouched,
-    # which is what keeps these dispatch sites inside _retry_collective
-    # (GM603) exactly as before.
     if not provenance:
-        if fused:
-            uniq, count = fused_sort_unique(routed.reshape(-1), None,
-                                            fused, merge, compact)
-        else:
-            uniq, count = sort_unique(routed.reshape(-1), merge, compact)
+        uniq, count = sort_unique(routed.reshape(-1), compact)
         all_counts, all_sends = _gather_counts(count, counts)
         return uniq[None], all_counts, all_sends
-    if fused:
-        uniq, count, uidx = fused_dedup_provenance(
-            routed.reshape(-1), None, fused, merge, compact
-        )
-    else:
-        uniq, count, uidx = dedup_provenance(routed.reshape(-1), merge,
-                                             compact)
+    uniq, count, uidx = dedup_provenance(routed.reshape(-1), compact)
     # Route each child's unique-index-within-owner back to its parent:
     # uidx is in routed layout (row i = slots received from source i), so
     # the return all_to_all lands row o of the parent's eidx with the uids
@@ -898,9 +872,9 @@ class ShardedSolver:
         self.bytes_gathered = 0
         #: transient level-step failures absorbed by retry (stats field).
         self.retries = 0
-        #: ISSUE 14 dispatch accounting (see engine.note_dispatch): device
+        #: dispatch accounting (see engine.note_dispatch): device
         #: computations/transfers this solve issued, with the per-(phase,
-        #: level) breakdown the fused A/B asserts on.
+        #: level) breakdown.
         self.dispatch_total = 0
         self.level_dispatches: Dict[tuple, int] = {}
         self.dispatch_by_kind: Dict[str, int] = {}
@@ -1229,19 +1203,12 @@ class ShardedSolver:
         """
         mesh, S = self.mesh, self.S
 
-        # Fused-dedup lowering, resolved at cache-key time (ISSUE 14): the
-        # flag changes the traced program, so it rides the lowering tuple —
-        # a mid-process GAMESMAN_FUSED flip can neither reuse a kernel
-        # traced the other way nor disagree with its key.
-        fz = fused_dedup_method() if fused_enabled() else None
-
         def build(game):
-            # resolved at cache-key time
-            mb, cm = use_merge_sort(), compact_method()
+            cm = compact_method()  # resolved at cache-key time
 
             def per_shard(local):
-                return _sharded_forward_step(game, S, route_cap, local, mb,
-                                             cm, provenance, fz)
+                return _sharded_forward_step(game, S, route_cap, local, cm,
+                                             provenance)
 
             data_specs = (P(AXIS), P(AXIS), P(AXIS)) if provenance \
                 else (P(AXIS),)
@@ -1256,7 +1223,7 @@ class ShardedSolver:
         return get_kernel(
             self.game, "sfwdp" if provenance else "sfwd",
             (self._mesh_key, cap, route_cap), build,
-            lowering=(backend_key(), compact_method(), fz or "off"),
+            lowering=(compact_method(),),
         )
 
     # Edge-backward kernel builders are factored out of their get_kernel
@@ -1583,8 +1550,7 @@ class ShardedSolver:
         mesh = self.mesh
 
         def build(game):
-            # resolved at cache-key time
-            mb, cm = use_merge_sort(), compact_method()
+            cm = compact_method()  # resolved at cache-key time
 
             @jax.named_scope("dedup")
             def per_shard(pool, kids, target):
@@ -1594,7 +1560,7 @@ class ShardedSolver:
                 )
                 sel = jnp.where(lv == target[0], c, game.sentinel)
                 uniq, count = sort_unique(
-                    jnp.concatenate([p, sel]), mb, cm
+                    jnp.concatenate([p, sel]), cm
                 )
                 with jax.named_scope("route"):
                     return uniq[None], jax.lax.all_gather(count, AXIS)
@@ -1609,7 +1575,7 @@ class ShardedSolver:
 
         return get_kernel(
             self.game, "smrg", (self._mesh_key, pool_cap, child_cap), build,
-            lowering=(backend_key(), compact_method()),
+            lowering=(compact_method(),),
         )
 
     def _level_check_fn(self, cap: int):
@@ -1789,22 +1755,11 @@ class ShardedSolver:
             levels[k].sent = sent
             self.bytes_routed += sent * per_child
             compaction = compaction_sort_bytes(item)
-            # Fused dedup changes the sort-operand denominator (ISSUE 14):
-            # callback = one numpy radix pass over the routed block;
-            # scatterinv = ONE pair sort + compaction instead of two.
-            fz = fused_dedup_method() if fused_enabled() else None
             if self.use_edges:
-                if fz == "callback":
-                    prov_bytes = item
-                elif fz == "scatterinv":
-                    prov_bytes = item + 4 + compaction
-                else:
-                    prov_bytes = provenance_sort_bytes(item, compaction)
+                prov_bytes = provenance_sort_bytes(item, compaction)
                 self.bytes_sorted += S * S * route_cap * prov_bytes
             else:
-                self.bytes_sorted += S * S * route_cap * (
-                    item if fz == "callback" else item + compaction
-                )
+                self.bytes_sorted += S * S * route_cap * (item + compaction)
             counts = np.asarray(count).reshape(-1).astype(np.int64)
             total = int(counts.sum())
             if total == 0:
@@ -1955,10 +1910,7 @@ class ShardedSolver:
                 route_cap = bucket_size(max_sent)
             self.bytes_routed += int(sends.sum()) * item
             compaction = compaction_sort_bytes(item)
-            fz = fused_dedup_method() if fused_enabled() else None
-            self.bytes_sorted += S * S * route_cap * (
-                item if fz == "callback" else item + compaction
-            )
+            self.bytes_sorted += S * S * route_cap * (item + compaction)
             ccounts = np.asarray(count).reshape(-1)
             total = int(ccounts.sum())
             if total > 0:
@@ -3242,12 +3194,10 @@ class ShardedSolver:
             "bytes_routed_padded": self.bytes_routed_padded,
             "bytes_sorted": self.bytes_sorted,
             "bytes_gathered": self.bytes_gathered,
-            # ISSUE 14 dispatch economy (see engine stats of the same
-            # names): proves the fused kernels dispatch less per level.
+            # Dispatch economy (see engine stats of the same names).
             "dispatches_total": self.dispatch_total,
             "dispatches_per_level": round(
                 self.dispatch_total / max(len(levels), 1), 2),
-            "fused": fused_enabled(),
             # ISSUE 15 roofline rollup (engine.roofline_stats): HBM
             # operand bytes are the sort+gather sides (routed bytes are
             # ICI traffic, accounted separately); bytes_host approximates

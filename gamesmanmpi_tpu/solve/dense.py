@@ -186,17 +186,6 @@ class DenseTables:
 
     # -- per-level constants ------------------------------------------------
 
-    def col_base(self, level: int) -> np.ndarray:
-        """[P, w] int32: within-class index of each column's FIRST cell
-        (= cells in lower-numbered columns). The one definition of the
-        class cell ordering — cellidx_rows and snapk both derive from it.
-        """
-        prof = self.profiles[level].astype(np.int32)
-        return np.concatenate(
-            [np.zeros((prof.shape[0], 1), np.int32),
-             np.cumsum(prof, axis=1)[:, :-1]], axis=1
-        )
-
     def cellidx_rows(self, level: int) -> np.ndarray:
         """[P, ncells] int16: within-class index of global slot j, -1 if the
         cell is above the column height (absent)."""
@@ -204,7 +193,11 @@ class DenseTables:
             return self._cellidx[level]
         prof = self.profiles[level].astype(np.int32)  # [P, w]
         w, h = self.width, self.height
-        base = self.col_base(level)  # [P, w] cells before column c
+        # [P, w] cells before column c: the class cell ordering
+        base = np.concatenate(
+            [np.zeros((prof.shape[0], 1), np.int32),
+             np.cumsum(prof, axis=1)[:, :-1]], axis=1
+        )
         r = np.tile(np.arange(h, dtype=np.int32), w)  # [ncells]
         c = np.repeat(np.arange(w, dtype=np.int32), h)
         idx = base[:, c] + r[None, :]  # [P, ncells]
@@ -278,20 +271,6 @@ class DenseTables:
                         key[c] -= 1
                         parent_row[p, c] = prv[tuple(int(v) for v in key)]
 
-        # Fused-rank snapshot slots: snapk[p, j] = the within-CHILD-class
-        # index the new cell of column j//h would get (= parent cells
-        # before that slot), at the one slot per column where r == h_c;
-        # -1 elsewhere. See _rank_all_moves_fused.
-        base = self.col_base(level).astype(np.int64)
-        snapk = np.full((P, self.ncells), -1, np.int32)
-        for c in range(w):
-            hc = prof[:, c]
-            rows = np.arange(P)
-            ok = hc < h
-            snapk[rows[ok], (c * h + hc[ok]).astype(np.int64)] = (
-                base[ok, c] + hc[ok]
-            )
-
         cellidx = self.cellidx_rows(level)
         child_cellidx = np.full((P, w, self.ncells), -1, np.int16)
         if level < self.ncells:
@@ -318,7 +297,6 @@ class DenseTables:
             "cellidx": cellidx,
             "child_cellidx": child_cellidx,
             "parent_cellidx": parent_cellidx,
-            "snapk": snapk,
         }
         self._level_consts[level] = consts
         return consts
@@ -418,20 +396,18 @@ def _connected_fold(stones, h: int, connect: int, dt):
     return won
 
 
-def _binom_lookup(brow, i, use_onehot: bool):
+def _binom_lookup(brow, i):
     """C(k, i) where brow[...] = binom[k] ([..., K] rank-dtype) and i is a
-    per-element ordinal in [0, K). Two lowerings: take_along_axis (a small
-    batched gather) or a one-hot select tree (pure VPU, K-1 selects)."""
-    if not use_onehot:
-        return jnp.take_along_axis(brow, i, axis=-1)
+    per-element ordinal in [0, K): a one-hot select tree (pure VPU, K-1
+    selects). XLA's TPU gather of the same lookup ran the 5x5 solve 43x
+    slower on the v5e (212k against 9.04M positions/s)."""
     out = jnp.zeros(i.shape, brow.dtype)
     for k in range(brow.shape[-1]):
         out = jnp.where(i == k, brow[..., k : k + 1], out)
     return out
 
 
-def _unrank_bits(ranks, n1, binom, cellidx, bitpos, dt, rank_dtype,
-                 use_onehot):
+def _unrank_bits(ranks, n1, binom, cellidx, bitpos, dt, rank_dtype):
     """[P, cb] combinadic ranks -> player-1 bitboards, via a descending walk
     over the global cells. binom is the [ncells+1, K] table; cellidx[j] is
     each class's within-class index for global cell j ([ncells, P] i32,
@@ -455,8 +431,8 @@ def _unrank_bits(ranks, n1, binom, cellidx, bitpos, dt, rank_dtype,
         kj = jax.lax.dynamic_index_in_dim(cellidx, j, 0, keepdims=False)
         exists = (kj >= 0)[:, None]  # [P, 1]
         brow = binom[jnp.clip(kj, 0, binom.shape[0] - 1)]  # [P, K]
-        cki = _binom_lookup(brow[:, None, :], rem[..., None],
-                            use_onehot)[..., 0]  # [P, cb] C(k_j, rem)
+        cki = _binom_lookup(brow[:, None, :],
+                            rem[..., None])[..., 0]  # [P, cb] C(k_j, rem)
         # C(k, rem) == 0 (k < rem) means every remaining cell MUST be a
         # stone — 0 <= r always holds, so `take` fires as required.
         take = exists & (rem > 0) & (cki <= r)
@@ -472,7 +448,7 @@ def _unrank_bits(ranks, n1, binom, cellidx, bitpos, dt, rank_dtype,
     return bits
 
 
-def _rank_bits(bits, binom, cellidx_c, bitpos, dt, rank_dtype, use_onehot):
+def _rank_bits(bits, binom, cellidx_c, bitpos, dt, rank_dtype):
     """[P, cb] stone bitboards -> combinadic ranks under the cell indexing
     given by cellidx_c ([ncells, P] i32, the TARGET class per row)."""
     ncells, P = cellidx_c.shape
@@ -487,8 +463,7 @@ def _rank_bits(bits, binom, cellidx_c, bitpos, dt, rank_dtype, use_onehot):
         bset = (bits & masks[j]) != 0
         take = exists & bset
         seen_n = jnp.where(take, seen + 1, seen)
-        ck = _binom_lookup(brow[:, None, :], seen_n[..., None],
-                           use_onehot)[..., 0]
+        ck = _binom_lookup(brow[:, None, :], seen_n[..., None])[..., 0]
         acc = jnp.where(take, acc + ck, acc)
         return acc, seen_n
 
@@ -498,81 +473,8 @@ def _rank_bits(bits, binom, cellidx_c, bitpos, dt, rank_dtype, use_onehot):
     return acc
 
 
-def _rank_all_moves_fused(bits, binom, cellidx, snapk, bitpos, rank_dtype,
-                          use_onehot, p1_moves: bool, w: int, h: int):
-    """All w child ranks in ONE walk over the parent's cells.
-
-    The per-move walk in _rank_bits re-reads every cell w times. But the
-    child class for move c differs from the parent only by inserting one
-    cell at within-child index t_c, so (colex combinadics):
-
-      p2 move:  child_rank(c) = A(t_c) + [S1 - S1(t_c)]
-      p1 move:  child_rank(c) = A(t_c) + C(t_c, seen(t_c)+1)
-                                + [S2 - S2(t_c)]
-
-    where A(t)   = sum of C(k, i) over set cells with parent index < t
-          S1(t)  = same prefix of C(k+1, i)     (cells shift up past t)
-          S2(t)  = same prefix of C(k+1, i+1)   (ordinals also shift: the
-                                                 new stone sits below)
-          seen(t)= set cells before t.
-
-    One walk maintains (A, S_shift, seen) and snapshots A - S_shift
-    (+ the new-stone term) at each column's insertion slot (snapk) —
-    2-3 binom lookups per cell instead of w, the dominant VPU cost of the
-    backward step under the one-hot lowering. Returns cranks [w, P, cb].
-    """
-    ncells, P = cellidx.shape
-    cb = bits.shape[1]
-    masks = jnp.asarray([1 << int(b) for b in bitpos], bits.dtype)
-    shift_ord = 1 if p1_moves else 0
-    kmax = binom.shape[0] - 1
-
-    def body(j, carry):
-        acc_par, acc_sh, seen, snaps = carry
-        kj = jax.lax.dynamic_index_in_dim(cellidx, j, 0, keepdims=False)
-        skj = jax.lax.dynamic_index_in_dim(snapk, j, 0, keepdims=False)
-        exists = (kj >= 0)[:, None]
-        bset = (bits & masks[j]) != 0
-        take = exists & bset
-        seen_n = jnp.where(take, seen + 1, seen)
-        browk = binom[jnp.clip(kj, 0, kmax)]
-        browk1 = binom[jnp.clip(kj + 1, 0, kmax)]
-        cpar = _binom_lookup(browk[:, None, :], seen_n[..., None],
-                             use_onehot)[..., 0]
-        csh = _binom_lookup(browk1[:, None, :],
-                            (seen_n + shift_ord)[..., None],
-                            use_onehot)[..., 0]
-        acc_par = jnp.where(take, acc_par + cpar, acc_par)
-        acc_sh = jnp.where(take, acc_sh + csh, acc_sh)
-        # Snapshot for the move of this step's column. The insertion slot
-        # is ABSENT in the parent (it sits above the column height), so
-        # take is False on snap rows and pre/post-step accumulators agree.
-        is_snap = (skj >= 0)[None, :, None]  # [1, P, 1]
-        snap_val = acc_par - acc_sh
-        if p1_moves:
-            brows = binom[jnp.clip(skj, 0, kmax)]
-            snap_val = snap_val + _binom_lookup(
-                brows[:, None, :], (seen_n + 1)[..., None], use_onehot
-            )[..., 0]
-        col = j // h
-        c_onehot = (jax.lax.iota(jnp.int32, w) == col)[:, None, None]
-        snaps = jnp.where(c_onehot & is_snap, snap_val[None], snaps)
-        return acc_par, acc_sh, seen_n, snaps
-
-    acc_par = jnp.zeros((P, cb), rank_dtype)
-    acc_sh = jnp.zeros((P, cb), rank_dtype)
-    seen = jnp.zeros((P, cb), jnp.int32)
-    snaps = jnp.zeros((w, P, cb), rank_dtype)
-    acc_par, acc_sh, seen, snaps = jax.lax.fori_loop(
-        0, ncells, body, (acc_par, acc_sh, seen, snaps)
-    )
-    return snaps + acc_sh[None]
-
-
 def build_dense_step(tables: DenseTables, level: int, cblock: int,
-                     rank_dtype, flat_dtype, use_onehot: bool,
-                     fused_rank: bool = False,
-                     gather_mode: str = "plain"):
+                     rank_dtype, flat_dtype, gather_mode: str = "plain"):
     """Build the backward step for one level at one block width.
 
     Returned fn:
@@ -580,8 +482,7 @@ def build_dense_step(tables: DenseTables, level: int, cblock: int,
        level),
        binom [ncells+1, K], cellidx [ncells, P] i32, filled [P],
        newbit [P, w], valid [P, w] bool, move_row [P, w] i32,
-       move_fill [P, w] i32, child_cellidx [ncells, P, w] i32,
-       snapk [ncells, P] i32)
+       move_fill [P, w] i32, child_cellidx [ncells, P, w] i32)
       -> (cells [P, cblock] u8 ([P, C] where a pallas-mode cblock pads
           C), missed gather lanes int32 scalar)
 
@@ -596,11 +497,6 @@ def build_dense_step(tables: DenseTables, level: int, cblock: int,
                  counted: the second output, 0 in plain mode. Blocks
                  never straddle profile rows (_cblock rounds to the
                  kernel block).
-
-    fused_rank picks the single-walk child ranking
-    (_rank_all_moves_fused) over the per-move walks; results are
-    identical (tests pin it) — it is a lowering choice, keyed into the
-    kernel cache.
 
     All shape-static; one compiled program per (level-shape, block width).
     """
@@ -621,7 +517,7 @@ def build_dense_step(tables: DenseTables, level: int, cblock: int,
     bitpos = [int(b) for b in tables.bitpos]
 
     def step(rank0, child_cells, binom, cellidx, filled, newbit,
-             valid, move_row, move_fill, child_cellidx, snapk):
+             valid, move_row, move_fill, child_cellidx):
         # Scopes (docs/OBSERVABILITY.md): expand = unrank + line tests,
         # lookup = child rank, gather = the u8 child-cell fetch, combine.
         P = filled.shape[0]
@@ -630,7 +526,7 @@ def build_dense_step(tables: DenseTables, level: int, cblock: int,
                      + jax.lax.iota(rank_dtype, cblock)[None, :])  # [1, cb]
 
             p1 = _unrank_bits(ranks, n1, binom, cellidx, bitpos, dt,
-                              rank_dtype, use_onehot)
+                              rank_dtype)
             p2 = filled[:, None] ^ p1
             mover = p1 if mover_is_p1 else p2
             current = p2 if mover_is_p1 else p1
@@ -650,23 +546,14 @@ def build_dense_step(tables: DenseTables, level: int, cblock: int,
                 ), jnp.int32(0)  # remoteness 0 everywhere at the top level
         prim_mask = mover_line | current_line
 
-        if fused_rank:
-            with jax.named_scope("lookup"):
-                cranks = _rank_all_moves_fused(
-                    p1, binom, cellidx, snapk, bitpos, rank_dtype,
-                    use_onehot, p1_moves, w, h,
-                )
         cells = []
         flats = []
         masks = []
         for c in range(w):
             with jax.named_scope("lookup"):
-                if fused_rank:
-                    crank = cranks[c]
-                else:
-                    cbits = (p1 | newbit[:, c : c + 1]) if p1_moves else p1
-                    crank = _rank_bits(cbits, binom, child_cellidx[:, :, c],
-                                       bitpos, dt, rank_dtype, use_onehot)
+                cbits = (p1 | newbit[:, c : c + 1]) if p1_moves else p1
+                crank = _rank_bits(cbits, binom, child_cellidx[:, :, c],
+                                   bitpos, dt, rank_dtype)
                 flat = (move_row[:, c : c + 1].astype(flat_dtype)
                         * flat_dtype(Cc) + crank.astype(flat_dtype))
                 ok = valid[:, c : c + 1] & jnp.ones((1, cblock), bool)
@@ -737,13 +624,10 @@ def build_dense_step(tables: DenseTables, level: int, cblock: int,
 
 
 def build_reach_step(tables: DenseTables, level: int, cblock: int,
-                     rank_dtype, flat_dtype, use_onehot: bool,
-                     fused_rank: bool = False, gather_mode: str = "plain"):
+                     rank_dtype, flat_dtype, gather_mode: str = "plain"):
     """Build the reachability-sweep step for one level (level >= 1).
 
-    fused_rank/gather_mode are accepted for builder-signature uniformity
-    and ignored: the sweep's one-rank-per-column walk has no per-move
-    fan-out to fuse (each column ranks a DIFFERENT parent bit pattern).
+    gather_mode is accepted for builder-signature uniformity and ignored.
 
     reach(y) = OR over columns c of y's class: the top stone of column c
     belongs to the player who made ply `level` AND the position with that
@@ -775,8 +659,7 @@ def build_reach_step(tables: DenseTables, level: int, cblock: int,
                  + jax.lax.iota(rank_dtype, cblock)[None, :])
         in_range = ranks < rank_dtype(C)
 
-        p1 = _unrank_bits(ranks, n1, binom, cellidx, bitpos, dt, rank_dtype,
-                          use_onehot)
+        p1 = _unrank_bits(ranks, n1, binom, cellidx, bitpos, dt, rank_dtype)
 
         reach = jnp.zeros((P, cblock), bool)
         for c in range(w):
@@ -791,7 +674,7 @@ def build_reach_step(tables: DenseTables, level: int, cblock: int,
                             else parent_filled ^ parent_p1)
             parent_live = ~_connected_fold(parent_mover, h, connect, dt)
             prank = _rank_bits(parent_p1, binom, parent_cellidx[:, :, c],
-                               bitpos, dt, rank_dtype, use_onehot)
+                               bitpos, dt, rank_dtype)
             flat = (parent_row[:, c : c + 1].astype(flat_dtype)
                     * flat_dtype(Cp) + prank.astype(flat_dtype))
             pr = parent_reach[
@@ -1037,25 +920,6 @@ class DenseSolver:
         self.sync_cells = env_int(
             "GAMESMAN_DENSE_SYNC_CELLS", 256 * 1024 * 1024
         )
-        # Binom lookup lowering: the one-hot select tree is bounded VPU
-        # work (K-1 selects, K <= 23); take_along_axis emits a gather,
-        # and XLA's TPU gathers measured ~11 ns/element (tools/microbench)
-        # — at (1 + max_moves) * ncells lookups per position that would
-        # dominate the whole solve. CONFIRMED on the v5e (chip session
-        # r04, 5x5): onehot 9.04M pos/s vs take 212k — a 43x collapse,
-        # exactly the predicted gather catastrophe. onehot is the default;
-        # GAMESMAN_DENSE_BINOM=take re-enables the gather for measurement.
-        self.use_onehot = env_str(
-            "GAMESMAN_DENSE_BINOM", "onehot"
-        ) != "take"
-        # Child-ranking lowering: "fused" = one walk for all moves
-        # (_rank_all_moves_fused), "simple" = per-move walks. Identical
-        # results (tests pin it). MEASURED on the v5e (chip session r04,
-        # 5x5 A/B): simple 9.04M pos/s vs fused 4.83M — simple wins 1.9x
-        # and stays the default; the flag remains for re-measurement.
-        self.use_fused = env_str(
-            "GAMESMAN_DENSE_RANK", "simple"
-        ) == "fused"
         # Gather lowering (identical results in all modes, tests pin it):
         #   "plain"  — clip + XLA gather, the CPU default. On the v5e XLA
         #              treats it as random access: 7.649 s of an 8.56 s
@@ -1149,13 +1013,11 @@ class DenseSolver:
         return dict(in_shardings=rep, out_shardings=(cells, rep))
 
     def _kernel(self, kind: str, level: int, cblock: int, builder):
-        t, rd, fd, oh, fr, gm = (self.tables, self._rank_dtype,
-                                 self._flat_dtype, self.use_onehot,
-                                 self.use_fused, self.gather_mode)
+        t, rd, fd, gm = (self.tables, self._rank_dtype, self._flat_dtype,
+                         self.gather_mode)
         return get_kernel(
             self.game, kind, self._kernel_key(kind, level, cblock),
-            lambda g: builder(t, level, cblock, rd, fd, oh, fused_rank=fr,
-                              gather_mode=gm),
+            lambda g: builder(t, level, cblock, rd, fd, gather_mode=gm),
             jit_kwargs=self._jit_kwargs(kind),
         )
 
@@ -1234,17 +1096,15 @@ class DenseSolver:
             sds((P, w), np.int32),        # move_row
             sds((P, w), np.int32),        # move_fill
             sds((t.ncells, P, w), np.int32),  # child_cellidx
-            sds((t.ncells, P), np.int32),     # snapk
         )
 
     def _kernel_key(self, kind: str, level: int, cblock: int):
-        # use_fused/gather_mode only change dense_step lowering;
-        # keying them into the reach kernels would recompile byte-identical
-        # programs on a flag flip (seconds of TPU compile each).
-        fused = self.use_fused if kind == "dense_step" else False
+        # gather_mode only changes dense_step lowering; keying it into the
+        # reach kernels would recompile byte-identical programs on a flag
+        # flip (seconds of TPU compile each).
         gm = self.gather_mode if kind == "dense_step" else "plain"
         return (
-            kind, level, cblock, self.use_onehot, fused, gm,
+            kind, level, cblock, gm,
             str(self._rank_dtype), str(self._flat_dtype), self.devices,
         )
 
@@ -1269,14 +1129,12 @@ class DenseSolver:
         def sched(kind, level, builder, for_reach):
             cblock, _ = self._cblock(level)
             key = self._kernel_key(kind, level, cblock)
-            rd, fd, oh, fr, gm = (self._rank_dtype, self._flat_dtype,
-                                  self.use_onehot, self.use_fused,
-                                  self.gather_mode)
+            rd, fd, gm = (self._rank_dtype, self._flat_dtype,
+                          self.gather_mode)
             P = len(t.profiles[level])
             schedule_kernel(
                 self.game, kind, key,
-                lambda g: builder(t, level, cblock, rd, fd, oh,
-                                  fused_rank=fr, gather_mode=gm),
+                lambda g: builder(t, level, cblock, rd, fd, gather_mode=gm),
                 self._avals(level, cblock, for_reach),
                 heavy=P * cblock * 8 > (512 << 20),
                 jit_kwargs=self._jit_kwargs(kind),
@@ -1345,7 +1203,6 @@ class DenseSolver:
                 child_cellidx=jnp.asarray(
                     steps_first(consts["child_cellidx"])
                 ),
-                snapk=jnp.asarray(steps_first(consts["snapk"])),
             )
         t._dev_consts[ck] = out
         return out
@@ -1441,7 +1298,7 @@ class DenseSolver:
                 consts["binom"], consts["cellidx"], consts["filled"],
                 consts["newbit"], consts["valid"],
                 consts["move_row"], consts["move_fill"],
-                consts["child_cellidx"], consts["snapk"],
+                consts["child_cellidx"],
             )
             blocks.append(cells_b)
             # Kept on the device: solve() fetches them once, with the root.

@@ -67,7 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from gamesmanmpi_tpu.core.codec import pack_cells, unpack_cells
+from gamesmanmpi_tpu.core.codec import unpack_cells
 from gamesmanmpi_tpu.core.values import UNDECIDED
 from gamesmanmpi_tpu.games.base import TensorGame
 from gamesmanmpi_tpu.ops.combine import combine_children
@@ -76,17 +76,7 @@ from gamesmanmpi_tpu.ops.dedup import (
     compaction_sort_bytes,
     sort_unique,
 )
-from gamesmanmpi_tpu.ops.fused import (
-    fused_dedup_method,
-    fused_dedup_provenance,
-    fused_enabled,
-    fused_sort_unique,
-    pipeline_mode,
-    use_value_table,
-)
-from gamesmanmpi_tpu.ops.mergesort import backend_key, use_merge_sort
 from gamesmanmpi_tpu.ops.lookup import lookup_window, search_method
-from gamesmanmpi_tpu.ops.pallas_gather import cells_table_gather
 from gamesmanmpi_tpu.ops.provenance import dedup_provenance, gather_cells
 from gamesmanmpi_tpu.ops.padding import MIN_BUCKET, bucket_size, pad_to, pad_to_bucket
 from gamesmanmpi_tpu.obs import (
@@ -172,9 +162,8 @@ _KERNELS: dict = {}
 
 
 # ---------------------------------------------------------------------------
-# Dispatch accounting (ISSUE 14). The fused-megakernel work claims "fewer
-# dispatches per level"; this counter is what makes that claim falsifiable
-# in a bench record instead of a narrative. Every device computation the
+# Dispatch accounting: how many device computations a solve issues, per
+# level, as a bench record can cite it. Every device computation the
 # engines issue — cached-kernel calls (counted in get_kernel's wrapper),
 # plus the eager slice/pad/upload/download ops the hot loops perform
 # between kernels — calls note_dispatch. The active solver registers a
@@ -245,10 +234,10 @@ def tally_dispatch(solver, kind: str) -> None:
 
 def _cache_key(game: TensorGame, kind: str, shape_key, lowering):
     """Cache key for a kernel. Builders whose programs embed a
-    flag/platform-resolved lowering — the sort backend (GAMESMAN_SORT
-    [_ROW]), the searchsorted method (GAMESMAN_SEARCH), the dedup
-    compaction (GAMESMAN_COMPACT) — pass the RESOLVED choices as the
-    `lowering` tuple at their get_kernel/schedule_kernel call site. The
+    flag/platform-resolved lowering — the searchsorted method
+    (GAMESMAN_SEARCH), the dedup compaction (GAMESMAN_COMPACT) — pass the
+    RESOLVED choices as the `lowering` tuple at their
+    get_kernel/schedule_kernel call site. The
     builder itself captures the same values when it runs (immediately, at
     key time — schedule_kernel calls builder(game) before handing the
     traceable to the pool), so a mid-process flag flip can neither reuse a
@@ -429,20 +418,18 @@ def canonical_children(game: TensorGame, states, active):
 
 
 @jax.named_scope("expand")
-def expand_core(game: TensorGame, states, merge: bool | None = None,
-                compact: str | None = None):
+def expand_core(game: TensorGame, states, compact: str | None = None):
     """Shared expand+mask+dedup: [B] -> (uniq [B*M] sorted, count).
 
-    merge/compact: sort-backend flag and compaction lowering, resolved at
-    BUILD time by kernel builders (None = read the env/platform at trace
-    time; see ops.mergesort.sort1, ops.dedup.compact_method)."""
+    compact: compaction lowering, resolved at BUILD time by kernel
+    builders (None = read the platform at trace time; see
+    ops.dedup.compact_method)."""
     children, _ = canonical_children(game, states, undecided_mask(game, states))
-    return sort_unique(children.reshape(-1), merge, compact)
+    return sort_unique(children.reshape(-1), compact)
 
 
 @jax.named_scope("expand")
-def expand_provenance(game: TensorGame, states, merge: bool | None = None,
-                      compact: str | None = None):
+def expand_provenance(game: TensorGame, states, compact: str | None = None):
     """Forward expand that also keeps the dedup sort's provenance.
 
     Returns (uniq [B*M], count, uidx [B*M] int32, prim [B] uint8):
@@ -462,7 +449,7 @@ def expand_provenance(game: TensorGame, states, merge: bool | None = None,
     prim = game.primitive(states)
     active = (states != game.sentinel) & (prim == UNDECIDED)
     children, _ = canonical_children(game, states, active)
-    uniq, count, uidx = dedup_provenance(children.reshape(-1), merge, compact)
+    uniq, count, uidx = dedup_provenance(children.reshape(-1), compact)
     return uniq, count, uidx, prim
 
 
@@ -496,158 +483,11 @@ def resolve_provenance(n, prim, uidx, wvals, wrem, max_moves: int):
 
 
 @jax.named_scope("expand")
-def expand_with_levels(game: TensorGame, states, merge: bool | None = None,
-                       compact: str | None = None):
+def expand_with_levels(game: TensorGame, states, compact: str | None = None):
     """Generic-path forward: expand_core + each child's topological level."""
-    uniq, count = expand_core(game, states, merge, compact)
+    uniq, count = expand_core(game, states, compact)
     levels = jnp.where(uniq != game.sentinel, game.level_of(uniq), -1)
     return uniq, levels, count
-
-
-# ------------------------------------------------------- fused level kernels
-# ISSUE 14: the megakernel bodies. One jitted program per level replaces the
-# unfused chain of expand-kernel dispatch + eager next-frontier slice/pad (+
-# speculative re-dispatch); the dedup inside is the fused rank/sort+dedup
-# stage (ops/fused), fed the level's COUNT so the callback lowering sorts
-# only the real prefix instead of the padded capacity.
-
-
-def _chain_to_cap(buf, cap: int, sentinel):
-    """In-program frontier chaining: slice (or sentinel-extend) the previous
-    level's dedup output to this level's capacity bucket. The unfused path
-    does this with eager ops between dispatches; here it fuses into the
-    megakernel, so the chained buffer never surfaces as its own dispatch."""
-    if buf.shape[0] >= cap:
-        return jax.lax.slice(buf, (0,), (cap,))
-    return jnp.concatenate(
-        [buf, jnp.full(cap - buf.shape[0], sentinel, dtype=buf.dtype)]
-    )
-
-
-@jax.named_scope("expand")
-def fused_forward_step(game: TensorGame, states, n, keep_children: bool,
-                       method: str | None, merge: bool | None,
-                       compact: str | None):
-    """One fused forward level: primitive + expand + canonicalize + dedup.
-
-    states: [cap] (sentinel tail beyond the real count n). Returns
-    (states [cap], uniq [cap*M], count, prim [cap], aux [cap*M]) where aux
-    is the level's canonical children (keep_children=True — the value-table
-    backward's input) or its dedup provenance uidx (the gather-only
-    backward's input). `states` is echoed so the caller can retain the
-    level without re-slicing outside the program.
-    """
-    prim = game.primitive(states)
-    active = (states != game.sentinel) & (prim == UNDECIDED)
-    children, _ = canonical_children(game, states, active)
-    flat = children.reshape(-1)
-    nv = n.astype(jnp.int32) * jnp.int32(game.max_moves)
-    if keep_children:
-        uniq, count = fused_sort_unique(flat, nv, method, merge, compact)
-        return states, uniq, count, prim, flat
-    uniq, count, uidx = fused_dedup_provenance(flat, nv, method, merge,
-                                               compact)
-    return states, uniq, count, prim, uidx
-
-
-@jax.named_scope("expand")
-def expand_with_levels_fused(game: TensorGame, states, n,
-                             method: str | None, merge: bool | None,
-                             compact: str | None):
-    """Generic-path fused forward: expand_with_levels with the fused dedup
-    stage and the count-limited prefix (n = real frontier rows)."""
-    prim = game.primitive(states)
-    active = (states != game.sentinel) & (prim == UNDECIDED)
-    children, _ = canonical_children(game, states, active)
-    nv = n.astype(jnp.int32) * jnp.int32(game.max_moves)
-    uniq, count = fused_sort_unique(children.reshape(-1), nv, method, merge,
-                                    compact)
-    levels = jnp.where(uniq != game.sentinel, game.level_of(uniq), -1)
-    return uniq, levels, count
-
-
-@jax.named_scope("gather")
-def fused_table_resolve(game: TensorGame, cells, states, prim, kids,
-                        table_len: int):
-    """One fused backward level against the persistent value table.
-
-    cells: [2^state_bits] packed (value, remoteness) cells indexed by
-    packed state — the cross-level ping-pong buffer: it is DONATED to this
-    kernel and returned updated, so the whole backward sweep runs in two
-    alternating aliases of one allocation. Children gather their answers
-    directly (cells_table_gather — every child of level k lives in level
-    k+1, already scattered), the negamax combine runs in-program, and this
-    level's own cells scatter in before the buffer is handed back.
-
-    Replaces, per level: the window slice/pad chain, the sort-merge join
-    or binary search, and (with stored kids) the re-expansion. Misses are
-    structurally impossible for real children; the counter tracks
-    undecided-with-UNDECIDED-child (a table-discipline bug) and zero-move
-    undecided rows (a game-definition error), same as resolve_level.
-    """
-    M = game.max_moves
-    B = states.shape[0]
-    valid = states != game.sentinel
-    undecided = valid & (prim == UNDECIDED)
-    k2 = kids.reshape(B, M)
-    kvalid = (k2 != game.sentinel) & undecided[:, None]
-    cv, cr = unpack_cells(cells_table_gather(cells, k2, kvalid))
-    mask = kvalid & (cv != UNDECIDED)
-    values, remoteness = combine_children(cv, cr, mask)
-    values = jnp.where(
-        undecided, values,
-        jnp.where(valid, prim, jnp.uint8(UNDECIDED)),
-    )
-    remoteness = jnp.where(undecided, remoteness, 0)
-    misses = jnp.sum(kvalid & (cv == UNDECIDED)) + jnp.sum(
-        undecided & ~jnp.any(kvalid, axis=-1)
-    )
-    # Sentinel lanes scatter out of bounds and drop; real lanes (including
-    # primitives — children of the shallower level need them) land at
-    # their state index.
-    idx = jnp.where(valid, states, states.dtype.type(table_len))
-    cells = cells.at[idx].set(pack_cells(values, remoteness), mode="drop")
-    return values, remoteness, misses, cells
-
-
-def _make_fwdm_builder(cap: int, keep_children: bool, method: str,
-                       merge: bool, compact: str):
-    """Builder factory for the forward megakernel — shared by the inline
-    get_kernel call and the background scheduler so both produce the same
-    program under the same key."""
-
-    def build(game):
-        def f(buf, n):
-            states = _chain_to_cap(buf, cap, game.sentinel)
-            return fused_forward_step(game, states, n, keep_children,
-                                      method, merge, compact)
-
-        return f
-
-    return build
-
-
-def _make_bwdt_builder(has_kids: bool, table_len: int):
-    """Builder factory for the value-table backward kernel (see _bwdt)."""
-
-    def build(game):
-        def f_kids(cells, states, prim, kids):
-            return fused_table_resolve(game, cells, states, prim, kids,
-                                       table_len)
-
-        def f_expand(cells, states):
-            # Level lost its stored children (budget eviction / resumed
-            # from checkpoint): regenerate them in-program — still one
-            # dispatch, just with the expand work back in it.
-            prim = game.primitive(states)
-            undecided = (states != game.sentinel) & (prim == UNDECIDED)
-            kids, _ = canonical_children(game, states, undecided)
-            return fused_table_resolve(game, cells, states, prim,
-                                       kids.reshape(-1), table_len)
-
-        return f_kids if has_kids else f_expand
-
-    return build
 
 
 @jax.named_scope("lookup")
@@ -723,23 +563,17 @@ class _Level:
     level's primitive values and its out-edge indices into the NEXT level's
     prefix. Device-only, kept while the store budget allows; when absent the
     backward pass falls back to the sort-merge join.
-
-    kids is the fused value-table alternative to uidx (ISSUE 14): the
-    level's canonical children [cap*M], kept so the fused backward gathers
-    their cells from the persistent table with no re-expansion. A level
-    carries uidx OR kids, never both (the forward mode decides).
     """
 
-    __slots__ = ("n", "host", "dev", "prim", "uidx", "kids")
+    __slots__ = ("n", "host", "dev", "prim", "uidx")
 
     def __init__(self, n: int, host: Optional[np.ndarray], dev,
-                 prim=None, uidx=None, kids=None):
+                 prim=None, uidx=None):
         self.n = n  # real (non-sentinel) count
         self.host = host  # np [n] sorted, or None if only on device
         self.dev = dev  # jnp [cap] sorted + sentinel tail, or None
         self.prim = prim  # jnp [cap] uint8, or None
         self.uidx = uidx  # jnp [cap*M] int32, or None
-        self.kids = kids  # jnp [cap*M] states, or None (fused table mode)
 
     def host_states(self) -> np.ndarray:
         if self.host is None:
@@ -829,22 +663,12 @@ class Solver:
         #: transient level-step failures absorbed by retry (stats field;
         #: the registry carries the per-point gamesman_retries_total).
         self.retries = 0
-        # ISSUE 14 fused/pipeline mode, resolved at SOLVE time like every
-        # platform/env-auto knob (a force_platform or env flip between
-        # construction and solve() must be honored).
-        self.use_fused: bool | None = None
-        self.pipeline: str | None = None
-        self._fused_table = False
         #: dispatch accounting (see note_dispatch): total device
         #: computations/transfers this solve issued, and the per-(phase,
         #: level) breakdown the zero-extra-dispatch tests assert on.
         self.dispatch_total = 0
         self.level_dispatches: Dict[tuple, int] = {}
         self.dispatch_by_kind: Dict[str, int] = {}
-        #: host-side seconds the pingpong pipeline ran while a device
-        #: kernel was in flight (downloads/export/checkpoint deferred one
-        #: level — stats field; 0.0 in level mode).
-        self.overlap_secs = 0.0
         #: analytic host-transfer bytes (frontier/table uploads+downloads
         #: and checkpoint materializations) — the host-side roofline
         #: denominator next to bytes_sorted/bytes_gathered's HBM side.
@@ -886,8 +710,8 @@ class Solver:
         # schedule_kernel), so resolving the lowering knobs HERE keeps the
         # traced program consistent with the key even when a background
         # worker traces it later.
-        mb, cm = use_merge_sort(), compact_method()
-        return lambda states: expand_provenance(game, states, mb, cm)
+        cm = compact_method()
+        return lambda states: expand_provenance(game, states, cm)
 
     @staticmethod
     def _bwd_builder(game):
@@ -911,13 +735,13 @@ class Solver:
 
     @staticmethod
     def _fwdf_builder(game):
-        mb, cm = use_merge_sort(), compact_method()
-        return lambda states: expand_core(game, states, mb, cm)
+        cm = compact_method()
+        return lambda states: expand_core(game, states, cm)
 
     @staticmethod
     def _fwd_lowering():
-        """Knobs the forward kernels embed: sorts + dedup compaction."""
-        return (backend_key(), compact_method())
+        """Knob the forward kernels embed: the dedup compaction."""
+        return (compact_method(),)
 
     def _fwdp(self, cap: int):
         """Provenance forward: states[cap] -> (uniq, count, uidx, prim)."""
@@ -936,25 +760,9 @@ class Solver:
         return get_kernel(self.game, "bwdp", (cap, wcap), self._bwdp_builder)
 
     def _fwd_generic(self, cap: int):
-        if self.use_fused:
-            # Generic-path megakernel: fused dedup + count-limited prefix
-            # (the caller passes the real frontier row count alongside the
-            # padded states). Separate kind — the signatures differ.
-            md = fused_dedup_method()
-
-            def build_fused(game):
-                mb, cm = use_merge_sort(), compact_method()
-                return lambda states, n: expand_with_levels_fused(
-                    game, states, n, md, mb, cm
-                )
-
-            return get_kernel(self.game, "fwdgm", cap, build_fused,
-                              lowering=self._fused_lowering())
-
         def build(game):
-            # resolved at cache-key time
-            mb, cm = use_merge_sort(), compact_method()
-            return lambda states: expand_with_levels(game, states, mb, cm)
+            cm = compact_method()  # resolved at cache-key time
+            return lambda states: expand_with_levels(game, states, cm)
 
         return get_kernel(self.game, "fwdg", cap, build,
                           lowering=self._fwd_lowering())
@@ -970,84 +778,6 @@ class Solver:
             self.game, "bwd", (cap, tuple(wcaps)), self._bwd_builder,
             lowering=(search_method(),),  # lookup_window's search lowering
         )
-
-    # ------------------------------------------------- fused megakernels
-
-    def _fused_lowering(self):
-        """Knobs the fused kernels embed: dedup method + sorts + compact."""
-        return (fused_dedup_method(), backend_key(), compact_method())
-
-    def _fwdm(self, in_len: int, cap: int):
-        """Forward megakernel: (buf [in_len], n) -> (states [cap],
-        uniq [cap*M], count, prim [cap], kids|uidx [cap*M]). Keyed on the
-        chain-input length AND the capacity — both are power-of-two
-        buckets, so the key count stays O(log max-frontier)."""
-        md = fused_dedup_method()
-        mb, cm = use_merge_sort(), compact_method()
-        return get_kernel(
-            self.game, "fwdm", (in_len, cap, self._fused_table),
-            _make_fwdm_builder(cap, self._fused_table, md, mb, cm),
-            lowering=self._fused_lowering(),
-        )
-
-    def _sched_fwdm(self, in_len: int, cap: int) -> None:
-        if cap > self._cap_ceiling:
-            return
-        md = fused_dedup_method()
-        mb, cm = use_merge_sort(), compact_method()
-        schedule_kernel(
-            self.game, "fwdm", (in_len, cap, self._fused_table),
-            _make_fwdm_builder(cap, self._fused_table, md, mb, cm),
-            (sds((in_len,), self.game.state_dtype), sds((), np.int32)),
-            heavy=self._heavy(cap), lowering=self._fused_lowering(),
-        )
-
-    def _bwdt(self, cap: int, has_kids: bool):
-        """Value-table backward megakernel: (cells [T], states [cap]
-        [, prim [cap], kids [cap*M]]) -> (values, rem, misses, cells').
-
-        The cells buffer is donated — the ping-pong discipline: exactly
-        two aliases of the [2^state_bits] table alternate across the
-        whole backward sweep, and no window tensors exist at all.
-        """
-        return get_kernel(
-            self.game, "bwdt", (cap, has_kids),
-            _make_bwdt_builder(has_kids, 1 << self.game.state_bits),
-            jit_kwargs={"donate_argnums": (0,)},
-        )
-
-    def _sched_bwdt(self, cap: int, has_kids: bool) -> None:
-        if cap > self._cap_ceiling:
-            return
-        g = self.game
-        T = 1 << g.state_bits
-        avals = [sds((T,), np.uint32), sds((cap,), g.state_dtype)]
-        if has_kids:
-            avals += [sds((cap,), np.uint8),
-                      sds((cap * g.max_moves,), g.state_dtype)]
-        schedule_kernel(
-            self.game, "bwdt", (cap, has_kids),
-            _make_bwdt_builder(has_kids, T), tuple(avals),
-            heavy=self._heavy(cap),
-            jit_kwargs={"donate_argnums": (0,)},
-        )
-
-    def _bwdc(self, cap: int):
-        """Checkpoint-resume cell scatter: fold a loaded level's solved
-        (values, remoteness) into the persistent table without resolving."""
-        T = 1 << self.game.state_bits
-
-        def build(game, T=T):
-            def f(cells, states, values, rem):
-                valid = states != game.sentinel
-                idx = jnp.where(valid, states, states.dtype.type(T))
-                return cells.at[idx].set(pack_cells(values, rem),
-                                         mode="drop")
-
-            return f
-
-        return get_kernel(self.game, "bwdc", cap, build,
-                          jit_kwargs={"donate_argnums": (0,)})
 
     # ---------------------------------------------- background compile plan
 
@@ -1105,23 +835,14 @@ class Solver:
 
     def _sched_fwd_step(self, cap: int) -> None:
         """Schedule whichever forward kernel this solver will request."""
-        if self.use_fused:
-            # The chain key the megakernel will actually request: the
-            # previous bucket's uniq buffer feeding this capacity (plus
-            # the same-capacity entry key for the root level).
-            self._sched_fwdm(cap, cap)
-            self._sched_fwdm(cap * self.game.max_moves, cap)
-            self._sched_fwdm(cap * self.game.max_moves, cap * 2)
-        elif self.use_provenance:
+        if self.use_provenance:
             self._sched_fwdp(cap)
         else:
             self._sched_fwdf(cap)
 
     def _sched_bwd_step(self, cap: int, wcap: int) -> None:
         """Schedule whichever backward kernel this solver will request."""
-        if self._fused_table:
-            self._sched_bwdt(cap, True)
-        elif self.use_provenance:
+        if self.use_provenance:
             self._sched_bwdp(cap, wcap)
         else:
             self._sched_bwd(cap, (wcap,))
@@ -1434,168 +1155,6 @@ class Solver:
             k += 1
         return levels
 
-    def _forward_fast_fused(self, init, start_level: int,
-                            resume: Optional[Dict[int, np.ndarray]] = None,
-                            ) -> Dict[int, _Level]:
-        """Megakernel forward sweep (GAMESMAN_FUSED=1): ONE dispatch/level.
-
-        The unfused path's per-level chain — expand-kernel dispatch, eager
-        next-frontier slice/pad, speculative re-dispatch — collapses into a
-        single jitted program per (in_len, cap) key (_fwdm): the previous
-        level's dedup output enters UNSLICED, the chain slice happens
-        in-program, and the fused dedup stage receives the previous level's
-        count so the callback lowering sorts only the real prefix. The
-        kernel also emits everything the backward pass needs (states echo,
-        primitive values, canonical children or provenance), so the
-        backward never re-expands and nothing round-trips through host
-        buffers.
-
-        Pipelining is inherent here — the chain is exactly the ping-pong
-        shape (uniq buffer feeding the next dispatch while the states echo
-        is retained) — so per-level host work (frontier checkpoint, budget
-        downloads) always runs AFTER the next level's kernel is in flight;
-        those seconds accumulate into overlap_secs.
-        """
-        g = self.game
-        levels: Dict[int, _Level] = {}
-        if resume:
-            ks = sorted(resume)
-            if ks != list(range(ks[0], ks[-1] + 1)) or ks[0] != start_level:
-                raise SolverError(
-                    f"forward checkpoint levels {ks} are not contiguous from "
-                    f"the root level {start_level} — stale checkpoint "
-                    "directory?"
-                )
-            for kk in ks:
-                arr = np.asarray(resume[kk], dtype=g.state_dtype)
-                levels[kk] = _Level(arr.shape[0], arr, None)
-            k = ks[-1]
-            host0 = levels[k].host
-        else:
-            host0 = np.atleast_1d(np.asarray(init, dtype=g.state_dtype))
-            k = start_level
-        cap = bucket_size(host0.shape[0], self.min_bucket)
-        note_dispatch("upload")
-        frontier = jnp.asarray(pad_to(host0, cap))
-        if resume:
-            levels[k].dev = frontier
-        else:
-            levels[k] = _Level(host0.shape[0], host0, frontier)
-            if self.checkpointer is not None:
-                with trace_span("checkpoint", level=k, kind="frontier"):
-                    self.checkpointer.save_frontier_level(k, host0)
-        stored_bytes = frontier.nbytes
-        item = np.dtype(g.state_dtype).itemsize
-        callback_dedup = fused_dedup_method() == "callback"
-        # The retried unit's held inputs: (buf, n_arg, in_len, cap).
-        call = (frontier, np.int32(levels[k].n), cap, cap)
-        pending = self._fwdm(call[2], call[3])(call[0], call[1])
-        evicted: set = set()
-        while True:
-            sp = Span("forward", logger=self.logger, level=k)
-            d0 = self.dispatch_total
-            self.progress = {
-                "phase": "forward", "level": k, "frontier": levels[k].n,
-            }
-            preempt.check("forward", level=k, logger=self.logger)
-            memguard.check("forward", level=k, logger=self.logger)
-            holder = [pending]
-
-            def _sync(holder=holder, k=k):
-                faults.fire("engine.forward", level=k)
-                faults.fire("engine.dedup", level=k)
-                return int(holder[0][2])  # the one host sync per level
-
-            def _redispatch(holder=holder, call=call):
-                holder[0] = self._fwdm(call[2], call[3])(call[0], call[1])
-
-            with trace_span("dedup", level=k):
-                n = self._retry("engine.forward", _sync, reset=_redispatch,
-                                level=k)
-            pending = holder[0]
-            states_out, uniq, count, prim, aux = pending
-            rec = levels[k]
-            if rec.dev is None and k not in evicted:
-                rec.dev = states_out
-            extra = prim.nbytes + aux.nbytes
-            if n > 0 and stored_bytes + extra <= self.device_store_bytes:
-                rec.prim = prim
-                if self._fused_table:
-                    rec.kids = aux
-                else:
-                    rec.uidx = aux
-                stored_bytes += extra
-            if n == 0:
-                sp.end(log=False)
-                self.status_tracker.forward_level(k, levels[k].n, sp.secs)
-                flightrec.boundary("forward", k)
-                break
-            if k + 1 >= g.num_levels:
-                raise SolverError(
-                    f"game {g.name}: children found at level {k + 1} but "
-                    f"num_levels={g.num_levels} — level_of/num_levels "
-                    "inconsistent"
-                )
-            next_cap = bucket_size(n, self.min_bucket)
-            if next_cap > call[3]:
-                for ahead in (next_cap * 2, next_cap * 4):
-                    self._sched_fwdm(ahead * g.max_moves, ahead)
-                    self._sched_bwd_step(min(ahead, self._block_size()),
-                                         ahead)
-            in_len = uniq.shape[0]
-            rec2 = _Level(n, None, None)
-            levels[k + 1] = rec2
-            call = (uniq, count, in_len, next_cap)
-            pending = self._fwdm(in_len, next_cap)(uniq, count)
-            # Host work runs with the next level's kernel in flight (the
-            # ping-pong overlap); its wall time is real but concurrent.
-            t_host = time.perf_counter()
-            over_budget = stored_bytes + next_cap * item \
-                > self.device_store_bytes
-            if over_budget:
-                evicted.add(k + 1)
-            else:
-                stored_bytes += next_cap * item
-            if self.checkpointer is not None or over_budget:
-                note_dispatch("download")
-                rec2.host = np.asarray(uniq[:n])
-            if self.checkpointer is not None:
-                with trace_span("checkpoint", level=k + 1, kind="frontier"):
-                    self.checkpointer.save_frontier_level(k + 1, rec2.host)
-            self.overlap_secs += time.perf_counter() - t_host
-            if callback_dedup:
-                # numpy radix sort over the real children prefix only.
-                level_sort_bytes = levels[k].n * g.max_moves * item
-            elif self._fused_table:
-                # plain dedup sort + compaction over the padded block.
-                level_sort_bytes = in_len * (
-                    item + compaction_sort_bytes(item)
-                )
-            else:
-                # scatterinv: ONE (state, i32) pair sort + the compaction
-                # (vs the provenance path's two pair sorts).
-                level_sort_bytes = in_len * (
-                    item + 4 + compaction_sort_bytes(item)
-                )
-            self.bytes_sorted += level_sort_bytes
-            fwd_host_bytes = (
-                n * item
-                if (self.checkpointer is not None or over_budget) else 0
-            )
-            self.bytes_host += fwd_host_bytes
-            sp.end(
-                frontier=levels[k].n,
-                children=n,
-                bytes_sorted=level_sort_bytes,
-                bytes_hbm=level_sort_bytes,
-                bytes_host=fwd_host_bytes,
-                dispatches=self.dispatch_total - d0,
-            )
-            self.status_tracker.forward_level(k, levels[k].n, sp.secs)
-            flightrec.boundary("forward", k)
-            k += 1
-        return levels
-
     @staticmethod
     def _pad_dev(arr, cap: int, fill):
         """Pad a 1-D device array to `cap` with `fill` (no-op when already)."""
@@ -1612,9 +1171,7 @@ class Solver:
         """Analytic host-transfer bytes of one resolved fast-path level
         (the roofline span field): the state re-upload when the level
         was host-spilled, plus the table materialization download
-        (states + packed values/remoteness) when one will happen. ONE
-        formula for both fast backward variants — hand-synced copies
-        drift."""
+        (states + packed values/remoteness) when one will happen."""
         will_tbl = (
             self.store_tables or k == root_level
             or self.checkpointer is not None
@@ -1650,210 +1207,9 @@ class Solver:
                 common[k] = caps[k]
         return ks, caps, common
 
-    def _resolve_blocked_table(self, rec: _Level, states_dev, cells):
-        """Value-table resolve, in column blocks when the level is wide.
-
-        Same memory contract as _resolve_blocked; the cells buffer chains
-        through the blocks (each donation hands the table to the next).
-        """
-        cap = states_dev.shape[0]
-        block = self._block_size()
-        has_kids = rec.kids is not None and rec.prim is not None
-        if cap <= block:
-            if has_kids:
-                return self._bwdt(cap, True)(cells, states_dev, rec.prim,
-                                             rec.kids)
-            return self._bwdt(cap, False)(cells, states_dev)
-        M = self.game.max_moves
-        values, rems = [], []
-        misses = None
-        for off in range(0, cap, block):
-            note_dispatch("eager")
-            sd = jax.lax.slice(states_dev, (off,), (off + block,))
-            if has_kids:
-                pr = jax.lax.slice(rec.prim, (off,), (off + block,))
-                kd = jax.lax.slice(rec.kids, (off * M,),
-                                   ((off + block) * M,))
-                v, r, m, cells = self._bwdt(block, True)(cells, sd, pr, kd)
-            else:
-                v, r, m, cells = self._bwdt(block, False)(cells, sd)
-            values.append(v)
-            rems.append(r)
-            misses = m if misses is None else misses + m
-        note_dispatch("eager")
-        return jnp.concatenate(values), jnp.concatenate(rems), misses, cells
-
-    def _backward_fast_table(self, levels: Dict[int, _Level],
-                             root_level: int) -> Dict[int, LevelTable]:
-        """Fused value-table backward (GAMESMAN_FUSED=1, u32 games within
-        the GAMESMAN_FUSED_TABLE_BITS gate): ONE dispatch per level.
-
-        A persistent [2^state_bits] packed-cell table replaces the sliding
-        window entirely: level k's kernel gathers its children's cells
-        (every child lives in level k+1, scattered the step before),
-        combines, and scatters its own cells in — with the table DONATED
-        through every call, so the whole sweep ping-pongs between two
-        aliases of one allocation. No window slices, no pads, no search,
-        no re-expansion (stored kids), no per-level host sync.
-
-        Retry contract under fusion (docs/ARCHITECTURE.md): donation makes
-        a failed dispatch non-re-entrant (the consumed table cannot be
-        re-presented), so this path has NO per-level retry — a kernel
-        failure aborts the solve and recovery is the checkpoint prefix,
-        exactly the campaign-level story. The unfused path keeps its
-        per-level retry; flip GAMESMAN_FUSED=0 to trade throughput for it.
-        """
-        g = self.game
-        resolved: Dict[int, LevelTable] = {}
-        completed = (
-            set(self.checkpointer.completed_levels())
-            if self.checkpointer is not None
-            else set()
-        )
-        ks = sorted(levels, reverse=True)
-        block = self._block_size()
-        for k in ks:
-            if k in completed:
-                continue
-            rec = levels[k]
-            cap = bucket_size(rec.n, self.min_bucket)
-            self._sched_bwdt(min(cap, block),
-                             rec.kids is not None and rec.prim is not None)
-        T = 1 << g.state_bits
-        note_dispatch("table_init")
-        cells = jnp.zeros(T, dtype=jnp.uint32)
-        pending_fin = None
-        for k in ks:
-            sp = Span("backward", logger=self.logger, level=k)
-            d0 = self.dispatch_total
-            rec = levels[k]
-            n = rec.n
-            self.progress = {"phase": "backward", "level": k, "n": n}
-            preempt.check("backward", level=k, logger=self.logger)
-            memguard.check("backward", level=k, logger=self.logger)
-            uploaded = rec.dev is None
-            if rec.dev is not None:
-                states_dev = rec.dev
-            else:
-                note_dispatch("upload")
-                states_dev = jnp.asarray(
-                    pad_to(rec.host_states(),
-                           bucket_size(n, self.min_bucket))
-                )
-            cap = states_dev.shape[0]
-            from_checkpoint = k in completed
-            table = None
-            if from_checkpoint:
-                from gamesmanmpi_tpu.utils.checkpoint import TORN_NPZ_ERRORS
-
-                try:
-                    table = self.checkpointer.load_level(k)
-                except TORN_NPZ_ERRORS as e:
-                    self.checkpointer.quarantine_and_log(k, e, self.logger)
-                    from_checkpoint = False
-            if from_checkpoint:
-                states_host = rec.host_states()
-                if table.states.shape[0] != n or not (
-                    np.asarray(table.states, dtype=g.state_dtype)
-                    == states_host
-                ).all():
-                    raise SolverError(
-                        f"checkpointed level {k} does not match the "
-                        "discovered frontier — stale checkpoint directory?"
-                    )
-                note_dispatch("upload")
-                values_dev = jnp.asarray(pad_to_cap_u8(table.values, cap))
-                rem_dev = jnp.asarray(pad_to_cap_i32(table.remoteness, cap))
-                cells = self._bwdc(cap)(cells, states_dev, values_dev,
-                                        rem_dev)
-                misses = None
-            else:
-                faults.fire("engine.backward", level=k)
-                values_dev, rem_dev, misses, cells = \
-                    self._resolve_blocked_table(rec, states_dev, cells)
-                if self.paranoid and int(misses) > 0:
-                    raise SolverError(
-                        f"level {k}: {int(misses)} consistency failures "
-                        "(UNDECIDED child cells — table discipline — or "
-                        "non-primitive positions with zero legal moves)"
-                    )
-            lvl_gather_bytes = 0 if from_checkpoint \
-                else cap * g.max_moves * 8  # kid read (4 B) + cell (4 B)
-            self.bytes_gathered += lvl_gather_bytes
-
-            def _finalize(k=k, rec=rec, n=n, table=table,
-                          values_dev=values_dev, rem_dev=rem_dev,
-                          from_checkpoint=from_checkpoint):
-                tbl = table
-                if tbl is None and (
-                    self.store_tables
-                    or k == root_level
-                    or self.checkpointer is not None
-                    or self.level_sink is not None
-                ):
-                    note_dispatch("download")
-                    with trace_span("download", level=k) as dl:
-                        tbl = LevelTable(
-                            states=rec.host_states(),
-                            values=np.asarray(values_dev[:n]),
-                            remoteness=np.asarray(rem_dev[:n]),
-                        )
-                        dl.set(bytes=_table_bytes(tbl))
-                if tbl is not None and (self.store_tables
-                                        or k == root_level):
-                    resolved[k] = tbl
-                if self.level_sink is not None and tbl is not None:
-                    with trace_span("db_export", level=k, n=n):
-                        self.level_sink(k, tbl)
-                if self.checkpointer is not None and not from_checkpoint:
-                    with trace_span("checkpoint", level=k, kind="level"):
-                        self.checkpointer.save_level(k, tbl)
-                rec.dev = None
-                rec.prim = rec.uidx = rec.kids = None
-                if not self.store_tables:
-                    rec.host = None
-
-            if pending_fin is not None:
-                # The deferred host work runs with this level's kernel in
-                # flight — the pipeline's measured overlap.
-                t0f = time.perf_counter()
-                pending_fin()
-                self.overlap_secs += time.perf_counter() - t0f
-                pending_fin = None
-            if self.pipeline == "pingpong":
-                pending_fin = _finalize
-            else:
-                _finalize()
-            if not from_checkpoint and cap >= (1 << 21):
-                # Same enqueue-run-ahead bound as the unfused path: one
-                # 8-byte fetch per BIG level caps liveness.
-                with trace_span("drain", level=k):
-                    np.asarray(misses)
-            item = np.dtype(g.state_dtype).itemsize
-            lvl_host_bytes = self._level_host_bytes(
-                k, root_level, cap, n, item, uploaded, from_checkpoint
-            )
-            self.bytes_host += lvl_host_bytes
-            sp.end(
-                n=n,
-                resumed=from_checkpoint,
-                bytes_gathered=lvl_gather_bytes,
-                bytes_hbm=lvl_gather_bytes,
-                bytes_host=lvl_host_bytes,
-                dispatches=self.dispatch_total - d0,
-            )
-            self.status_tracker.backward_level(k, n, sp.secs,
-                                               resumed=from_checkpoint)
-            flightrec.boundary("backward", k)
-        if pending_fin is not None:
-            pending_fin()
-        return resolved
-
     def _backward_fast(self, levels: Dict[int, _Level],
                        root_level: int) -> Dict[int, LevelTable]:
         """Deepest-first resolve; the window is the previous (deeper) level."""
-        if self._fused_table:
-            return self._backward_fast_table(levels, root_level)
         g = self.game
         resolved: Dict[int, LevelTable] = {}
         completed = (
@@ -1880,7 +1236,6 @@ class Solver:
                 wcaps = (max(C, caps[k + 1]),) if k + 1 in levels else ()
                 self._sched_bwd(min(C, block), wcaps)
         prev = None  # (states_dev, values_dev, rem_dev) of level k+1, at its C
-        pending_fin = None  # pingpong: the deeper level's deferred host work
         for k in ks:
             sp = Span("backward", logger=self.logger, level=k)
             d0 = self.dispatch_total
@@ -1999,53 +1354,35 @@ class Solver:
                         "positions with zero legal moves)"
                     )
             prev = (states_dev, values_dev, rem_dev)
-
-            def _finalize(k=k, rec=rec, n=n, table=table,
-                          values_dev=values_dev, rem_dev=rem_dev,
-                          from_checkpoint=from_checkpoint):
-                # The level's host-side tail: table materialization (the
-                # downloads), export, checkpoint seal, buffer release. In
-                # pingpong mode this runs one level LATE — after the next
-                # (shallower) level's kernel is dispatched — so the
-                # downloads overlap device execution (overlap_secs).
-                tbl = table
-                if tbl is None and (
-                    self.store_tables
-                    or k == root_level
-                    or self.checkpointer is not None
-                    or self.level_sink is not None
-                ):
-                    note_dispatch("download")
-                    with trace_span("download", level=k) as dl:
-                        tbl = LevelTable(
-                            states=rec.host_states(),
-                            values=np.asarray(values_dev[:n]),
-                            remoteness=np.asarray(rem_dev[:n]),
-                        )
-                        dl.set(bytes=_table_bytes(tbl))
-                if tbl is not None and (self.store_tables
-                                        or k == root_level):
-                    resolved[k] = tbl
-                if self.level_sink is not None and tbl is not None:
-                    with trace_span("db_export", level=k, n=n):
-                        self.level_sink(k, tbl)
-                if self.checkpointer is not None and not from_checkpoint:
-                    with trace_span("checkpoint", level=k, kind="level"):
-                        self.checkpointer.save_level(k, tbl)
-                rec.dev = None  # release the forward copy
-                rec.prim = rec.uidx = rec.kids = None  # release provenance
-                if not self.store_tables:
-                    rec.host = None
-
-            if pending_fin is not None:
-                t0f = time.perf_counter()
-                pending_fin()
-                self.overlap_secs += time.perf_counter() - t0f
-                pending_fin = None
-            if self.pipeline == "pingpong":
-                pending_fin = _finalize
-            else:
-                _finalize()
+            # The level's host-side tail: table materialization (the
+            # downloads), export, checkpoint seal, buffer release.
+            tbl = table
+            if tbl is None and (
+                self.store_tables
+                or k == root_level
+                or self.checkpointer is not None
+                or self.level_sink is not None
+            ):
+                note_dispatch("download")
+                with trace_span("download", level=k) as dl:
+                    tbl = LevelTable(
+                        states=rec.host_states(),
+                        values=np.asarray(values_dev[:n]),
+                        remoteness=np.asarray(rem_dev[:n]),
+                    )
+                    dl.set(bytes=_table_bytes(tbl))
+            if tbl is not None and (self.store_tables or k == root_level):
+                resolved[k] = tbl
+            if self.level_sink is not None and tbl is not None:
+                with trace_span("db_export", level=k, n=n):
+                    self.level_sink(k, tbl)
+            if self.checkpointer is not None and not from_checkpoint:
+                with trace_span("checkpoint", level=k, kind="level"):
+                    self.checkpointer.save_level(k, tbl)
+            rec.dev = None  # release the forward copy
+            rec.prim = rec.uidx = None  # release provenance
+            if not self.store_tables:
+                rec.host = None
             if not from_checkpoint and C >= (1 << 21):
                 # Bound enqueue run-ahead: with no per-level downloads the
                 # host races through the whole backward, allocating every
@@ -2073,8 +1410,6 @@ class Solver:
             self.status_tracker.backward_level(k, n, sp.secs,
                                                resumed=from_checkpoint)
             flightrec.boundary("backward", k)
-        if pending_fin is not None:
-            pending_fin()
         return resolved
 
     # ---------------------------------------------------------- generic phase
@@ -2097,26 +1432,17 @@ class Solver:
             memguard.check("forward", level=k, logger=self.logger)
             padded = pad_to_bucket(frontier, self.min_bucket)
             note_dispatch("upload")
-            fwd_args = (jnp.asarray(padded),)
-            if self.use_fused:
-                # The megakernel takes the real row count so its callback
-                # dedup sorts only the real prefix.
-                fwd_args += (np.int32(frontier.shape[0]),)
+            states_dev = jnp.asarray(padded)
             uniq, levels, count = self._fwd_generic(padded.shape[0])(
-                *fwd_args
+                states_dev
             )
             # expand_core's dedup sort (+ compaction re-sort when the
-            # platform lowers compaction as a sort). The fused callback
-            # lowering sorts only the real children prefix — its operand
-            # accounting must match the kernel that ran.
+            # platform lowers compaction as a sort).
             item = np.dtype(g.state_dtype).itemsize
-            if self.use_fused and fused_dedup_method() == "callback":
-                lvl_sort_bytes = frontier.shape[0] * g.max_moves * item
-            else:
-                lvl_sort_bytes = (
-                    padded.shape[0] * g.max_moves
-                    * (item + compaction_sort_bytes(item))
-                )
+            lvl_sort_bytes = (
+                padded.shape[0] * g.max_moves
+                * (item + compaction_sort_bytes(item))
+            )
             self.bytes_sorted += lvl_sort_bytes
             # Generic-path dedup is two-stage: the kernel's sort-unique
             # (whose wait is the int(count) sync) plus the host-side
@@ -2131,10 +1457,10 @@ class Solver:
                     nn = int(c)
                     return nn, np.asarray(u[:nn]), np.asarray(lv[:nn])
 
-                def _redispatch(holder=holder, fwd_args=fwd_args,
+                def _redispatch(holder=holder, states_dev=states_dev,
                                 padded=padded):
                     holder[0] = self._fwd_generic(padded.shape[0])(
-                        *fwd_args
+                        states_dev
                     )
 
                 n, kids, kid_levels = self._retry(
@@ -2349,24 +1675,11 @@ class Solver:
     def _solve_impl(self) -> SolveResult:
         g = self.game
         t0 = time.perf_counter()
-        # ISSUE 14 gates, resolved at solve time like every env/platform-
-        # auto knob. The fused fast path always carries backward inputs
-        # forward: canonical children when the value table applies (u32
-        # within GAMESMAN_FUSED_TABLE_BITS), dedup provenance otherwise —
-        # so use_provenance is implied by the mode, not the platform.
-        self.use_fused = fused_enabled()
-        self.pipeline = pipeline_mode()
-        self._fused_table = (
-            self.use_fused and self.fast and use_value_table(g)
+        # Platform-auto knob, resolved here (not in __init__) so a
+        # force_platform between construction and solve() is honored.
+        self.use_provenance = platform_auto_bool(
+            "GAMESMAN_PROVENANCE", accel=True, cpu=False
         )
-        if self.use_fused:
-            self.use_provenance = self.fast and not self._fused_table
-        else:
-            # Platform-auto knob, resolved here (not in __init__) so a
-            # force_platform between construction and solve() is honored.
-            self.use_provenance = platform_auto_bool(
-                "GAMESMAN_PROVENANCE", accel=True, cpu=False
-            )
         if self.checkpointer is not None:
             self.checkpointer.bind_game(g.name)
         saved = (
@@ -2403,9 +1716,8 @@ class Solver:
                     for k, v in saved.items()
                 }
             else:
-                fwd = (self._forward_fast_fused if self.use_fused
-                       else self._forward_fast)
-                levels = fwd(init, start_level, resume=partial or None)
+                levels = self._forward_fast(init, start_level,
+                                            resume=partial or None)
                 if self.checkpointer is not None:
                     self.checkpointer.mark_frontiers_complete()
             t_forward = time.perf_counter() - t0
@@ -2465,18 +1777,12 @@ class Solver:
             # "Efficiency accounting" for how to read them.
             "bytes_sorted": self.bytes_sorted,
             "bytes_gathered": self.bytes_gathered,
-            # ISSUE 14 dispatch economy: total device computations/
-            # transfers this solve issued, per discovered level, plus the
-            # host seconds the pingpong pipeline overlapped with device
-            # execution. These are what a bench record cites to prove the
-            # fused path dispatches LESS, not just runs faster.
+            # Dispatch economy: total device computations/transfers this
+            # solve issued, and per discovered level.
             "dispatches_total": self.dispatch_total,
             "dispatches_per_level": round(
                 self.dispatch_total
                 / max(len(levels) if self.fast else len(pools), 1), 2),
-            "overlap_secs": round(self.overlap_secs, 3),
-            "fused": bool(self.use_fused),
-            "pipeline": self.pipeline,
             # ISSUE 15 roofline accounting: the per-solve rollup of the
             # per-level bytes_hbm/bytes_host/dispatches/wall span fields.
             "bytes_host": self.bytes_host,

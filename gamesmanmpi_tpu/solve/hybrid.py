@@ -84,7 +84,7 @@ def default_cutover(ncells: int) -> int:
 
 
 def build_extract_step(tables, level: int, cblock: int, rank_dtype,
-                       use_onehot: bool, canon_fn=None):
+                       canon_fn=None):
     """Level-B frontier extraction: (row, rank) reach cells -> packed states.
 
     Returned fn:
@@ -115,8 +115,7 @@ def build_extract_step(tables, level: int, cblock: int, rank_dtype,
         ranks = (rank0.astype(rank_dtype)
                  + jax.lax.iota(rank_dtype, cblock)[None, :])
         in_range = ranks < rank_dtype(C)
-        p1 = _unrank_bits(ranks, n1, binom, cellidx, bitpos, dt, rank_dtype,
-                          use_onehot)
+        p1 = _unrank_bits(ranks, n1, binom, cellidx, bitpos, dt, rank_dtype)
         current = p1 if current_is_p1 else filled[:, None] ^ p1
         packed = current | guards[:, None]
         if canon_fn is not None:
@@ -128,8 +127,7 @@ def build_extract_step(tables, level: int, cblock: int, rank_dtype,
 
 
 def build_boundary_step(tables, level: int, cblock: int, wcap: int,
-                        rank_dtype, use_onehot: bool, method: str,
-                        canon_fn=None):
+                        rank_dtype, method: str, canon_fn=None):
     """Dense resolve of cutover level K against the sparse level-B table.
 
     Identical to build_dense_step except the child value source: instead
@@ -165,7 +163,7 @@ def build_boundary_step(tables, level: int, cblock: int, wcap: int,
         p1 = _unrank_bits(
             (rank0.astype(rank_dtype)
              + jax.lax.iota(rank_dtype, cblock)[None, :]),
-            n1, binom, cellidx, bitpos, dt, rank_dtype, use_onehot,
+            n1, binom, cellidx, bitpos, dt, rank_dtype,
         )
         p2 = filled[:, None] ^ p1
         mover = p1 if mover_is_p1 else p2
@@ -208,8 +206,7 @@ def build_boundary_step(tables, level: int, cblock: int, wcap: int,
 
 
 def build_boundary_children_step(tables, level: int, cblock: int,
-                                 rank_dtype, use_onehot: bool,
-                                 canon_fn=None):
+                                 rank_dtype, canon_fn=None):
     """Streamed boundary, phase 1: one rank block's packed children.
 
     Returned fn:
@@ -233,7 +230,7 @@ def build_boundary_children_step(tables, level: int, cblock: int,
         p1 = _unrank_bits(
             (rank0.astype(rank_dtype)
              + jax.lax.iota(rank_dtype, cblock)[None, :]),
-            n1, binom, cellidx, bitpos, dt, rank_dtype, use_onehot,
+            n1, binom, cellidx, bitpos, dt, rank_dtype,
         )
         p2 = filled[:, None] ^ p1
         mover = p1 if mover_is_p1 else p2
@@ -481,7 +478,7 @@ class HybridSolver:
 
         def key(kind):
             return (kind, self.tables.width, self.tables.height,
-                    self.tables.connect, B, cblock, d.use_onehot)
+                    self.tables.connect, B, cblock)
 
         # Keyed on the SYM game (g.cache_key embeds the _sym name): the
         # canonicalizing and plain extract programs must never share a
@@ -490,7 +487,7 @@ class HybridSolver:
         step = get_kernel(
             g, "hyx", key("hyx"),
             lambda _g: build_extract_step(
-                t, B, cblock, d._rank_dtype, d.use_onehot, canon_fn=canon
+                t, B, cblock, d._rank_dtype, canon_fn=canon
             ),
         )
         pieces = []
@@ -571,8 +568,7 @@ class HybridSolver:
         w = t.width
 
         def kkey(kind, *extra):
-            return (kind, t.width, t.height, t.connect, K, cblock,
-                    d.use_onehot) + extra
+            return (kind, t.width, t.height, t.connect, K, cblock) + extra
 
         canon = g.canonicalize if g.sym else None
         table_bytes = wcap * (kstates.dtype.itemsize + 1)
@@ -580,8 +576,7 @@ class HybridSolver:
             step = get_kernel(
                 g, "hyb", kkey("hyb", wcap, sm),
                 lambda _g: build_boundary_step(
-                    t, K, cblock, wcap, d._rank_dtype, d.use_onehot, sm,
-                    canon_fn=canon,
+                    t, K, cblock, wcap, d._rank_dtype, sm, canon_fn=canon,
                 ),
             )
             ks_dev, kc_dev = jnp.asarray(kstates), jnp.asarray(kcells)
@@ -599,7 +594,7 @@ class HybridSolver:
         children_step = get_kernel(
             g, "hybc", kkey("hybc"),
             lambda _g: build_boundary_children_step(
-                t, K, cblock, d._rank_dtype, d.use_onehot, canon_fn=canon
+                t, K, cblock, d._rank_dtype, canon_fn=canon
             ),
         )
         acc_step = get_kernel(

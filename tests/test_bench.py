@@ -51,11 +51,10 @@ def _run_bench(extra_env, timeout=600):
     return json.loads(lines[0]), proc.stderr
 
 
-def test_bench_smoke_fused_contract():
-    """BENCH_SMOKE=1 (ISSUE 14): the fast contract check that pins the
-    new record fields so they can't rot — warmup excluded from the
-    variance block but published raw, dispatch economy present, and the
-    fused/unfused A/B with per-arm dispatches and byte-parity."""
+def test_bench_smoke_contract():
+    """BENCH_SMOKE=1: the fast contract check that pins the record
+    fields so they can't rot — warmup excluded from the variance block
+    but published raw, and dispatch economy present."""
     record, stderr = _run_bench({
         "BENCH_ENGINE": "classic",
         "BENCH_SMOKE": "1",
@@ -64,17 +63,9 @@ def test_bench_smoke_fused_contract():
     assert record["runs"]["n"] == 1
     assert len(record["runs"]["all_pps"]) == 1
     assert len(record["runs"]["warmup_pps"]) == 1
-    # Dispatch economy fields (the ISSUE 14 claim surface).
+    # Dispatch economy fields.
     assert record["dispatches"]["total"] > 0
     assert record["dispatches"]["per_level"] > 0
-    assert "overlap_secs" in record and "fused" in record
-    # Fused A/B: parity proven every round, per-arm dispatch counts.
-    ab = record["fused_ab"]
-    assert ab["parity_ok"] is True
-    assert ab["unfused"]["table_sha256"] == ab["fused"]["table_sha256"]
-    assert ab["fused"]["dispatches_per_level"] \
-        < ab["unfused"]["dispatches_per_level"]
-    assert ab["speedup"] > 0
     # Roofline fields: operand throughput and per-chip rate.
     rf = record["roofline"]
     assert set(rf) == {"operand_gbps", "pps_per_chip"}

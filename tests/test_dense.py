@@ -216,11 +216,11 @@ def test_device_rank_unrank_match_host_u64_board():
 
     bits = jax.jit(lambda r: _unrank_bits(
         r, n1_of_level(L), jnp.asarray(binom), jnp.asarray(cellidx),
-        [int(b) for b in t.bitpos], jnp.uint64, jnp.uint32, False,
+        [int(b) for b in t.bitpos], jnp.uint64, jnp.uint32,
     ))(jnp.asarray(ranks))
     back = jax.jit(lambda b: _rank_bits(
         b, jnp.asarray(binom), jnp.asarray(cellidx),
-        [int(b2) for b2 in t.bitpos], jnp.uint64, jnp.uint32, False,
+        [int(b2) for b2 in t.bitpos], jnp.uint64, jnp.uint32,
     ))(bits)
     bits_np = np.asarray(bits)
     back_np = np.asarray(back)
@@ -262,47 +262,19 @@ def test_dense_no_tables_mode():
         rd.lookup(int(g.initial_state()))
 
 
-@pytest.mark.slow  # ~47 s CPU: full-solve A/B of the fused rank lowering
-def test_dense_fused_rank_matches_simple(monkeypatch):
-    # GAMESMAN_DENSE_RANK=fused is a pure lowering change (one walk for
-    # all moves instead of per-move walks): every table cell must match.
-    g = get_game("connect4:w=3,h=3,connect=3")
-    simple = DenseSolver(g).solve()
-    monkeypatch.setenv("GAMESMAN_DENSE_RANK", "fused")
-    fused = DenseSolver(g).solve()
-    assert (fused.value, fused.remoteness) == (simple.value,
-                                              simple.remoteness)
-    for L, cells in simple.cells.items():
-        np.testing.assert_array_equal(fused.cells[L], cells)
-    # And on a rectangular 5-column board (p1/p2 parity + wider fan-out),
-    # level tables again identical.
-    g2 = get_game("connect4:w=5,h=2")
-    f2 = DenseSolver(g2).solve()
-    monkeypatch.delenv("GAMESMAN_DENSE_RANK")
-    s2 = DenseSolver(g2).solve()
-    assert (f2.value, f2.remoteness, f2.num_positions) == (
-        s2.value, s2.remoteness, s2.num_positions
-    )
-    for L, cells in s2.cells.items():
-        np.testing.assert_array_equal(f2.cells[L], cells)
-
-
 def test_dense_sorted_gather_matches_plain(monkeypatch):
     """The monotone (sorted) fill feeding the Pallas window gather
-    (interpret mode here) on 5x2: at default blocking, with tiny blocks
+    (interpret mode here) on 5x2: at default blocking and with tiny blocks
     (block_elems=64: kernel blocks span several profile rows and the tail
-    block's pad lanes), and fed by the fused rank. Every level table
-    matches the plain gather; the default blocking misses no lane."""
+    block's pad lanes). Every level table matches the plain gather; the
+    default blocking misses no lane."""
     g = get_game("connect4:w=5,h=2")
     plain = DenseSolver(g).solve()
     monkeypatch.setenv("GAMESMAN_DENSE_GATHER", "pallas")
     pal = DenseSolver(g).solve()
     blocked = DenseSolver(g, block_elems=64).solve()
-    monkeypatch.setenv("GAMESMAN_DENSE_RANK", "fused")
-    both = DenseSolver(g).solve()
     assert pal.stats["gather_miss_lanes"] == 0
-    assert both.stats["gather_miss_lanes"] == 0
-    for run in (pal, blocked, both):
+    for run in (pal, blocked):
         assert run.stats["gather_mode"] == "pallas"
         assert run.stats["gather_lanes"] == plain.stats["gather_lanes"]
         for L, cells in plain.cells.items():

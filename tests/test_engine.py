@@ -143,3 +143,109 @@ def test_platform_conditional_paths_parity(monkeypatch):
     r = Solver(get_game(g), paranoid=True).solve()
     assert (r.value, r.remoteness) == (base.value, base.remoteness)
     assert full_table(r) == base_tab
+
+
+def test_dispatch_counter_registry_series():
+    """gamesman_dispatches_total{phase} grows with a solve."""
+    from gamesmanmpi_tpu.obs import default_registry
+
+    reg = default_registry()
+    game = get_game("tictactoe")
+    before = reg.counter(
+        "gamesman_dispatches_total",
+        phase="forward", game=game.name,
+    ).value
+    Solver(game).solve()
+    after = reg.counter(
+        "gamesman_dispatches_total",
+        phase="forward", game=game.name,
+    ).value
+    assert after > before
+
+
+# The exact lowerings an accelerator resolves (platform_auto_flag/_bool):
+# provenance forward + gather-only backward, speculative dispatch, the
+# sort-merge join and the re-sort compaction.
+ACCEL_CONFIG = {
+    "GAMESMAN_PROVENANCE": "1",
+    "GAMESMAN_SPECULATE": "1",
+    "GAMESMAN_SEARCH": "sort",
+    "GAMESMAN_COMPACT": "resort",
+}
+
+needs_mesh = pytest.mark.skipif(
+    len(__import__("jax").devices()) < 8, reason="needs 8 (fake) devices"
+)
+
+
+def _accel_solver(kind, spec, tmp_path):
+    """-> (spec, thunk running the accelerator-configured solve)."""
+    import pathlib
+
+    from helpers import REPO
+
+    if kind == "gamedsl":
+        spec = str(pathlib.Path(REPO) / spec)
+    if kind == "sharded":
+        from gamesmanmpi_tpu.parallel import ShardedSolver
+
+        return spec, lambda: ShardedSolver(get_game(spec), num_shards=4,
+                                           paranoid=True).solve()
+    if kind == "resume":
+        from gamesmanmpi_tpu.utils.checkpoint import LevelCheckpointer
+
+        def run():
+            ck = str(tmp_path / "ck")
+            Solver(get_game(spec), paranoid=True,
+                   checkpointer=LevelCheckpointer(ck)).solve()
+            # Every level sealed: the second run must load, not resolve.
+            resumed = Solver(get_game(spec), paranoid=True,
+                             checkpointer=LevelCheckpointer(ck))
+            result = resumed.solve()
+            assert not {"fwdp", "bwdp", "bwd"} & set(resumed.dispatch_by_kind)
+            return result
+
+        return spec, run
+    return spec, lambda: Solver(get_game(spec), paranoid=True).solve()
+
+
+@pytest.mark.parametrize("kind,spec,backward", [
+    ("classic", "tictactoe", None),               # dihedral symmetry
+    ("classic", "connect4:w=4,h=4", None),
+    ("classic", "connect4:w=4,h=3,sym=1", None),  # mirror canonicalize
+    ("classic", "nim:heaps=3-4-5", None),         # generic path
+    ("classic", "chomp:w=3,h=3", None),           # generic, widest moves
+    ("gamedsl", "examples/specs/gomoku_4x3x3.json", None),
+    ("gamedsl", "examples/specs/mnk_3x3x3_misere.json", None),
+    ("gamedsl", "examples/specs/connect4_4x4.json", None),
+    pytest.param("sharded", "connect4:w=4,h=3", "lookup", marks=needs_mesh),
+    pytest.param("sharded", "connect4:w=4,h=3", "edges", marks=needs_mesh),
+    pytest.param("sharded", "nim:heaps=3-4-5", "lookup", marks=needs_mesh),
+    pytest.param("sharded", "nim:heaps=3-4-5", "edges", marks=needs_mesh),
+    ("resume", "connect4:w=4,h=3", None),         # checkpoint, then resume
+])
+def test_accelerator_configuration_parity(monkeypatch, tmp_path, kind,
+                                          spec, backward):
+    """Every engine path under the accelerator's lowerings, all at once,
+    against the CPU default solve's full table: the path the chip runs,
+    on every game family, the sharded engine in both backward modes and a
+    checkpoint resume."""
+    spec, run = _accel_solver(kind, spec, tmp_path)
+    base = Solver(get_game(spec), paranoid=True).solve()
+    for var, val in ACCEL_CONFIG.items():
+        monkeypatch.setenv(var, val)
+    if backward is not None:
+        monkeypatch.setenv("GAMESMAN_BACKWARD", backward)
+    r = run()
+    assert (r.value, r.remoteness) == (base.value, base.remoteness)
+    assert r.num_positions == base.num_positions
+    assert full_table(r) == full_table(base)
+
+
+def test_connect4_bitboard_solve_parity(monkeypatch):
+    """End-to-end: bitboard on/off produce identical full tables (the
+    flag is part of cache_key, so kernels cannot cross-contaminate)."""
+    base = Solver(get_game("connect4:w=4,h=3"), paranoid=True).solve()
+    monkeypatch.setenv("GAMESMAN_C4_BITBOARD", "0")
+    loop = Solver(get_game("connect4:w=4,h=3"), paranoid=True).solve()
+    assert full_table(loop) == full_table(base)

@@ -124,9 +124,6 @@ def test_spec_problem_catalogue():
     # GS101: does not fit uint64 packing
     assert ("GS101", "error") in codes(
         GameSpec(name="g", width=8, height=8, family="drop", k=4))
-    # GS102: fits, but outside the 26-bit fused value-table gate
-    assert ("GS102", "warning") in codes(
-        GameSpec(name="g", width=7, height=6, family="drop", k=4))
     # GS103: no direction fits a k-window
     assert ("GS103", "error") in codes(
         GameSpec(name="g", width=3, height=3, k=5))

@@ -5,6 +5,7 @@ expand/primitive must agree exactly with the scalar module of identical
 packing in examples/ref_games/.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -123,3 +124,32 @@ def test_mnk_444_forward_smoke():
     assert (levels == 1).all()
     prim = np.asarray(game.primitive(children[0]))
     assert (prim == 0).all()  # no opening move ends the game
+
+
+@pytest.mark.parametrize("wh", [(4, 4), (5, 4), (7, 6)])
+def test_connect4_bitboard_decompose_parity(wh):
+    """The whole-word masked-smear decompose must be bit-identical to the
+    per-column msb loop on every REACHABLE state shape (random playouts;
+    garbage lanes are out of contract — the engines mask them)."""
+    w, h = wh
+    game = get_game(f"connect4:w={w},h={h}")
+    rng = np.random.default_rng(3)
+    states = [int(game.initial_state())]
+    frontier = [int(game.initial_state())]
+    for _ in range(min(w * h, 12)):
+        batch = np.asarray(frontier, dtype=game.state_dtype)
+        kids, mask = jax.jit(game.expand)(jnp.asarray(batch))
+        kids, mask = np.asarray(kids), np.asarray(mask)
+        nxt = list(np.unique(kids[mask]))
+        if not nxt:
+            break
+        rng.shuffle(nxt)
+        frontier = nxt[:256]
+        states.extend(frontier)
+    batch = jnp.asarray(np.asarray(states, dtype=game.state_dtype))
+    fast = jax.jit(game._decompose)(batch)
+    ref = jax.jit(game._decompose_loop)(batch)
+    for a, b, name in zip(fast, ref,
+                          ("guards", "filled", "current", "opponent")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)

@@ -220,3 +220,102 @@ def test_expand_provenance_contract():
                 assert m[slot]
                 assert 0 <= ui[slot] < n
                 assert uq[ui[slot]] == flat[slot]
+
+
+# ------------------------------------------- platform lowerings vs NumPy
+#
+# The dedup and lookup kernels carry two lowerings each, picked from the
+# platform: compaction "resort" (accelerators) or "scatter" (CPU), search
+# "sort" (accelerators) or "scan" (CPU). The CPU suite otherwise runs only
+# the CPU side, so each case below passes both explicitly and checks them
+# against NumPy, over lengths that are not powers of two and both state
+# widths, with sentinel-masked lanes mixed in.
+
+GRID_N = [1, 2, 7, 128, 1000, 4096, 10_000]
+GRID_DTYPES = [np.uint32, np.uint64]
+
+
+def _masked_states(n, dtype, seed):
+    """[n] states with duplicates and ~15% sentinel lanes."""
+    rng = np.random.default_rng(seed)
+    x = rng.integers(0, max(n // 2, 1), size=n).astype(dtype)
+    x[rng.random(n) < 0.15] = np.iinfo(dtype).max
+    return x
+
+
+@pytest.mark.parametrize("n", GRID_N)
+@pytest.mark.parametrize("dtype", GRID_DTYPES)
+def test_sort_unique_matches_numpy(n, dtype):
+    import jax
+
+    x = _masked_states(n, dtype, seed=n)
+    sentinel = np.iinfo(dtype).max
+    want = np.unique(x[x != sentinel])
+    for compact in ("scatter", "resort"):
+        s, count = jax.jit(lambda v: sort_unique(v, compact))(jnp.asarray(x))
+        s = np.asarray(s)
+        assert int(count) == want.shape[0], compact
+        np.testing.assert_array_equal(s[: want.shape[0]], want,
+                                      err_msg=compact)
+        assert (s[want.shape[0]:] == sentinel).all(), compact
+
+
+@pytest.mark.parametrize("n", GRID_N)
+@pytest.mark.parametrize("dtype", GRID_DTYPES)
+def test_dedup_provenance_matches_numpy(n, dtype):
+    import jax
+
+    from gamesmanmpi_tpu.ops import dedup_provenance
+
+    x = _masked_states(n, dtype, seed=n + 1)
+    sentinel = np.iinfo(dtype).max
+    real = x != sentinel
+    want, inverse = np.unique(x[real], return_inverse=True)
+    want_uidx = np.full(n, -1, np.int32)
+    want_uidx[real] = inverse
+    for compact in ("scatter", "resort"):
+        uniq, count, uidx = jax.jit(
+            lambda v: dedup_provenance(v, compact)
+        )(jnp.asarray(x))
+        uniq = np.asarray(uniq)
+        assert int(count) == want.shape[0], compact
+        np.testing.assert_array_equal(uniq[: want.shape[0]], want,
+                                      err_msg=compact)
+        assert (uniq[want.shape[0]:] == sentinel).all(), compact
+        np.testing.assert_array_equal(np.asarray(uidx), want_uidx,
+                                      err_msg=compact)
+
+
+@pytest.mark.parametrize("n", GRID_N)
+@pytest.mark.parametrize("dtype", GRID_DTYPES)
+def test_lookup_sorted_matches_numpy(n, dtype):
+    import jax
+
+    rng = np.random.default_rng(n + 2)
+    sentinel = np.iinfo(dtype).max
+    # A solved level: sorted distinct states (even numbers), sentinel tail.
+    real = np.unique(rng.integers(0, 4 * n, size=n).astype(dtype) * 2)
+    m = real.shape[0]
+    states = np.full(n, sentinel, dtype)
+    states[:m] = real
+    values = np.full(n, UNDECIDED, np.uint8)
+    values[:m] = rng.choice([WIN, LOSE, TIE], size=m)
+    rems = np.zeros(n, np.int32)
+    rems[:m] = rng.integers(0, 200, size=m)
+    # Queries: hits, misses (odd numbers are never stored) and sentinels.
+    keys = np.where(rng.random(n) < 0.5, real[rng.integers(0, m, size=n)],
+                    rng.integers(0, 8 * n, size=n).astype(dtype) | 1)
+    keys = keys.astype(dtype)
+    keys[rng.random(n) < 0.15] = sentinel
+    idx = np.clip(np.searchsorted(real, keys), 0, m - 1)
+    hit = (real[idx] == keys) & (keys != sentinel)
+    want_v = np.where(hit, values[idx], UNDECIDED)
+    want_r = np.where(hit, rems[idx], 0)
+    table = tuple(jnp.asarray(a) for a in (states, values, rems))
+    for method in ("scan", "sort"):
+        v, r, h = jax.jit(
+            lambda k, *t: lookup_sorted(k, *t, method=method)
+        )(jnp.asarray(keys), *table)
+        np.testing.assert_array_equal(np.asarray(h), hit, err_msg=method)
+        np.testing.assert_array_equal(np.asarray(v), want_v, err_msg=method)
+        np.testing.assert_array_equal(np.asarray(r), want_r, err_msg=method)

@@ -21,7 +21,6 @@ from gamesmanmpi_tpu.games import get_game
 _ACCEL_KNOBS = {
     "GAMESMAN_COMPACT": "resort",
     "GAMESMAN_SEARCH": "sort",
-    "GAMESMAN_FUSED_DEDUP": "scatterinv",
 }
 
 
@@ -86,14 +85,6 @@ def test_classic_expand_compiles(spec, dtype, one_chip, accel_knobs):
     assert compiled.memory_analysis().temp_size_in_bytes > 0
 
 
-def test_fused_dedup_scatterinv_compiles(one_chip):
-    from gamesmanmpi_tpu.ops.fused import fused_dedup_provenance
-
-    flat = jax.ShapeDtypeStruct((8192,), jnp.uint32, sharding=one_chip)
-    jax.jit(lambda x: fused_dedup_provenance(x, method="scatterinv")) \
-        .lower(flat).compile()
-
-
 def _compile_dense_step(level, gather_mode, one_chip, monkeypatch):
     from gamesmanmpi_tpu.solve.dense import DenseSolver, build_dense_step
 
@@ -104,8 +95,7 @@ def _compile_dense_step(level, gather_mode, one_chip, monkeypatch):
     # backend is the CPU, as it is here: build the step as the TPU would.
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     fn = build_dense_step(ds.tables, level, cblock, ds._rank_dtype,
-                          ds._flat_dtype, ds.use_onehot,
-                          fused_rank=ds.use_fused, gather_mode=gather_mode)
+                          ds._flat_dtype, gather_mode=gather_mode)
     avals = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
              for a in ds._avals(level, cblock, for_reach=False)]
     return jax.jit(fn).lower(*avals).compile()

@@ -164,21 +164,7 @@ def main():
     timeit(f"jnp.sort same total [{N>>20}M]", jnp.sort, keys,
            bytes_moved=2 * 4 * N)
 
-    # 6b. the shipped merge-ladder sort (ops/mergesort.py) vs XLA's sort —
-    # the GAMESMAN_SORT=merge decision is this pair of lines.
-    from gamesmanmpi_tpu.ops.mergesort import merge_sort
-
-    for row in (2048, 16 * 1024, 128 * 1024):
-        os.environ["GAMESMAN_SORT_ROW"] = str(row)
-        timeit(f"merge_sort u32 [{N>>20}M] row={row>>10}K", merge_sort,
-               keys, bytes_moved=2 * 4 * N)
-    os.environ.pop("GAMESMAN_SORT_ROW", None)
-    origin_i32 = jnp.arange(N, dtype=jnp.int32)
-    timeit(f"merge_sort u32+payload [{N>>20}M]",
-           lambda k, o: merge_sort(k, o), keys, origin_i32,
-           bytes_moved=2 * 8 * N)
-
-    # 6c. searchsorted on u64 (the hybrid boundary join's primitive: child
+    # 6b. searchsorted on u64 (the hybrid boundary join's primitive: child
     # packed states searched in the sorted sparse level-B table — the
     # GAMESMAN_SEARCH decision at the join's scale, and the per-element
     # cost the CHIP_PLAN §2b cutover arithmetic needs). Queries half-hit.

@@ -5,8 +5,8 @@
 
 With no arguments, lints every committed spec under examples/specs/.
 Runs gamedsl's static validation (gamesmanmpi_tpu/gamedsl/spec.py) —
-schema strictness, board-vs-encoding bit budgets (the 63-bit packing
-limit and the 26-bit fused value-table `_bwdt` gate), unreachable or
+schema strictness, the board-vs-encoding bit budget (the 63-bit packing
+limit), unreachable or
 dead win predicates, symmetry generators incompatible with the move
 family, and symmetry-closure preservation of the win-line set — without
 tracing a kernel or touching an accelerator.
