@@ -11,7 +11,10 @@ from gamesmanmpi_tpu.ops.padding import bucket_size, pad_to_bucket
 from gamesmanmpi_tpu.ops.dedup import sort_unique
 from gamesmanmpi_tpu.ops.lookup import lookup_sorted, lookup_window
 from gamesmanmpi_tpu.ops.combine import combine_children
-from gamesmanmpi_tpu.ops.provenance import dedup_provenance, gather_cells
+from gamesmanmpi_tpu.ops.provenance import (
+    dedup_provenance,
+    dedup_provenance_sorted,
+)
 
 __all__ = [
     "bucket_size",
@@ -21,5 +24,5 @@ __all__ = [
     "lookup_window",
     "combine_children",
     "dedup_provenance",
-    "gather_cells",
+    "dedup_provenance_sorted",
 ]

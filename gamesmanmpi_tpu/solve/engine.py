@@ -77,7 +77,12 @@ from gamesmanmpi_tpu.ops.dedup import (
     sort_unique,
 )
 from gamesmanmpi_tpu.ops.lookup import lookup_window, search_method
-from gamesmanmpi_tpu.ops.provenance import dedup_provenance, gather_cells
+from gamesmanmpi_tpu.ops.provenance import (
+    PERM_PAD,
+    dedup_provenance_sorted,
+    fetch_method,
+    sorted_child_cells,
+)
 from gamesmanmpi_tpu.ops.padding import MIN_BUCKET, bucket_size, pad_to, pad_to_bucket
 from gamesmanmpi_tpu.obs import (
     Heartbeat,
@@ -432,54 +437,74 @@ def expand_core(game: TensorGame, states, compact: str | None = None):
 def expand_provenance(game: TensorGame, states, compact: str | None = None):
     """Forward expand that also keeps the dedup sort's provenance.
 
-    Returns (uniq [B*M], count, uidx [B*M] int32, prim [B] uint8):
-    uidx[b*M + m] is the index of child (b, m) within the `uniq` prefix
-    (-1 for padding/invalid children), and prim is primitive(states).
+    Returns (uniq [B*M], count, perm [B*M] int32, nreal int32, prim [B]
+    uint8), perm and nreal as ops/provenance.dedup_provenance_sorted gives
+    them: in the dedup sort's order, each slot's origin child slot b*M + m,
+    with bit 31 set where it opens a run of equal children, the nreal real
+    (non-sentinel) children first. prim is primitive(states).
 
     Rationale: the forward dedup sort already determines where every child
     lands in the next level's sorted table. Carrying the origin slot through
-    the sort (one extra operand) and routing the run-index back (one pair
-    sort) preserves that knowledge, so the backward pass needs NO search and
-    NO re-expansion — child values become a single gather (see
-    resolve_provenance). Costs one extra pair sort in forward; saves the
-    sort-merge join (the backward pass's dominant cost) per level. The
-    pair-sort core is shared with the sharded engine's edge-cached backward
+    the sort (one extra operand) preserves that knowledge, so the backward
+    pass needs NO search and NO re-expansion: a monotone fetch of the deeper
+    level's cells in sorted order, one pair sort back to parent order and
+    the combine (see resolve_provenance). It saves the sort-merge join (the
+    backward pass's dominant cost) per level, for one operand more in the
+    forward sort. The first pair sort is shared with the sharded engine's
+    edge-cached forward, which keeps its provenance in routed order instead
     (ops/provenance.dedup_provenance).
     """
     prim = game.primitive(states)
     active = (states != game.sentinel) & (prim == UNDECIDED)
     children, _ = canonical_children(game, states, active)
-    uniq, count, uidx = dedup_provenance(children.reshape(-1), compact)
-    return uniq, count, uidx, prim
+    uniq, count, perm, nreal = dedup_provenance_sorted(children.reshape(-1),
+                                                       compact)
+    return uniq, count, perm, nreal, prim
 
 
-@jax.named_scope("gather")
-def resolve_provenance(n, prim, uidx, wvals, wrem, max_moves: int):
-    """Backward resolve from stored provenance: gathers + combine only.
+@jax.named_scope("combine")
+def resolve_cells(n, prim, cells):
+    """Combine child cells, moves major, into a level's results.
 
     n: scalar int32 — number of real rows (real states are a prefix of the
     capacity, a dedup-compaction invariant). prim: [C] uint8 (from forward).
-    uidx: [C*M] int32 child indices into the deeper level's prefix (-1 =
-    no child). wvals/wrem: deeper level's solved values/remoteness [W].
+    cells: [M, C] uint32 packed child cells, cells[m, b] for parent b's
+    move m, cell 0 where there is no child (a real child's value is always
+    decided). -> (values, rem, misses).
 
-    Lookup misses are structurally impossible here (the indices were
-    derived from the very sort that built the deeper level), so the
-    consistency counter only tracks non-primitive zero-move rows.
+    Lookup misses are structurally impossible here (the cells were fetched
+    through the very sort that built the deeper level), so the consistency
+    counter only tracks non-primitive zero-move rows.
     """
     C = prim.shape[0]
     valid = jax.lax.iota(jnp.int32, C) < n
     undecided = valid & (prim == UNDECIDED)
-    m = uidx.reshape(C, max_moves)
-    mask = (m >= 0) & undecided[:, None]
-    cv, cr = unpack_cells(gather_cells(m, wvals, wrem))
-    values, remoteness = combine_children(cv, cr, mask)
+    cv, cr = unpack_cells(cells)
+    mask = (cv != UNDECIDED) & undecided[None, :]
+    values, remoteness = combine_children(cv.T, cr.T, mask.T)
     values = jnp.where(
         undecided, values,
         jnp.where(valid, prim, jnp.uint8(UNDECIDED)),
     )
     remoteness = jnp.where(undecided, remoteness, 0)
-    misses = jnp.sum(undecided & ~jnp.any(mask, axis=-1))
+    misses = jnp.sum(undecided & ~jnp.any(mask, axis=0))
     return values, remoteness, misses
+
+
+def resolve_provenance(n, prim, perm, nreal, wvals, wrem, max_moves: int,
+                       method: str = "plain"):
+    """Backward resolve from stored provenance: fetch, un-permute, combine.
+
+    perm/nreal: the level's expand_provenance outputs, [C*M] and scalar.
+    wvals/wrem: the deeper level's solved values/remoteness [W]. method:
+    the fetch's lowering (ops/provenance.fetch_method). -> (values, rem,
+    misses, the fetch's missed lanes); see sorted_child_cells and
+    resolve_cells.
+    """
+    cells, missed = sorted_child_cells(perm, nreal, wvals, wrem, max_moves,
+                                       method)
+    values, remoteness, misses = resolve_cells(n, prim, cells)
+    return values, remoteness, misses, missed
 
 
 @jax.named_scope("expand")
@@ -559,21 +584,22 @@ def _device_store_bytes() -> int:
 class _Level:
     """One discovered level: host states + optionally the device copy.
 
-    prim/uidx are the forward pass's provenance (expand_provenance): this
-    level's primitive values and its out-edge indices into the NEXT level's
-    prefix. Device-only, kept while the store budget allows; when absent the
-    backward pass falls back to the sort-merge join.
+    prim/perm/nreal are the forward pass's provenance (expand_provenance):
+    this level's primitive values, and its out-edges in the order of the
+    dedup sort that built the NEXT level. Device-only, kept while the store
+    budget allows; when absent the backward pass falls back to the
+    sort-merge join.
     """
 
-    __slots__ = ("n", "host", "dev", "prim", "uidx")
+    __slots__ = ("n", "host", "dev", "prim", "perm", "nreal")
 
-    def __init__(self, n: int, host: Optional[np.ndarray], dev,
-                 prim=None, uidx=None):
+    def __init__(self, n: int, host: Optional[np.ndarray], dev):
         self.n = n  # real (non-sentinel) count
         self.host = host  # np [n] sorted, or None if only on device
         self.dev = dev  # jnp [cap] sorted + sentinel tail, or None
-        self.prim = prim  # jnp [cap] uint8, or None
-        self.uidx = uidx  # jnp [cap*M] int32, or None
+        self.prim = None  # jnp [cap] uint8, or None
+        self.perm = None  # jnp [cap*M] int32, or None
+        self.nreal = None  # jnp int32 scalar, or None
 
     def host_states(self) -> np.ndarray:
         if self.host is None:
@@ -638,6 +664,10 @@ class Solver:
         # convention docs/ARCHITECTURE.md states.
         self.bytes_sorted = 0
         self.bytes_gathered = 0
+        #: Lanes of the provenance backward's sorted-order fetch, and its
+        #: per-level missed lanes (device scalars, summed once at the end).
+        self.gather_lanes = 0
+        self._gather_miss: list = []
         # Background compiles only pay off where compiles are expensive
         # (remote accelerator); on CPU they would just slow the test suite.
         flag = env_str("GAMESMAN_PRECOMPILE", "auto")
@@ -646,14 +676,14 @@ class Solver:
         else:
             self.precompile = flag not in ("0", "off", "false")
         self._cap_ceiling = self._cap_limit() if self.precompile else 0
-        # Provenance forward (expand_provenance: two pair sorts + a re-sort)
-        # trades forward sort work for a gather-only backward — a clear win
+        # Provenance forward (expand_provenance: a pair sort + a re-sort)
+        # trades forward sort work for a search-free backward — a clear win
         # on the TPU, where the backward's sort-merge join was the dominant
-        # cost. On CPU
-        # the same trade REGRESSED the solve ~5x (BENCH_r01 813k vs
-        # BENCH_r03 150k pos/s on 5x4): forward sort work tripled while the
-        # backward it saves was already cheap. Keyed on the platform that
-        # will execute, not on an env var benches could forget
+        # cost. On CPU the same trade REGRESSED the solve ~5x (BENCH_r01
+        # 813k vs BENCH_r03 150k pos/s on 5x4, with the forward's sort work
+        # then tripled) while the backward it saves was already cheap.
+        # Keyed on the platform that will execute, not on an env var
+        # benches could forget
         # (GAMESMAN_PROVENANCE=0/1 remains as a test/experiment override).
         # RESOLVED AT SOLVE TIME, like every other platform-auto knob: a
         # force_platform between construction and solve() must re-resolve
@@ -729,9 +759,22 @@ class Solver:
     @staticmethod
     def _bwdp_builder(game):
         M = game.max_moves
-        return lambda n, prim, uidx, wvals, wrem: resolve_provenance(
-            n, prim, uidx, wvals, wrem, M
+        fm = fetch_method()  # resolved at cache-key time
+        return lambda n, prim, perm, nreal, wvals, wrem: resolve_provenance(
+            n, prim, perm, nreal, wvals, wrem, M, fm
         )
+
+    @staticmethod
+    def _bwdg_builder(game):
+        M = game.max_moves
+        fm = fetch_method()
+        return lambda perm, nreal, wvals, wrem: sorted_child_cells(
+            perm, nreal, wvals, wrem, M, fm
+        )
+
+    @staticmethod
+    def _bwdc_builder(game):
+        return resolve_cells
 
     @staticmethod
     def _fwdf_builder(game):
@@ -744,7 +787,8 @@ class Solver:
         return (compact_method(),)
 
     def _fwdp(self, cap: int):
-        """Provenance forward: states[cap] -> (uniq, count, uidx, prim)."""
+        """Provenance forward: states[cap] -> (uniq, count, perm, nreal,
+        prim)."""
         return get_kernel(self.game, "fwdp", cap, self._fwdp_builder,
                           lowering=self._fwd_lowering())
 
@@ -754,10 +798,23 @@ class Solver:
         return get_kernel(self.game, "fwdf", cap, self._fwdf_builder,
                           lowering=self._fwd_lowering())
 
-    def _bwdp(self, cap: int, wcap: int):
-        """Provenance backward: (n, prim[cap], uidx[cap*M], wvals[wcap],
-        wrem[wcap]) -> (values, rem, misses)."""
-        return get_kernel(self.game, "bwdp", (cap, wcap), self._bwdp_builder)
+    def _bwdp(self, cap: int):
+        """Provenance backward of a whole level: (n, prim[cap],
+        perm[cap*M], nreal, wvals[cap], wrem[cap]) -> (values, rem, misses,
+        missed fetch lanes)."""
+        return get_kernel(self.game, "bwdp", cap, self._bwdp_builder,
+                          lowering=(fetch_method(),))
+
+    def _bwdg(self, cap: int):
+        """A wide level's child-cell fetch: (perm[cap*M], nreal,
+        wvals[cap], wrem[cap]) -> (cells[M, cap], missed fetch lanes)."""
+        return get_kernel(self.game, "bwdg", cap, self._bwdg_builder,
+                          lowering=(fetch_method(),))
+
+    def _bwdc(self, block: int):
+        """A block of a wide level's combine: (n, prim[block],
+        cells[M, block]) -> (values, rem, misses)."""
+        return get_kernel(self.game, "bwdc", block, self._bwdc_builder)
 
     def _fwd_generic(self, cap: int):
         def build(game):
@@ -840,27 +897,44 @@ class Solver:
         else:
             self._sched_fwdf(cap)
 
-    def _sched_bwd_step(self, cap: int, wcap: int) -> None:
-        """Schedule whichever backward kernel this solver will request."""
+    def _sched_bwd_step(self, cap: int) -> None:
+        """Schedule whichever backward kernels this solver will request
+        for a level of common capacity `cap` (see _backward_plan)."""
         if self.use_provenance:
-            self._sched_bwdp(cap, wcap)
+            self._sched_bwdp(cap)
         else:
-            self._sched_bwd(cap, (wcap,))
+            self._sched_bwd(min(cap, self._block_size()), (cap,))
 
-    def _sched_bwdp(self, cap: int, wcap: int) -> None:
+    def _sched_bwdp(self, cap: int) -> None:
+        """The provenance backward's kernels at common capacity `cap`: one
+        bwdp, or for a level wider than the block the bwdg fetch and the
+        bwdc combine (see _resolve_blocked_prov)."""
         if cap > self._cap_ceiling:
             return
-        M = self.game.max_moves
-        avals = (
+        g = self.game
+        M = g.max_moves
+        lowering = (fetch_method(),)
+        fetch_avals = (
+            sds((cap * M,), np.int32),
             sds((), np.int32),
             sds((cap,), np.uint8),
-            sds((cap * M,), np.int32),
-            sds((wcap,), np.uint8),
-            sds((wcap,), np.int32),
+            sds((cap,), np.int32),
         )
+        block = self._block_size()
+        if cap <= block:
+            schedule_kernel(
+                g, "bwdp", cap, self._bwdp_builder,
+                (sds((), np.int32), sds((cap,), np.uint8)) + fetch_avals,
+                heavy=self._heavy(cap), lowering=lowering,
+            )
+            return
+        schedule_kernel(g, "bwdg", cap, self._bwdg_builder, fetch_avals,
+                        heavy=self._heavy(cap), lowering=lowering)
         schedule_kernel(
-            self.game, "bwdp", (cap, wcap), self._bwdp_builder, avals,
-            heavy=self._heavy(max(cap, wcap)),
+            g, "bwdc", block, self._bwdc_builder,
+            (sds((), np.int32), sds((block,), np.uint8),
+             sds((M, block), np.uint32)),
+            heavy=self._heavy(block),
         )
 
     def _schedule_initial_ladder(self) -> None:
@@ -875,7 +949,7 @@ class Solver:
             if cap > self._cap_ceiling:
                 break
             self._sched_fwd_step(cap)
-            self._sched_bwd_step(min(cap, self._block_size()), cap)
+            self._sched_bwd_step(cap)
             cap *= 2
 
     def _block_size(self) -> int:
@@ -886,33 +960,35 @@ class Solver:
         keys must agree."""
         return 1 << max(self.backward_block, 1).bit_length() - 1
 
-    def _resolve_blocked_prov(self, n: int, prim, uidx, wvals, wrem):
-        """Provenance resolve, in column blocks when the level is wide.
+    def _resolve_blocked_prov(self, n: int, prim, perm, nreal, wvals,
+                              wrem):
+        """Provenance resolve, its combine in column blocks when the level
+        is wide. -> (values, rem, misses, missed fetch lanes).
 
-        Same blocking contract as _resolve_blocked: per-block temporaries
-        bounded by the block; the window (wvals/wrem) is shared by every
+        The fetch and its un-permute sort run once over the whole level
+        (bwdg): the sorted order spans every parent. The combine keeps
+        _resolve_blocked's contract: per-block temporaries bounded by the
         block; results concatenate on device; misses accumulate on device.
         """
         C = prim.shape[0]
         M = self.game.max_moves
         block = self._block_size()
         if C <= block:
-            return self._bwdp(C, C)(np.int32(n), prim, uidx, wvals, wrem)
+            return self._bwdp(C)(np.int32(n), prim, perm, nreal, wvals, wrem)
+        cells, missed = self._bwdg(C)(perm, nreal, wvals, wrem)
         values, rems = [], []
         misses = None
         for off in range(0, C, block):
             nb = np.int32(min(max(n - off, 0), block))
-            v, r, m = self._bwdp(block, C)(
+            v, r, m = self._bwdc(block)(
                 nb,
                 jax.lax.slice(prim, (off,), (off + block,)),
-                jax.lax.slice(uidx, (off * M,), ((off + block) * M,)),
-                wvals,
-                wrem,
+                jax.lax.slice(cells, (0, off), (M, off + block)),
             )
             values.append(v)
             rems.append(r)
             misses = m if misses is None else misses + m
-        return jnp.concatenate(values), jnp.concatenate(rems), misses
+        return jnp.concatenate(values), jnp.concatenate(rems), misses, missed
 
     def _resolve_blocked(self, states_dev, wcaps: tuple, window_args: tuple):
         """Backward-resolve a level, in column blocks when it is wide.
@@ -948,9 +1024,9 @@ class Solver:
 
         Two latency hiders on top of the level loop:
 
-        * the expand kernel is expand_provenance — its uidx/prim outputs are
-          stored (budget permitting) so the backward pass becomes pure
-          gathers (see resolve_provenance);
+        * the expand kernel is expand_provenance — its perm/nreal/prim
+          outputs are stored (budget permitting) so the backward pass needs
+          no search (see resolve_provenance);
         * the next level's expand is dispatched SPECULATIVELY at the current
           capacity before the unique-count host sync;
           most levels keep their bucket, so the device computes through the
@@ -1005,11 +1081,11 @@ class Solver:
 
         def fwd_step(arr):
             """Dispatch the platform-selected forward kernel; normalize to
-            (uniq, count, uidx|None, prim|None)."""
+            (uniq, count, perm|None, nreal|None, prim|None)."""
             if self.use_provenance:
                 return self._fwdp(arr.shape[0])(arr)
             u, c = self._fwdf(arr.shape[0])(arr)
-            return u, c, None, None
+            return u, c, None, None, None
 
         pending = fwd_step(frontier)
         while True:
@@ -1050,14 +1126,14 @@ class Solver:
             if holder[0] is not pending:
                 pending = holder[0]
                 spec = spec_input = None  # speculation predates the retry
-            uniq, count, uidx, prim = pending
+            uniq, count, perm, nreal, prim = pending
             rec = levels[k]
-            if uidx is not None:
-                extra = prim.nbytes + uidx.nbytes
+            if perm is not None:
+                extra = prim.nbytes + perm.nbytes
                 if n > 0 and stored_bytes + extra <= self.device_store_bytes:
-                    # Keep this level's provenance for the gather-only
+                    # Keep this level's provenance for the search-free
                     # backward.
-                    rec.prim, rec.uidx = prim, uidx
+                    rec.prim, rec.perm, rec.nreal = prim, perm, nreal
                     stored_bytes += extra
             if n == 0:
                 # Terminal probe: the span's trace event is kept (its
@@ -1085,7 +1161,7 @@ class Solver:
                 # the backward pass will actually request.
                 for ahead in (next_cap * 2, next_cap * 4):
                     self._sched_fwd_step(ahead)
-                    self._sched_bwd_step(min(ahead, self._block_size()), ahead)
+                    self._sched_bwd_step(ahead)
             if next_cap == cap and spec is not None:
                 nxt = spec_input
                 pending = spec
@@ -1128,9 +1204,9 @@ class Solver:
             # must match the kernel the platform lowered).
             compaction = compaction_sort_bytes(item)
             if self.use_provenance:
-                # expand_provenance: (child, origin i32) pair sort +
-                # (origin, uid) i32 pair sort + the compaction.
-                level_sort_bytes = cap * g.max_moves * (item + 12 + compaction)
+                # expand_provenance: (child, origin i32) pair sort + the
+                # compaction (the un-permute sort is the backward's).
+                level_sort_bytes = cap * g.max_moves * (item + 4 + compaction)
             else:
                 # expand_core: one dedup sort + the compaction.
                 level_sort_bytes = cap * g.max_moves * (item + compaction)
@@ -1227,8 +1303,8 @@ class Solver:
                 continue
             C = common[k]
             rec = levels[k]
-            if k + 1 in levels and rec.uidx is not None:
-                self._sched_bwdp(min(C, block), C)
+            if k + 1 in levels and rec.perm is not None:
+                self._sched_bwdp(C)
             else:
                 # Window shape = its own bucket padded to C (no-op pad when
                 # the plan uses exact buckets) — must match the key the
@@ -1258,7 +1334,8 @@ class Solver:
             cap = states_dev.shape[0]
             from_checkpoint = k in completed
             item = np.dtype(g.state_dtype).itemsize
-            lvl_sort_bytes = lvl_gather_bytes = 0
+            lvl_sort_bytes = lvl_gather_bytes = lvl_lanes = 0
+            lvl_missed = None
             table = None
             if from_checkpoint:
                 from gamesmanmpi_tpu.utils.checkpoint import TORN_NPZ_ERRORS
@@ -1288,28 +1365,34 @@ class Solver:
                     # The level's inputs (states_dev, prev window triple,
                     # stored provenance) are all still referenced, so a
                     # transient failure re-dispatches idempotently.
-                    nonlocal lvl_sort_bytes, lvl_gather_bytes
+                    nonlocal lvl_sort_bytes, lvl_gather_bytes, lvl_lanes, \
+                        lvl_missed
                     faults.fire("engine.backward", level=k)
-                    if prev is not None and rec.uidx is not None:
-                        # uidx read (4 B) + packed-cell gather (4 B) per
-                        # child.
-                        lvl_gather_bytes = C * g.max_moves * 8
-                        # Gather-only resolve from forward provenance: no
-                        # search, no re-expansion (see resolve_provenance).
+                    if prev is not None and rec.perm is not None:
+                        slots = C * g.max_moves
+                        # The monotone fetch: perm read (4 B) + packed
+                        # cell (4 B) per child slot; the un-permute sort:
+                        # (i32 origin, u32 cell) pairs.
+                        lvl_gather_bytes = slots * 8
+                        lvl_sort_bytes = slots * 8
+                        lvl_lanes = slots
+                        # Resolve from forward provenance: no search, no
+                        # re-expansion (see resolve_provenance).
                         wcap = caps[k + 1]
                         note_dispatch("eager")
                         note_dispatch("eager")
                         wv = jax.lax.slice(prev[1], (0,), (wcap,))
                         wr = jax.lax.slice(prev[2], (0,), (wcap,))
-                        return self._resolve_blocked_prov(
+                        *out, lvl_missed = self._resolve_blocked_prov(
                             n,
                             self._pad_dev(rec.prim, C, np.uint8(UNDECIDED)),
-                            self._pad_dev(
-                                rec.uidx, C * g.max_moves, np.int32(-1)
-                            ),
+                            self._pad_dev(rec.perm, slots,
+                                          np.int32(PERM_PAD)),
+                            rec.nreal,
                             self._pad_dev(wv, C, np.uint8(UNDECIDED)),
                             self._pad_dev(wr, C, np.int32(0)),
                         )
+                        return out
                     if prev is not None:
                         if search_method() == "sort":
                             # Sort-merge join operands + fused u64 payload
@@ -1346,6 +1429,9 @@ class Solver:
                 values_dev, rem_dev, misses = self._retry(
                     "engine.backward", _resolve, level=k
                 )
+                if lvl_missed is not None:
+                    self.gather_lanes += lvl_lanes
+                    self._gather_miss.append(lvl_missed)
                 if self.paranoid and int(misses) > 0:
                     raise SolverError(
                         f"level {k}: {int(misses)} consistency failures (child "
@@ -1380,7 +1466,7 @@ class Solver:
                 with trace_span("checkpoint", level=k, kind="level"):
                     self.checkpointer.save_level(k, tbl)
             rec.dev = None  # release the forward copy
-            rec.prim = rec.uidx = None  # release provenance
+            rec.prim = rec.perm = rec.nreal = None  # release provenance
             if not self.store_tables:
                 rec.host = None
             if not from_checkpoint and C >= (1 << 21):
@@ -1706,7 +1792,7 @@ class Solver:
                                   self.min_bucket)
                 for c in (cap, cap * 2, cap * 4):
                     self._sched_fwd_step(c)
-                    self._sched_bwd_step(min(c, self._block_size()), c)
+                    self._sched_bwd_step(c)
         init, start_level = canonical_scalar(g, g.initial_state())
         if self.fast:
             if saved is not None:
@@ -1759,6 +1845,7 @@ class Solver:
             )
         value = int(root.values[i])
         remoteness = int(root.remoteness[i])
+        gather_miss = sum(int(m) for m in jax.device_get(self._gather_miss))
         stats = {
             "game": g.name,
             "engine": "classic",
@@ -1777,6 +1864,10 @@ class Solver:
             # "Efficiency accounting" for how to read them.
             "bytes_sorted": self.bytes_sorted,
             "bytes_gathered": self.bytes_gathered,
+            # The provenance backward's sorted-order fetch: its lanes, and
+            # those its window kernel's views missed (re-fetched exactly).
+            "gather_lanes": self.gather_lanes,
+            "gather_miss_lanes": gather_miss,
             # Dispatch economy: total device computations/transfers this
             # solve issued, and per discovered level.
             "dispatches_total": self.dispatch_total,

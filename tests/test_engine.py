@@ -86,6 +86,63 @@ def test_blocked_backward_parity():
     assert full_table(result) == full_table(base)
 
 
+_C4_4X4_ORACLE = []
+
+
+def _c4_4x4_oracle():
+    if not _C4_4X4_ORACLE:
+        _, _, table = oracle_solve(load_module(REF_GAMES / "connect4_4x4.py"))
+        _C4_4X4_ORACLE.append(table)
+    return _C4_4X4_ORACLE[0]
+
+
+@pytest.mark.parametrize("source,block,fetch", [
+    ("builtin", "whole", "plain"),
+    ("builtin", "blocked", "plain"),
+    ("rule_file", "whole", "plain"),
+    ("rule_file", "blocked", "plain"),
+    ("builtin", "blocked", "pallas"),   # the window kernel, interpreted
+    ("rule_file", "whole", "pallas"),
+])
+def test_provenance_backward_oracle_parity(monkeypatch, tmp_path, source,
+                                           block, fetch):
+    """The classic backward from forward provenance (the child cells
+    fetched in the dedup sort's order, un-permuted by one pair sort, then
+    combined) against the oracle: Connect-4 4x4 built in, and as the
+    benchmark's rule file cut to 4x4; each level's combine whole, and in
+    several blocks; the fetch through XLA's gather and through the Pallas
+    window kernel, which must serve every lane from its views."""
+    import json
+
+    import gamesmanmpi_tpu.solve.engine as engine
+    from helpers import REPO
+
+    monkeypatch.setenv("GAMESMAN_PROVENANCE", "1")
+    monkeypatch.setenv("GAMESMAN_BACKWARD_BLOCK",
+                       "256" if block == "blocked" else "0")
+    monkeypatch.setattr(engine, "fetch_method", lambda: fetch)
+    if source == "builtin":
+        game = get_game("connect4:w=4,h=4")
+    else:
+        import pathlib
+
+        doc = json.loads((pathlib.Path(REPO) / "benchmarks" / "specs"
+                          / "connect4_6x4.json").read_text())
+        doc["name"] = "connect4_4x4_rules"
+        doc["board"] = {"width": 4, "height": 4}
+        path = tmp_path / "connect4_4x4_rules.json"
+        path.write_text(json.dumps(doc))
+        game = get_game(str(path))
+    solver = Solver(game, paranoid=True)
+    result = solver.solve()
+    assert_table_parity(result, _c4_4x4_oracle())
+    kinds = solver.dispatch_by_kind
+    assert kinds.get("bwdp", 0) > 0
+    assert ("bwdc" in kinds) == (block == "blocked")
+    assert result.stats["gather_lanes"] > 0
+    assert result.stats["gather_miss_lanes"] == 0
+
+
 def test_chomp_parity_and_strategy_stealing():
     """Chomp 3x3: full-table oracle parity; every board >1x1 is a
     first-player WIN (strategy stealing), the closed-form anchor."""

@@ -167,9 +167,12 @@ def test_route_by_owner_overflow_drops_and_counts():
 
 def test_expand_provenance_contract():
     """expand_provenance must agree with expand_core on (uniq, count), its
-    prim with game.primitive, and uidx must map every real child slot to
-    that child's index in the uniq prefix (-1 exactly on invalid slots) —
-    the invariant the gather-only backward pass rests on."""
+    prim with game.primitive, and perm/nreal must list every child slot
+    once in the dedup sort's order: the nreal real children first, in
+    non-decreasing state order, the run-first bit exactly where a new
+    state starts, so that the run index (cumsum of those bits, less one)
+    is each child's index in the uniq prefix — the invariant the backward
+    pass's sorted-order fetch rests on."""
     import jax
 
     from gamesmanmpi_tpu.games import get_game
@@ -184,7 +187,6 @@ def test_expand_provenance_contract():
         game = get_game(spec)
         # A frontier with real states, duplicates of children guaranteed
         # (siblings share children via transpositions), and sentinel pads.
-        rng = np.random.default_rng(7)
         init = game.initial_state()
         kids, _ = jax.jit(lambda s: game.expand(s))(
             jnp.asarray([init], dtype=game.state_dtype)
@@ -196,7 +198,7 @@ def test_expand_provenance_contract():
         states_j = jnp.asarray(states)
 
         uniq_c, count_c = jax.jit(lambda s: expand_core(game, s))(states_j)
-        uniq_p, count_p, uidx, prim = jax.jit(
+        uniq_p, count_p, perm, nreal, prim = jax.jit(
             lambda s: expand_provenance(game, s)
         )(states_j)
         assert int(count_c) == int(count_p)
@@ -210,16 +212,129 @@ def test_expand_provenance_contract():
         )(states_j)
         flat = np.asarray(children).reshape(-1)
         m = np.asarray(mask).reshape(-1)
-        ui = np.asarray(uidx)
+        p = np.asarray(perm)
+        first = p < 0
+        origin = p & 0x7FFFFFFF
         uq = np.asarray(uniq_p)
-        n = int(count_p)
-        for slot in range(flat.shape[0]):
-            if flat[slot] == game.sentinel:
-                assert ui[slot] == -1
-            else:
-                assert m[slot]
-                assert 0 <= ui[slot] < n
-                assert uq[ui[slot]] == flat[slot]
+        n, real = int(count_p), int(nreal)
+        assert sorted(origin.tolist()) == list(range(flat.shape[0]))
+        assert real == int((flat != game.sentinel).sum())
+        kids_sorted = flat[origin]
+        assert (kids_sorted[real:] == game.sentinel).all()
+        assert not first[real:].any()
+        assert m[origin[:real]].all()
+        assert (np.diff(kids_sorted[:real]) > 0).tolist() == \
+            first[1:real].tolist()
+        assert real == 0 or first[0]
+        uid = np.cumsum(first) - 1
+        assert int(first.sum()) == n
+        assert (uq[uid[:real]] == kids_sorted[:real]).all()
+
+
+def _window_uid(pattern, n, seed):
+    """A run index over n slots: steps of 0 or 1 from 0."""
+    rng = np.random.default_rng(seed)
+    if pattern == "all_steps":
+        steps = np.ones(n, np.int32)
+    elif pattern == "random_steps":
+        steps = rng.integers(0, 2, size=n)
+    else:  # long runs of equal children, then dense steps
+        steps = (rng.random(n) < 0.05).astype(np.int32)
+        steps[n // 2:] = 1
+    steps[0] = 0
+    return np.cumsum(steps).astype(np.int32)
+
+
+@pytest.mark.parametrize("pattern", ["all_steps", "random_steps",
+                                     "runs_then_steps"])
+def test_window_gather_serves_run_index_from_views(pattern):
+    """The run index steps by 0 or 1 per slot, so a block of 1024 lanes
+    spans at most 1024 entries of a 4-byte table: the window kernel
+    (interpreted) at SORTED_FETCH_WINDOW serves every lane from its views
+    and gives table[uid] exactly."""
+    from gamesmanmpi_tpu.ops.pallas_gather import monotone_window_gather
+    from gamesmanmpi_tpu.ops.provenance import SORTED_FETCH_WINDOW
+
+    assert SORTED_FETCH_WINDOW == 2048
+    n = 9 * 1024 + 77  # a partial last block
+    uid = _window_uid(pattern, n, seed=len(pattern))
+    rng = np.random.default_rng(1)
+    table = rng.integers(0, 1 << 32, size=int(uid[-1]) + 300,
+                         dtype=np.uint32)
+    out, missed = monotone_window_gather(
+        jnp.asarray(table), jnp.asarray(uid), window=SORTED_FETCH_WINDOW,
+        interpret=True)
+    assert int(missed) == 0
+    np.testing.assert_array_equal(np.asarray(out), table[uid])
+
+
+def _fetch_case(case, dtype):
+    """[C*M] child states for the sorted-fetch parity test."""
+    rng = np.random.default_rng(len(case))
+    sentinel = np.iinfo(dtype).max
+    n = 1024 * 4
+    if case == "all_sentinel":
+        return np.full(n, sentinel, dtype)
+    if case == "single_unique":
+        x = np.full(n, 12345, dtype)
+        x[rng.random(n) < 0.2] = sentinel
+        return x
+    x = rng.integers(0, n // 3, size=n).astype(dtype)
+    if case == "sentinel_tail":
+        x[n // 3:] = sentinel
+    else:
+        x[rng.random(n) < 0.15] = sentinel
+    return x
+
+
+FETCH_CASES = [(c, d) for c in ("runs", "sentinel_tail")
+                for d in (np.uint32, np.uint64)
+               ] + [("all_sentinel", np.uint32), ("single_unique", np.uint64),
+                    ("padded", np.uint32)]
+
+
+@pytest.mark.parametrize(
+    "case,dtype", FETCH_CASES,
+    ids=[f"{c}-{np.dtype(d).name}" for c, d in FETCH_CASES])
+def test_sorted_fetch_matches_parent_order_gather(case, dtype):
+    """The classic backward's fetch in the dedup sort's order plus its
+    un-permute sort gives each child slot the cell that a gather in parent
+    slot order through dedup_provenance's uidx gives (cell 0 where there is
+    no child), moves major; through XLA's gather and through the Pallas
+    window kernel (interpreted), which misses no lane. "padded": a level
+    padded to a larger common capacity, as the backward pads it."""
+    import jax
+
+    from gamesmanmpi_tpu.core.codec import pack_cells_np
+    from gamesmanmpi_tpu.ops import dedup_provenance, dedup_provenance_sorted
+    from gamesmanmpi_tpu.ops.provenance import PERM_PAD, sorted_child_cells
+
+    M = 4
+    x = _fetch_case("runs" if case == "padded" else case, dtype)
+    _, count, uidx = jax.jit(dedup_provenance)(jnp.asarray(x))
+    uniq, count_s, perm, nreal = jax.jit(dedup_provenance_sorted)(
+        jnp.asarray(x))
+    assert int(count_s) == int(count)
+    uidx = np.asarray(uidx)
+    if case == "padded":
+        slots = 2 * x.shape[0]
+        perm = jnp.concatenate(
+            [perm, jnp.full(slots - perm.shape[0], PERM_PAD, jnp.int32)])
+        uidx = np.concatenate([uidx, np.full(slots - uidx.shape[0], -1,
+                                             np.int32)])
+    W = max(int(count), 1) + 5
+    rng = np.random.default_rng(2)
+    wvals = rng.integers(1, 4, size=W).astype(np.uint8)
+    wrem = rng.integers(0, 40, size=W).astype(np.int32)
+    cells = pack_cells_np(wvals, wrem)
+    want = np.where(uidx >= 0, cells[np.clip(uidx, 0, W - 1)], 0)
+    want = want.reshape(-1, M).T  # moves major
+    for method in ("plain", "pallas"):
+        got, missed = jax.jit(
+            lambda p, r, v, w: sorted_child_cells(p, r, v, w, M, method)
+        )(perm, nreal, jnp.asarray(wvals), jnp.asarray(wrem))
+        assert int(missed) == 0, method
+        np.testing.assert_array_equal(np.asarray(got), want, err_msg=method)
 
 
 # ------------------------------------------- platform lowerings vs NumPy

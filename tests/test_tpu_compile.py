@@ -85,6 +85,33 @@ def test_classic_expand_compiles(spec, dtype, one_chip, accel_knobs):
     assert compiled.memory_analysis().temp_size_in_bytes > 0
 
 
+@pytest.mark.parametrize("kind", ["bwdp", "bwdg"])
+def test_classic_provenance_backward_compiles(kind, one_chip, monkeypatch):
+    """The classic engine's accelerator backward from provenance for 6x4
+    at the 65536 minimum bucket: the sorted-order fetch through the Pallas
+    window kernel (a Mosaic custom call), then the un-permute sort, whole
+    (bwdp) or as a wide level's fetch alone (bwdg)."""
+    import numpy as np
+
+    from gamesmanmpi_tpu.solve import Solver
+
+    game = get_game("connect4:w=6,h=4")
+    cap, M = 65536, game.max_moves
+    # fetch_method reads the default backend when the builder runs.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    builder = {"bwdp": Solver._bwdp_builder,
+               "bwdg": Solver._bwdg_builder}[kind]
+    fn = builder(game)
+    fetch = [((cap * M,), np.int32), ((), np.int32), ((cap,), np.uint8),
+             ((cap,), np.int32)]
+    shapes = ([((), np.int32), ((cap,), np.uint8)] if kind == "bwdp"
+              else []) + fetch
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+             for s, d in shapes]
+    compiled = jax.jit(fn).lower(*avals).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def _compile_dense_step(level, gather_mode, one_chip, monkeypatch):
     from gamesmanmpi_tpu.solve.dense import DenseSolver, build_dense_step
 

@@ -11,7 +11,7 @@ replacements on the real chip before any is built:
   plan needs per-row sorts to be much faster per element);
 - u8-key pair sort (cost of a partition pass done via lax.sort);
 - gather bandwidth vs table size (does a VMEM-sized table gather fast?);
-- permutation-inversion: scatter vs pair-sort (expand_provenance sort #2);
+- permutation-inversion: scatter vs pair-sort (dedup_provenance's sort #2);
 - pure-JAX bitonic merge of two sorted halves (sorted-merge lever);
 - a trivial Pallas kernel (does Pallas/Mosaic work on the chip?).
 
